@@ -78,6 +78,14 @@ def pick(default, smoke_value):
     return smoke_value if SMOKE else default
 
 
+def on_tpu() -> bool:
+    """Whether JAX runs on a TPU.  Rows of the ``'kernel'`` backend time the
+    compiled Pallas kernels and exist only there; elsewhere they are left
+    out, never filled in by another path."""
+    import jax  # deferred: most benches never touch the device
+    return jax.devices()[0].platform == "tpu"
+
+
 def bench_cfg(**overrides) -> SimConfig:
     """The standard bench SimConfig, smoke-shrunk when --smoke is active."""
     cfg = SimConfig(duration_ms=pick(150_000.0, 15_000.0), seed=3,

@@ -8,6 +8,9 @@ from __future__ import annotations
 import argparse
 import sys
 import traceback
+from pathlib import Path
+
+from repro.compile_cache import enable_compile_cache
 
 from . import (bench_ablation, bench_dynamic, bench_dynamic_throughput,
                bench_fabric, bench_kernels, bench_param_variation,
@@ -69,12 +72,15 @@ def main() -> None:
                     help="worker pool flavor for --workers > 1: threads "
                          "(default) or spawned processes (sidesteps the "
                          "GIL for CPU-bound grids; scenario builders are "
-                         "picklable dataclasses so cells ship cleanly)")
+                         "picklable dataclasses so cells ship cleanly). "
+                         "Process mode refuses grids on a device fluid "
+                         "backend ('jnp'/'kernel'): one process per chip")
     ap.add_argument("--cache-dir", default=None, metavar="DIR",
                     help="content-keyed sweep-result cache (nightly CI): "
                          "grids whose materialized inputs are unchanged "
                          "restore from DIR instead of re-simulating")
     args = ap.parse_args()
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     if args.smoke:
         common.SMOKE = True
     common.WORKERS = max(1, args.workers)

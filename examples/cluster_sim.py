@@ -9,7 +9,9 @@ controller ablations that only the new API can apply to trace runs
 Run:  PYTHONPATH=src python examples/cluster_sim.py [--jobs 10] [--seed 1]
 """
 import argparse
+from pathlib import Path
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.metronome_testbed import MODEL_FLEET, trace_scenario
 from repro.core.cluster import make_fabric_cluster
 from repro.core.experiment import Policy, sweep
@@ -31,6 +33,7 @@ def main():
     ap.add_argument("--no-reconfigure", action="store_true",
                     help="ablate the section III-C reconfiguration loop")
     args = ap.parse_args()
+    enable_compile_cache(Path(__file__).resolve().parents[1])
 
     trace = generate_trace(MODEL_FLEET, duration_s=args.duration_s,
                            total_gpus=13, target_load=0.85, seed=args.seed,

@@ -1,11 +1,12 @@
 """kernel-parity: every Pallas kernel needs ops wiring, a ref oracle and
 an interpret-mode parity test.
 
-The dispatch contract (``kernels/ops.py``): real TPU -> compiled Pallas;
-anything else -> interpret mode or the jit'd jnp reference from
-``kernels/ref.py``.  This container never runs compiled Pallas, so the
-ONLY thing standing between a kernel edit and silently-wrong TPU behavior
-is the interpret-mode parity test against the ref oracle.  Three rules per
+The dispatch contract (``kernels/ops.py``): on a TPU, compiled Pallas;
+anywhere else, interpret mode when the caller passes ``interpret=True``
+and an error otherwise — never the jnp reference from ``kernels/ref.py``.
+The CPU test suite never runs compiled Pallas, so what stands between a
+kernel edit and silently-wrong TPU results is the interpret-mode parity
+test against the ref oracle.  Three rules per
 public kernel function in ``kernels/*.py`` (excluding ``ops.py`` /
 ``ref.py``):
 

@@ -25,7 +25,7 @@ BASELINE_VERSION = 1
 
 # directories never scanned (vendored/generated/VCS content)
 _SKIP_DIRS = {".git", "__pycache__", ".bench_cache", "node_modules",
-              ".pytest_cache", ".ruff_cache", ".mypy_cache"}
+              ".pytest_cache", ".ruff_cache", ".mypy_cache", "build"}
 
 
 @dataclasses.dataclass(frozen=True)

@@ -222,6 +222,18 @@ def _priority_split(workloads: Sequence[Workload]
     return hi, lo
 
 
+def _cell_config(scenario: Scenario, policy: Policy,
+                 sim_config: Optional[SimConfig]) -> SimConfig:
+    """The SimConfig one cell runs under: ``sim_config``, else the
+    scenario's, else the default, with the policy's fluid backend."""
+    config = sim_config or scenario.sim_config or SimConfig()
+    if (policy.sim_backend is not None
+            and config.fluid_backend != policy.sim_backend):
+        config = dataclasses.replace(config,
+                                     fluid_backend=policy.sim_backend)
+    return config
+
+
 def run(scenario: Scenario, policy: Policy,
         sim_config: Optional[SimConfig] = None) -> ExperimentResult:
     """Run one (scenario, policy) cell and return the typed result.
@@ -240,11 +252,7 @@ def run(scenario: Scenario, policy: Policy,
     STATIC contention-free bound: background flows and events are
     deliberately ignored.
     """
-    config = sim_config or scenario.sim_config or SimConfig()
-    if (policy.sim_backend is not None
-            and config.fluid_backend != policy.sim_backend):
-        config = dataclasses.replace(config,
-                                     fluid_backend=policy.sim_backend)
+    config = _cell_config(scenario, policy, sim_config)
     cluster, workloads, background, events = scenario.materialize()
     hi, lo = _priority_split(workloads)
 
@@ -383,11 +391,26 @@ def sweep(scenarios: Sequence[Scenario], policies: Sequence[Policy],
     picklable scenarios/policies: use module-level build callables (the
     ``configs.metronome_testbed`` builders are dataclass instances for
     exactly this) and schedulers registered at import time of their
-    defining module."""
+    defining module.
+
+    Process mode refuses a grid in which any cell runs a device fluid
+    backend (``'jnp'`` or ``'kernel'``): a chip belongs to one process, so
+    spawned workers would fail or hang on it.  Run such grids with threads
+    or serially."""
     if mode not in ("thread", "process"):
         raise ValueError(f"mode must be 'thread' or 'process', got {mode!r}")
     grid = [(scenario, policy) for scenario in scenarios
             for policy in policies]
+    if mode == "process":
+        backends = {f"{s.name}/{p.name}":
+                    _cell_config(s, p, sim_config).fluid_backend
+                    for s, p in grid}
+        on_device = {c: b for c, b in backends.items() if b != "python"}
+        if on_device:
+            raise ValueError(
+                "sweep(mode='process') runs only host fluid backends "
+                "('python'); a device backend needs the chip in one "
+                f"process — use mode='thread' or workers=1: {on_device}")
     if workers <= 1 or len(grid) <= 1:
         cells = [_run_cell(s, p, sim_config) for s, p in grid]
         return SweepResult(cells=cells, meta=dict(meta or {}))

@@ -11,9 +11,11 @@ per-flow Python loop for a batched vectorized solve:
   * ``backend='jnp'`` — the fill expressed as a fixed point over a
     (flows x links) demand/route matrix, solved by the jit'd jnp oracle
     (``kernels.ref.progressive_fill_ref``), float32.
-  * ``backend='kernel'`` — same matrix form through the
-    ``kernels.ops.progressive_fill`` dispatch: compiled Pallas on a real
-    TPU, the jit'd jnp oracle anywhere else (this CPU container).
+  * ``backend='kernel'`` — same matrix form through the ``metronome_fill``
+    Pallas kernel (``kernels.ops.progressive_fill``): compiled on a TPU.
+    Off a TPU it raises, unless the caller of :func:`fill_many` /
+    :func:`fill_corpus` passes ``interpret=True`` (interpret mode, for
+    parity tests); it never substitutes the jnp path.
 
 The matrix form: routes[f, l] = 1 iff flow f's path crosses link l.  Each
 round every unfrozen flow grows by the same increment — the minimum over
@@ -228,6 +230,9 @@ def fill_many(
     and solved by the vectorized backend in a single call.  Returns the
     unpadded per-problem rate vectors.
 
+    ``interpret=True`` runs the ``'kernel'`` backend's Pallas kernel in
+    interpret mode (off a TPU; see the module docstring).
+
     ``pad_to=(F, L)`` raises the pad shape beyond the batch maximum so
     repeated calls with similar problems land on a fixed set of jit-compiled
     shapes (the event-loop steady state) instead of recompiling per tick.
@@ -255,7 +260,7 @@ def fill_many(
         d[i, :fi] = di
         routes[i, :fi, :li] = ri
         caps[i, :li] = ci
-    if backend == "jnp" and interpret is None:
+    if backend == "jnp":
         out = kops.progressive_fill_ref(d, routes, caps)
     else:
         out = kops.progressive_fill(d, routes, caps, interpret=interpret)

@@ -28,9 +28,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .ref import FILL_EPS
 
-# jax<0.6 compat: CompilerParams was named TPUCompilerParams (same kwargs)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 LANE = 128
 SUBLANE = 8
 _INF = 1e30
@@ -39,7 +36,7 @@ _INF = 1e30
 def _fill_kernel(demands_ref, routes_ref, caps_ref, out_ref, *, f_pad: int):
     d = demands_ref[...][0]        # (F_pad, 1)
     routes = routes_ref[...][0]    # (F_pad, L_pad)
-    caps = caps_ref[...]           # (1, L_pad)
+    caps = caps_ref[...][0]        # (1, L_pad)
 
     act0 = (d > FILL_EPS).astype(jnp.float32)
     state0 = (jnp.zeros_like(d), caps, act0)
@@ -81,9 +78,11 @@ def metronome_fill(
     d = d.at[:, :f, 0].set(demands.astype(jnp.float32))
     r = jnp.zeros((b, f_pad, l_pad), jnp.float32)
     r = r.at[:, :f, :l].set(routes.astype(jnp.float32))
-    # padded links: unit capacity, zero routes — they never saturate
-    c = jnp.ones((b, l_pad), jnp.float32)
-    c = c.at[:, :l].set(caps.astype(jnp.float32))
+    # padded links: unit capacity, zero routes — they never saturate.  The
+    # singleton middle axis makes the block's last two dims (1, L_pad) equal
+    # the array's, which the TPU lowering requires for any batch size.
+    c = jnp.ones((b, 1, l_pad), jnp.float32)
+    c = c.at[:, 0, :l].set(caps.astype(jnp.float32))
 
     kernel = functools.partial(_fill_kernel, f_pad=f_pad)
     out = pl.pallas_call(
@@ -92,11 +91,11 @@ def metronome_fill(
         in_specs=[
             pl.BlockSpec((1, f_pad, 1), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, f_pad, l_pad), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, l_pad), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, l_pad), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, f_pad, 1), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, f_pad, 1), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(d, r, c)

@@ -30,9 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.6 compat: CompilerParams was named TPUCompilerParams (same kwargs)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 LANE = 128
 
 
@@ -85,7 +82,7 @@ def metronome_score_pairwise(
         ],
         out_specs=pl.BlockSpec((block_a, rb), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((ra_pad, rb), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(base, a, b)
@@ -174,7 +171,7 @@ def metronome_score_multilink_batch(
         ],
         out_specs=pl.BlockSpec((1, block_a, rb), lambda ci, i: (ci, i, 0)),
         out_shape=jax.ShapeDtypeStruct((c, ra_pad, rb), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(caps, base, a, b)
@@ -222,7 +219,7 @@ def metronome_score_multilink(
         ],
         out_specs=pl.BlockSpec((block_a, rb), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((ra_pad, rb), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(caps, base, a, b)

@@ -1,13 +1,20 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Each op dispatches: real TPU -> compiled Pallas; anything else (this CPU
-container, tests) -> interpret mode or the jnp reference. Training gets a
-``custom_vjp`` whose backward recomputes through the jnp oracle (flash
-forward is exact, so gradients match the reference path).
+Every dispatcher runs its Pallas kernel and nothing else: compiled on a
+TPU, in interpret mode off a TPU only when the caller passes
+``interpret=True``, and otherwise it raises an error naming the platform.
+No dispatcher substitutes the jnp reference; that is the fluid engine's
+``backend='jnp'`` (:func:`progressive_fill_ref`) under its own name.
+:data:`DISPATCHES` counts each call by ``(op, mode)``, so a caller can show
+which path ran.  Training gets a ``custom_vjp`` whose backward recomputes
+through the jnp oracle (flash forward is exact, so gradients match the
+reference path).
 """
 from __future__ import annotations
 
+import collections
 import functools
+import threading
 from typing import Optional
 
 import jax
@@ -22,12 +29,28 @@ from .metronome_score import (metronome_score_multilink,
                               metronome_score_pairwise)
 from .rg_lru import rg_lru_pallas
 
+# (op, "compiled" | "interpret") -> calls
+DISPATCHES: collections.Counter = collections.Counter()
+_DISPATCH_LOCK = threading.Lock()
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+
+def _interpret(op: str, interpret: Optional[bool]) -> bool:
+    """The ``interpret`` flag for one Pallas call of ``op``: True when the
+    caller asks for interpret mode, False on a TPU; anywhere else an error
+    (never a silent fallback)."""
+    if interpret:
+        mode = "interpret"
+    else:
+        platform = jax.devices()[0].platform
+        if platform != "tpu":
+            raise RuntimeError(
+                f"{op}: the compiled Pallas kernel needs a TPU, but JAX's "
+                f"default platform is {platform!r}; pass interpret=True for "
+                "interpret mode, or use the jnp reference in kernels.ref")
+        mode = "compiled"
+    with _DISPATCH_LOCK:
+        DISPATCHES[(op, mode)] += 1
+    return mode == "interpret"
 
 
 # ---------------------------------------------------------------------------
@@ -38,9 +61,9 @@ def _on_tpu() -> bool:
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     interpret: Optional[bool] = None):
     """(B,H,S,D) x (B,Hkv,S,D)^2 -> (B,H,S,D)."""
-    itp = (not _on_tpu()) if interpret is None else interpret
     return flash_attention_fwd(q, k, v, causal=causal, window=window,
-                               interpret=itp)
+                               interpret=_interpret("flash_attention",
+                                                    interpret))
 
 
 def _fa_fwd(q, k, v, causal, window, interpret):
@@ -63,68 +86,44 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # metronome rotation scoring
 # ---------------------------------------------------------------------------
 
+_score_pairwise_jit = jax.jit(metronome_score_pairwise,
+                              static_argnames=("capacity", "interpret"))
+_score_multilink_jit = jax.jit(metronome_score_multilink,
+                               static_argnames="interpret")
+_score_multilink_batch_jit = jax.jit(metronome_score_multilink_batch,
+                                     static_argnames="interpret")
+
+
 def score_pairwise(base_demand, bank_a, bank_b, capacity: float,
                    interpret: Optional[bool] = None) -> np.ndarray:
     """Eq. 18 scores for every (rot_a, rot_b) pair; see core/rotation.py."""
-    itp = (not _on_tpu()) if interpret is None else interpret
-    out = metronome_score_pairwise(
+    out = _score_pairwise_jit(
         jnp.asarray(base_demand), jnp.asarray(bank_a), jnp.asarray(bank_b),
-        capacity, interpret=itp)
+        capacity=float(capacity),
+        interpret=_interpret("score_pairwise", interpret))
     return np.asarray(out)
-
-
-_score_multilink_jit = jax.jit(ref.metronome_score_multilink_ref)
 
 
 def score_multilink(base_demand, bank_a, bank_b, capacities,
                     interpret: Optional[bool] = None) -> np.ndarray:
     """Joint (min-over-links) Eq. 18 scores for every rotation pair of two
-    free jobs over stacked (L, R, S) per-link demand banks.
-
-    Dispatch: real TPU -> compiled Pallas multi-link kernel; anything else
-    -> the jit'd jnp reference (the batched CPU fallback of the fabric-wide
-    planner).  ``interpret=True`` forces the Pallas kernel in interpret
-    mode (parity tests only — far slower than the jnp path)."""
-    if interpret:
-        out = metronome_score_multilink(
-            jnp.asarray(base_demand), jnp.asarray(bank_a),
-            jnp.asarray(bank_b), jnp.asarray(capacities), interpret=True)
-    elif _on_tpu():
-        out = metronome_score_multilink(
-            jnp.asarray(base_demand), jnp.asarray(bank_a),
-            jnp.asarray(bank_b), jnp.asarray(capacities), interpret=False)
-    else:
-        out = _score_multilink_jit(
-            jnp.asarray(base_demand), jnp.asarray(bank_a),
-            jnp.asarray(bank_b), jnp.asarray(capacities))
+    free jobs over stacked (L, R, S) per-link demand banks."""
+    out = _score_multilink_jit(
+        jnp.asarray(base_demand), jnp.asarray(bank_a), jnp.asarray(bank_b),
+        jnp.asarray(capacities),
+        interpret=_interpret("score_multilink", interpret))
     return np.asarray(out)
-
-
-_score_multilink_batch_jit = jax.jit(ref.metronome_score_multilink_batch_ref)
 
 
 def score_multilink_batch(base_demand, bank_a, bank_b, capacities,
                           interpret: Optional[bool] = None) -> np.ndarray:
     """Candidate-batched joint Eq. 18 scores: ONE dispatch over stacked
     (C, L, R, S) banks returning (C, Ra, Rb) — the Score phase's surviving
-    candidates evaluated together instead of one kernel launch each.
-
-    Dispatch mirrors :func:`score_multilink`: real TPU -> compiled Pallas
-    batch kernel; anything else -> the jit'd jnp reference;
-    ``interpret=True`` forces the Pallas kernel in interpret mode (parity
-    tests only)."""
-    if interpret:
-        out = metronome_score_multilink_batch(
-            jnp.asarray(base_demand), jnp.asarray(bank_a),
-            jnp.asarray(bank_b), jnp.asarray(capacities), interpret=True)
-    elif _on_tpu():
-        out = metronome_score_multilink_batch(
-            jnp.asarray(base_demand), jnp.asarray(bank_a),
-            jnp.asarray(bank_b), jnp.asarray(capacities), interpret=False)
-    else:
-        out = _score_multilink_batch_jit(
-            jnp.asarray(base_demand), jnp.asarray(bank_a),
-            jnp.asarray(bank_b), jnp.asarray(capacities))
+    candidates evaluated together instead of one kernel launch each."""
+    out = _score_multilink_batch_jit(
+        jnp.asarray(base_demand), jnp.asarray(bank_a), jnp.asarray(bank_b),
+        jnp.asarray(capacities),
+        interpret=_interpret("score_multilink_batch", interpret))
     return np.asarray(out)
 
 
@@ -132,35 +131,25 @@ def score_multilink_batch(base_demand, bank_a, bank_b, capacities,
 # progressive-filling fluid solve
 # ---------------------------------------------------------------------------
 
-_progressive_fill_jit = jax.jit(ref.progressive_fill_ref)
+_progressive_fill_ref_jit = jax.jit(ref.progressive_fill_ref)
+_progressive_fill_jit = jax.jit(metronome_fill, static_argnames="interpret")
 
 
 def progressive_fill_ref(demands, routes, caps) -> np.ndarray:
     """The jit'd jnp fixed-point fill — the fluid engine's ``backend='jnp'``
-    path, always the vectorized reference regardless of platform."""
-    return np.asarray(_progressive_fill_jit(
+    path, on whatever platform JAX runs."""
+    return np.asarray(_progressive_fill_ref_jit(
         jnp.asarray(demands), jnp.asarray(routes), jnp.asarray(caps)))
 
 
 def progressive_fill(demands, routes, caps,
                      interpret: Optional[bool] = None) -> np.ndarray:
-    """Batched progressive-fill rates (B, F) over (B, F, L) route matrices.
-
-    Dispatch mirrors :func:`score_multilink`: real TPU -> compiled Pallas
-    fill kernel; anything else -> the jit'd jnp reference;
-    ``interpret=True`` forces the Pallas kernel in interpret mode (parity
-    tests only — far slower than the jnp path)."""
-    if interpret:
-        out = metronome_fill(
-            jnp.asarray(demands), jnp.asarray(routes), jnp.asarray(caps),
-            interpret=True)
-    elif _on_tpu():
-        out = metronome_fill(
-            jnp.asarray(demands), jnp.asarray(routes), jnp.asarray(caps),
-            interpret=False)
-    else:
-        out = _progressive_fill_jit(
-            jnp.asarray(demands), jnp.asarray(routes), jnp.asarray(caps))
+    """Batched progressive-fill rates (B, F) over (B, F, L) route matrices
+    through the ``metronome_fill`` kernel — the fluid engine's
+    ``backend='kernel'`` path."""
+    out = _progressive_fill_jit(
+        jnp.asarray(demands), jnp.asarray(routes), jnp.asarray(caps),
+        interpret=_interpret("progressive_fill", interpret))
     return np.asarray(out)
 
 
@@ -169,5 +158,4 @@ def progressive_fill(demands, routes, caps,
 # ---------------------------------------------------------------------------
 
 def rg_lru(a, x, interpret: Optional[bool] = None):
-    itp = (not _on_tpu()) if interpret is None else interpret
-    return rg_lru_pallas(a, x, interpret=itp)
+    return rg_lru_pallas(a, x, interpret=_interpret("rg_lru", interpret))

@@ -18,9 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.6 compat: CompilerParams was named TPUCompilerParams (same kwargs)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _rg_lru_kernel(a_ref, x_ref, y_ref, h_ref, *, block_s: int):
     si = pl.program_id(2)
@@ -36,10 +33,7 @@ def _rg_lru_kernel(a_ref, x_ref, y_ref, h_ref, *, block_s: int):
         ai = jax.lax.dynamic_slice_in_dim(a, i, 1, axis=0)
         xi = jax.lax.dynamic_slice_in_dim(x, i, 1, axis=0)
         h = ai * h + xi  # (1, block_w)
-        # leading axis via dslice, not a bare 0: jax<0.6 interpret-mode
-        # discharge chokes on int indices mixed with slices
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(i, 1), slice(None)),
-                 h.astype(y_ref.dtype)[None])
+        y_ref[pl.ds(0, 1), pl.ds(i, 1), :] = h.astype(y_ref.dtype)[None]
         return h
 
     h0 = h_ref[...][None, :] if h_ref.ndim == 1 else h_ref[...]
@@ -73,7 +67,7 @@ def rg_lru_pallas(
                                lambda bi, wi, si: (bi, si, wi)),
         out_shape=jax.ShapeDtypeStruct((b, s, w), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, x)

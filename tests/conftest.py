@@ -1,4 +1,7 @@
+import functools
 import os
+
+import pytest
 
 # Tests must see the single real CPU device (the 512-device override is
 # strictly dryrun.py's business).
@@ -14,8 +17,6 @@ except ModuleNotFoundError:
     # case SKIPs instead of erroring the whole session.
     import sys
     import types
-
-    import pytest
 
     class _Strategy:
         """Inert stand-in accepted anywhere a SearchStrategy is expected."""
@@ -80,3 +81,21 @@ else:
         "ci", deadline=None, max_examples=25,
         suppress_health_check=[HealthCheck.too_slow])
     settings.load_profile("ci")
+
+
+def _interpret_call(dispatch, *args, interpret=None):
+    return dispatch(*args, interpret=True)
+
+
+@pytest.fixture
+def interpret_kernels(monkeypatch):
+    """Run the Metronome kernel dispatchers in Pallas interpret mode, the
+    CPU stand-in for the compiled TPU path that ``backend='kernel'`` takes
+    (off a TPU the dispatchers otherwise raise).  Returns the dispatch
+    counter, so a test can show that the kernel really ran."""
+    from repro.kernels import ops
+    for name in ("progressive_fill", "score_multilink",
+                 "score_multilink_batch"):
+        monkeypatch.setattr(
+            ops, name, functools.partial(_interpret_call, getattr(ops, name)))
+    return ops.DISPATCHES

@@ -17,6 +17,7 @@ Plus the machinery that rides along: incremental per-component memoization
 production-trace generator, the ``Policy.sim_backend`` knob, process-mode
 sweeps and the content-keyed sweep cache.
 """
+import dataclasses
 import json
 import math
 import os
@@ -153,16 +154,30 @@ class TestScenarioParity:
         np.testing.assert_allclose(via_jnp, golden, atol=TOL, rtol=0)
         np.testing.assert_allclose(via_kernel, golden, atol=TOL, rtol=0)
 
-    def test_engine_fill_matches_oracle(self):
-        """FluidEngine.fill dispatches per backend onto the same problem."""
+    def test_engine_fill_matches_oracle(self, interpret_kernels):
+        """FluidEngine.fill dispatches per backend onto the same problem;
+        'kernel' runs the Pallas kernel (interpret mode here)."""
         cluster, fw, wls = scheduled("F4")
         view = LinkView.from_registry(cluster, fw.registry)
         demands, paths, caps = view.fill_problem(
             [j for wl in wls for j in wl.jobs])
         golden = fluid.FluidEngine("python").fill(demands, paths, caps)
+        ran = interpret_kernels[("progressive_fill", "interpret")]
         for backend in ("jnp", "kernel"):
             got = fluid.FluidEngine(backend).fill(demands, paths, caps)
             np.testing.assert_allclose(got, golden, atol=TOL, rtol=0)
+        assert interpret_kernels[("progressive_fill", "interpret")] == ran + 1
+
+    def test_kernel_backend_refuses_without_tpu(self):
+        """Off a TPU, 'kernel' without interpret=True raises and names the
+        platform; it never substitutes the jnp reference."""
+        mat = fluid.problem_matrix([5.0, 3.0], [("h0",), ("h0",)],
+                                   {"h0": 4.0})[:3]
+        with pytest.raises(RuntimeError, match="platform is 'cpu'"):
+            fluid.fill_many([mat], backend="kernel")
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            fluid.FluidEngine("kernel").solve_batch(
+                [([5.0], [("h0",)], {"h0": 4.0})])
 
     def test_unknown_backend_rejected(self):
         with pytest.raises(ValueError, match="unknown fluid backend"):
@@ -410,6 +425,22 @@ class TestSweepInfra:
     def test_bad_mode_rejected(self):
         with pytest.raises(ValueError, match="thread.*process"):
             sweep(*self._grid(), mode="threads")
+
+    @pytest.mark.parametrize("where", ["policy", "sim_config"])
+    def test_process_mode_refuses_device_backend(self, where):
+        """A chip belongs to one process: no spawned worker may run a
+        device fluid backend, whether the policy or the config selects
+        it.  The refusal comes before any cell runs."""
+        scenarios, _ = self._grid()
+        if where == "policy":
+            policies = [Policy("default"),
+                        Policy("metronome", sim_backend="kernel")]
+            cfg = self.GRID_CFG
+        else:
+            policies = [Policy("default")]
+            cfg = dataclasses.replace(self.GRID_CFG, fluid_backend="jnp")
+        with pytest.raises(ValueError, match="one process"):
+            sweep(scenarios, policies, cfg, workers=2, mode="process")
 
     def test_cache_roundtrip_and_keying(self, tmp_path):
         from benchmarks import cache

@@ -265,12 +265,14 @@ class TestJointBatch:
                                         max_exhaustive=0)
         assert np.array_equal(cd_cached.shifts, cd_fresh.shifts)
 
-    def test_batch_kernel_backend_matches_numpy(self):
+    def test_batch_kernel_backend_matches_numpy(self, interpret_kernels):
         view, registry, links = self._j1_specs()
+        ran = interpret_kernels[("score_multilink", "interpret")]
         res_np = rotation.joint_solve_batch(
             [(view, links)], registry, backend="numpy")[0]
         res_k = rotation.joint_solve_batch(
             [(view, links)], registry, backend="kernel")[0]
+        assert interpret_kernels[("score_multilink", "interpret")] == ran + 1
         assert np.array_equal(res_np.shifts, res_k.shifts)
         assert res_np.score == pytest.approx(res_k.score, abs=1e-4)
 

@@ -181,19 +181,22 @@ class TestJointConflictOracle:
                                                ctrl.di_pre)
         assert ctrl.global_offsets_ms == want
 
-    def test_joint_solve_direct(self):
+    def test_joint_solve_direct(self, interpret_kernels):
         """joint_solve over the full J1 component: feasible on every link,
-        reference pinned at zero (Eq. 16), numpy == kernel backend."""
+        reference pinned at zero (Eq. 16), numpy == kernel backend (the
+        multi-link kernel in interpret mode)."""
         cluster, fw, ctrl = schedule_snapshot("J1")
         view = LinkView.from_registry(cluster, fw.registry)
         links = [l for l in view.planning_links()
                  if rotation.solve_link(view, fw.registry, l)[1] is not None]
+        ran = interpret_kernels[("score_multilink", "interpret")]
         res_np = rotation.joint_solve(view, fw.registry, links,
                                       backend="numpy")
         res_k = rotation.joint_solve(view, fw.registry, links,
                                      backend="kernel")
         assert res_np.feasible
         assert res_np.jobs[0] == "j1-hi" and res_np.shifts[0] == 0
+        assert interpret_kernels[("score_multilink", "interpret")] == ran + 1
         assert np.array_equal(res_np.shifts, res_k.shifts)
         assert res_np.score == pytest.approx(res_k.score, abs=1e-4)
 
