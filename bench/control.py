@@ -1,0 +1,209 @@
+"""The control of the ``correct`` comparison, and the faults it must catch.
+
+The control puts the plain reference fill in the program's place,
+computed in bfloat16, the precision below the float32 that the
+configurations state for the rate solve.  A sound comparison reads it as
+not correct.
+
+:func:`installed` patches one of these into the program for the length of
+a ``with`` block:
+
+- ``control``: the bfloat16 fill in place of the fill kernel;
+- ``frozen``: a chunk that returns without advancing simulated time (a
+  step that returns its state unchanged);
+- ``half_batch``: the fill leaves every second real problem of a batch
+  unsolved, at zero;
+- ``altered_rate``: one rate of every fill batch is altered by 1% where
+  it is produced;
+- ``altered_placement``: each admitted job's first pod is moved onto
+  its second pod's node;
+- ``slow_compute``: the event loop runs every compute phase 1% long;
+- ``skipped_step``: the event loop leaves one due job in 50 unstepped
+  until its next tick.
+
+The benchmark's own runs never import this module; :func:`main` takes the
+readings that the limits are set from.
+"""
+from __future__ import annotations
+
+import contextlib
+
+import numpy as np
+
+EPS_BF16 = 1e-2
+
+
+def fill_bf16(demands, routes, caps):
+    """Progressive filling over a ``(B, F, L)`` batch in bfloat16 (JAX)."""
+    import jax
+    import jax.numpy as jnp
+
+    bf = jnp.bfloat16
+    d = jnp.asarray(demands, bf)
+    r = jnp.asarray(routes, bf)
+    c = jnp.asarray(caps, bf)
+    f = d.shape[1]
+    big = jnp.asarray(1e30, jnp.float32).astype(bf)
+
+    def cond(s):
+        return jnp.logical_and(jnp.any(s[2] > 0), s[3] < f + 1)
+
+    def body(s):
+        rates, rem, act, i = s
+        counts = jnp.einsum("bfl,bf->bl", r, act)
+        ratio = jnp.where(counts > 0, rem / jnp.maximum(counts, 1), big)
+        head = jnp.where(act > 0, d - rates, big)
+        inc = jnp.maximum(jnp.minimum(ratio.min(axis=1), head.min(axis=1)),
+                          0).astype(bf)
+        rates = (rates + inc[:, None] * act).astype(bf)
+        rem = (rem - inc[:, None] * counts).astype(bf)
+        sat = (rem <= EPS_BF16).astype(bf)
+        blocked = jnp.einsum("bfl,bl->bf", r, sat) > 0
+        met = rates >= d - EPS_BF16
+        act = jnp.where(jnp.logical_or(met, blocked), 0, act).astype(bf)
+        return rates, rem, act, i + 1
+
+    act0 = (d > EPS_BF16).astype(bf)
+    out = jax.lax.while_loop(cond, body, (jnp.zeros_like(d), c, act0, 0))[0]
+    return out.astype(jnp.float32)
+
+
+_fill_bf16_jit = None
+
+
+def _control_fill(demands, routes, caps, interpret=None):
+    global _fill_bf16_jit
+    if _fill_bf16_jit is None:
+        import jax
+        _fill_bf16_jit = jax.jit(fill_bf16)
+    return np.asarray(_fill_bf16_jit(demands, routes, caps))
+
+
+@contextlib.contextmanager
+def installed(kind: str):
+    """Patch the program with the control or one fault while the block
+    runs."""
+    from repro.core import simulator
+    from repro.kernels import ops
+
+    saved = []
+
+    def patch(obj, name, value):
+        saved.append((obj, name, getattr(obj, name)))
+        setattr(obj, name, value)
+
+    if kind == "control":
+        patch(ops, "progressive_fill", _control_fill)
+        patch(ops, "progressive_fill_ref",
+              lambda d, r, c: _control_fill(d, r, c))
+    elif kind in ("half_batch", "altered_rate"):
+        for name in ("progressive_fill", "progressive_fill_ref"):
+            patch(ops, name, _broken(getattr(ops, name), kind))
+    elif kind == "slow_compute":
+        enter = simulator.ClusterSimulator._enter_compute
+
+        def slow(self, st, inject):
+            enter(self, st, inject)
+            st.phase_end += 0.01 * st.job.traffic.compute_ms
+            self._sync_job(st)
+
+        patch(simulator.ClusterSimulator, "_enter_compute", slow)
+    elif kind == "skipped_step":
+        step = simulator.ClusterSimulator._step_job
+        calls = [0]
+
+        def skipping(self, st):
+            calls[0] += 1
+            if calls[0] % 50:
+                step(self, st)
+
+        patch(simulator.ClusterSimulator, "_step_job", skipping)
+    elif kind == "frozen":
+        run = simulator.ClusterSimulator.run
+
+        def frozen(self):
+            if getattr(self, "_frozen_once", False):
+                return self._result()
+            self._frozen_once = True
+            return run(self)
+
+        patch(simulator.ClusterSimulator, "run", frozen)
+    elif kind == "altered_placement":
+        from repro.core import framework
+        schedule_job = framework.SchedulingFramework.schedule_job
+
+        def moved(self, job):
+            ok = schedule_job(self, job)
+            if ok and len(job.tasks) > 1:
+                job.tasks[0].node = job.tasks[1].node
+            return ok
+
+        patch(framework.SchedulingFramework, "schedule_job", moved)
+    else:
+        raise ValueError(f"unknown control or fault {kind!r}")
+    try:
+        yield
+    finally:
+        for obj, name, value in reversed(saved):
+            setattr(obj, name, value)
+
+
+def _broken(fn, kind: str):
+    def wrapped(demands, routes, caps, *a, **kw):
+        out = np.array(fn(demands, routes, caps, *a, **kw), copy=True)
+        live = np.argwhere(np.asarray(demands) > 0)
+        if kind == "half_batch":
+            rows = np.unique(live[:, 0])
+            out[rows[1::2]] = 0.0
+        elif len(live):
+            b, f = live[0]
+            out[b, f] *= 1.01
+        return out
+    return wrapped
+
+
+def main(argv=None) -> int:
+    """Readings for the limits: runs of one cell on the chip, one per seed,
+    in one process, sound or with the control or a fault installed; one
+    JSON line each with the numbers compared.
+
+        python3 bench/control.py --workload <cell> --seconds <s>
+            [--kind control|<fault>] --seeds <n> [<n> ...]
+    """
+    import argparse
+    import json
+    import sys
+    import time
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent
+    sys.path[:0] = [str(root / "src"), str(root)]
+    from bench import harness
+    from bench.spec import load_cell
+
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--kind", default=None)
+    ap.add_argument("--seeds", type=int, nargs="+", required=True)
+    ap.add_argument("--rehearse", action="store_true")
+    args = ap.parse_args(argv)
+    cell = load_cell(args.workload, root / "BENCHMARK.json")
+    for seed in args.seeds:
+        t = time.perf_counter()
+        ctx = (installed(args.kind) if args.kind
+               else contextlib.nullcontext())
+        with ctx:
+            out = harness.run(cell, seed=seed, seconds=args.seconds,
+                              trace=False, root=root, t_start=t,
+                              rehearse=args.rehearse)
+        print(json.dumps({"kind": args.kind or "sound", "seed": seed,
+                          "correct": out["correct"],
+                          "checks": out["checks"],
+                          "metrics": out["metrics"]
+                          or out.get("rehearsal_metrics")}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

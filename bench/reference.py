@@ -1,0 +1,252 @@
+"""Plain references that decide ``correct``.  Nothing here imports the
+program: each function takes the inputs a decision was made from and
+recomputes the answer the configuration's semantics call for.
+
+- :func:`fill` -- max-min fair rates by progressive filling (float64): all
+  unfrozen flows grow together, a flow freezes when its demand is met or a
+  link on its path is full.
+- :func:`least_allocated` -- the Kubernetes default scheduler's choice for
+  a job, pod by pod: NodeResourcesFit plus the spread cap filter, the
+  LeastAllocated score, ties to the lowest node index, all or nothing.
+- :func:`follow` -- the fluid model of the configuration, followed event
+  by event in float64 from the admissions: the completion time of every
+  iteration of every job.  :func:`progress_gap` compares it with the
+  program's.
+"""
+from __future__ import annotations
+
+from typing import Dict, List, Optional, Sequence, Tuple
+
+EPS = 1e-9
+RESOURCES = ("cpu", "mem", "gpu")
+
+
+def fill(demands: Sequence[float], paths: Sequence[Sequence[str]],
+         caps: Dict[str, float]) -> List[float]:
+    """Progressive-filling max-min fair rates (float64)."""
+    n = len(paths)
+    d = [float(x) for x in demands]
+    rem = {l: float(caps[l]) for p in paths for l in p}
+    rates = [0.0] * n
+    active = [i for i in range(n) if d[i] > EPS]
+    while active:
+        counts: Dict[str, int] = {}
+        for i in active:
+            for l in paths[i]:
+                counts[l] = counts.get(l, 0) + 1
+        inc = min(d[i] - rates[i] for i in active)
+        if counts:
+            inc = min(inc, min(rem[l] / c for l, c in counts.items()))
+        inc = max(inc, 0.0)
+        for i in active:
+            rates[i] += inc
+        for l, c in counts.items():
+            rem[l] -= inc * c
+        still = [i for i in active if rates[i] < d[i] - EPS
+                 and all(rem[l] > EPS for l in paths[i])]
+        if len(still) == len(active):
+            break
+        active = still
+    return rates
+
+
+def _fits(req: Sequence[float], free: Sequence[float]) -> bool:
+    return all(r <= f for r, f in zip(req, free))
+
+
+def least_allocated(rec: dict) -> Tuple[bool, Optional[List[str]]]:
+    """The default scheduler's outcome for one admission attempt ``rec``
+    (see ``probes.AdmissionRecorder``): (admitted, node per pod)."""
+    nodes = rec["nodes"]
+    free = {n: list(rec["free"][n]) for n in nodes}
+    per_node: Dict[str, int] = {}
+    placed: List[str] = []
+    for pod in rec["pods"]:
+        best, best_key = None, None
+        for idx, n in enumerate(nodes):
+            if pod["spread"] > 0 and per_node.get(n, 0) >= pod["spread"]:
+                continue
+            if not _fits(pod["req"], free[n]):
+                continue
+            cap = rec["capacity"][n]
+            terms = [(free[n][k] - pod["req"][k]) / cap[k]
+                     for k in range(len(RESOURCES)) if cap[k] > 0]
+            score = 100.0 * (sum(terms) / len(terms)) if terms else 0.0
+            key = (score, -idx)
+            if best_key is None or key > best_key:
+                best, best_key = n, key
+        if best is None:
+            return False, None
+        free[best] = [f - r for f, r in zip(free[best], pod["req"])]
+        per_node[best] = per_node.get(best, 0) + 1
+        placed.append(best)
+    return True, placed
+
+
+def least_allocated_mismatch(rec: dict) -> int:
+    """1 when the program's outcome differs from :func:`least_allocated`."""
+    ok, placed = least_allocated(rec)
+    if ok != rec["admitted"]:
+        return 1
+    return int(ok and placed != rec["placed"])
+
+
+ADMISSION_CHECKS = {
+    "least_allocated": least_allocated_mismatch,
+}
+
+
+# ----------------------------------------------------------------- progress
+def links_of(layout: dict) -> Tuple[Dict[str, float], Dict[str, str]]:
+    """(capacity of every link, leaf of every worker) of a configuration's
+    cluster: one NIC per worker, named after it; with ``leaves``, one
+    uplink per leaf (``uplink:<leaf>``) carrying its NICs over the
+    oversubscription factor."""
+    caps = {n["name"]: float(n["bw_gbps"]) for n in layout["nodes"]}
+    leaf_of: Dict[str, str] = {}
+    for leaf, members in layout.get("leaves", {}).items():
+        for m in members:
+            leaf_of[m] = leaf
+        caps[f"uplink:{leaf}"] = (sum(caps[m] for m in members)
+                                  / float(layout["oversubscription"]))
+    return caps, leaf_of
+
+
+def job_flows(nodes: Sequence[str], bw_gbps: float,
+              leaf_of: Dict[str, str]) -> List[Tuple[float, Tuple[str, ...]]]:
+    """(demand, path) of each flow of one communication phase: one flow
+    per worker the job uses, at the summed demand of its pods there, over
+    the worker's NIC and, when the job spans leaves, its leaf's uplink.  A
+    job on one worker synchronises locally and has none."""
+    used: Dict[str, int] = {}
+    for n in nodes:
+        used[n] = used.get(n, 0) + 1
+    if len(used) <= 1:
+        return []
+    leaves = {leaf_of.get(n) for n in used}
+    out = []
+    for n, k in used.items():
+        path = (n,)
+        if len(leaves) > 1:
+            path = (n, f"uplink:{leaf_of[n]}")
+        out.append((bw_gbps * k, path))
+    return out
+
+
+def follow(jobs: Dict[str, dict], start: dict, admissions: Sequence[tuple],
+           departures: Dict[str, float], layout: dict, until_ms: float,
+           startup_ms: float = 0.0) -> Dict[str, List[float]]:
+    """Completion time (ms) of every iteration that ends from
+    ``start["t_ms"]`` until ``until_ms``, per job.
+
+    ``jobs`` maps a job to its ``compute_ms``, ``comm_ms`` and per-pod
+    ``bw_gbps``.  ``start`` is the state followed from: its time and, per
+    job then admitted, its ``workers``, ``phase`` (``waiting``,
+    ``compute`` or ``comm``), the end of a timed phase (``end``, None
+    while flows move) and the Gb ``left`` on the flow of each worker.
+    ``admissions`` holds ``(time_ms, job, workers)`` of every later
+    admission in order; ``departures`` the time each job leaves.  A job
+    admitted at t starts at t + ``startup_ms``, computes for its compute
+    time, then moves ``demand x comm time`` over each of its flows at the
+    max-min fair rates of all flows then active; the iteration ends when
+    its last flow ends, and the next begins at once."""
+    caps, leaf_of = links_of(layout)
+    adm = sorted(admissions, key=lambda a: a[0])
+    dep = sorted((t, name) for name, t in departures.items())
+    t = float(start["t_ms"])
+    live: Dict[str, dict] = {}
+    flows: List[list] = []          # [job, demand, remaining Gb, path]
+    out: Dict[str, List[float]] = {}
+    for name, st in start["jobs"].items():
+        spec = jobs[name]
+        live[name] = {"phase": st["phase"], "end": st["end"],
+                      "flows": job_flows(st["workers"], spec["bw_gbps"],
+                                         leaf_of)}
+        out[name] = []
+        for demand, path in live[name]["flows"]:
+            left = st["left"].get(path[0], 0.0)
+            if st["phase"] == "comm" and left > EPS:
+                flows.append([name, demand, left, path])
+    rates: List[float] = [0.0] * len(flows)
+    ai = di = 0
+    dirty = True
+    while True:
+        if dirty:
+            rates = fill([f[1] for f in flows], [f[3] for f in flows], caps)
+            dirty = False
+        nxt = until_ms
+        if ai < len(adm):
+            nxt = min(nxt, adm[ai][0])
+        if di < len(dep):
+            nxt = min(nxt, dep[di][0])
+        for st in live.values():
+            if st["end"] is not None:
+                nxt = min(nxt, st["end"])
+        for f, r in zip(flows, rates):
+            if r > EPS:
+                nxt = min(nxt, t + f[2] / r * 1e3)
+        nxt = max(nxt, t)
+        dt = nxt - t
+        if dt > 0:
+            for f, r in zip(flows, rates):
+                f[2] -= min(f[2], r * dt / 1e3)
+        t = nxt
+        if t >= until_ms:
+            return out
+        while di < len(dep) and dep[di][0] <= t + EPS:
+            name = dep[di][1]
+            di += 1
+            if live.pop(name, None) is not None:
+                kept = [(f, r) for f, r in zip(flows, rates) if f[0] != name]
+                flows = [f for f, _ in kept]
+                rates = [r for _, r in kept]
+                dirty = True
+        while ai < len(adm) and adm[ai][0] <= t + EPS:
+            _, name, workers = adm[ai]
+            ai += 1
+            live[name] = {"phase": "waiting", "end": t + startup_ms,
+                          "flows": job_flows(workers, jobs[name]["bw_gbps"],
+                                             leaf_of)}
+            out[name] = []
+        if any(f[2] <= EPS for f in flows):
+            kept = [(f, r) for f, r in zip(flows, rates) if f[2] > EPS]
+            flows = [f for f, _ in kept]
+            rates = [r for _, r in kept]
+            dirty = True
+        busy = {f[0] for f in flows}
+        for name, st in live.items():
+            spec = jobs[name]
+            due = st["end"] is not None and t + EPS >= st["end"]
+            if st["phase"] == "waiting" and due:
+                st.update(phase="compute", end=t + spec["compute_ms"])
+            elif st["phase"] == "compute" and due:
+                if st["flows"]:
+                    for demand, path in st["flows"]:
+                        flows.append([name, demand,
+                                      demand * spec["comm_ms"] / 1e3, path])
+                        rates.append(0.0)
+                    st.update(phase="comm", end=None)
+                    dirty = True
+                else:
+                    st.update(phase="comm", end=t + spec["comm_ms"])
+            elif st["phase"] == "comm" and (
+                    due or (st["end"] is None and name not in busy)):
+                out[name].append(t)
+                st.update(phase="compute", end=t + spec["compute_ms"])
+
+
+def progress_gap(program: Dict[str, List[float]],
+                 reference: Dict[str, List[float]], until_ms: float) -> float:
+    """Widest gap (ms) between the program's and the reference's
+    completion time of one iteration, over every job.  Where one side
+    completed an iteration that the other did not by ``until_ms``, the gap
+    is at least the time from that completion to ``until_ms``."""
+    gap = 0.0
+    for name in set(program) | set(reference):
+        a, b = program.get(name, []), reference.get(name, [])
+        k = min(len(a), len(b))
+        for x, y in zip(a[:k], b[:k]):
+            gap = max(gap, abs(x - y))
+        for x in a[k:] + b[k:]:
+            gap = max(gap, until_ms - x)
+    return gap

@@ -1,0 +1,60 @@
+"""The comparison that decides ``correct`` passes sound runs and fails the
+control and every fault, on test-sized cells in CPU rehearsal."""
+from __future__ import annotations
+
+import time
+
+import pytest
+
+from bench import control, harness
+from bench.spec import load_cell
+
+TRACE = "testbed-k8s.tiny-trace"
+PEAK = "tiny-fabric.tiny-peak"
+
+
+def _run(root, cell, seed, kind=None):
+    c = load_cell(cell, root / "BENCHMARK.json", root / "bench")
+    t = time.perf_counter()
+    if kind is None:
+        return harness.run(c, seed=seed, seconds=1.0, trace=False,
+                           root=root, t_start=t, rehearse=True,
+                           bench_dir=root / "bench")
+    with control.installed(kind):
+        return harness.run(c, seed=seed, seconds=1.0, trace=False,
+                           root=root, t_start=t, rehearse=True,
+                           bench_dir=root / "bench")
+
+
+@pytest.mark.parametrize("cell", [TRACE, PEAK])
+@pytest.mark.parametrize("seed", [4000000021, 4000000022])
+def test_sound_run_is_correct(tiny_checkout, cell, seed):
+    out = _run(tiny_checkout, cell, seed)
+    assert out["correct"], out["checks"]
+
+
+@pytest.mark.parametrize("cell,kind,number", [
+    (PEAK, "control", "fill_err_gbps"),
+    (TRACE, "control", "progress_gap_ms"),
+])
+def test_control_is_not_correct(tiny_checkout, cell, kind, number):
+    out = _run(tiny_checkout, cell, 4000000023, kind)
+    assert not out["correct"]
+    c = out["checks"][number]
+    assert c["value"] > c["limit"]
+
+
+@pytest.mark.parametrize("cell", [TRACE, PEAK])
+@pytest.mark.parametrize("kind,number", [
+    ("frozen", "clock_gap_ms"),
+    ("half_batch", "fill_err_gbps"),
+    ("altered_rate", "fill_err_gbps"),
+    ("altered_placement", "bad_admissions"),
+    ("slow_compute", "progress_gap_ms"),
+    ("skipped_step", "progress_gap_ms"),
+])
+def test_fault_is_not_correct(tiny_checkout, cell, kind, number):
+    out = _run(tiny_checkout, cell, 4000000024, kind)
+    assert not out["correct"]
+    c = out["checks"][number]
+    assert c["value"] > c["limit"]
