@@ -38,7 +38,9 @@ must reproduce the seed exactly.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -46,6 +48,57 @@ import numpy as np
 EPS = 1e-9
 
 BACKENDS = ("python", "jnp", "kernel")
+
+
+# ---------------------------------------------------------------------------
+# program spans and timers (SimConfig.profile)
+# ---------------------------------------------------------------------------
+
+def trace_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session records,
+    else None.  Callers look it up once per run and hand it down, so a tick
+    pays no check; jax is imported here and not with the module, so
+    ``repro.core`` stays importable without it."""
+    try:
+        from jax import profiler
+    except ImportError:
+        return None
+    annotation = profiler.TraceAnnotation
+    return annotation if annotation.is_enabled() else None
+
+
+_UNTIMED = contextlib.nullcontext()
+
+
+class _Interval:
+    __slots__ = ("stats", "field", "span", "t0")
+
+    def __init__(self, stats, field: str, span) -> None:
+        self.stats, self.field, self.span = stats, field, span
+
+    # the span's own cost falls inside the interval, so that a parent's
+    # time outside its timed parts holds no tracing cost
+    def __enter__(self) -> None:
+        self.t0 = time.perf_counter()
+        if self.span is not None:
+            self.span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        if self.span is not None:
+            self.span.__exit__(*exc)
+        dt = time.perf_counter() - self.t0
+        setattr(self.stats, self.field, getattr(self.stats, self.field) + dt)
+
+
+def interval(stats, field: str, annotate, name: str):
+    """Context manager that adds the seconds of its body to
+    ``stats.<field>`` and, where ``annotate`` (see :func:`trace_annotation`)
+    is given, spans the body as ``name`` on the profiler's clock.  With
+    ``stats=None`` it does nothing and allocates nothing."""
+    if stats is None:
+        return _UNTIMED
+    return _Interval(stats, field,
+                     annotate(name) if annotate is not None else None)
 
 
 # ---------------------------------------------------------------------------
@@ -220,6 +273,8 @@ def fill_many(
     backend: str = "jnp",
     interpret: Optional[bool] = None,
     pad_to: Optional[Tuple[int, int]] = None,
+    timing: Optional["FluidStats"] = None,
+    annotate=None,
 ) -> List[np.ndarray]:
     """Solve many fill problems in ONE batched dispatch.
 
@@ -239,33 +294,40 @@ def fill_many(
 
     This is the production-trace throughput path: thousands of active-set
     snapshots of a 10k-job trace fill together instead of one per-flow
-    Python loop each (``benchmarks/bench_trace_throughput.py``)."""
+    Python loop each (``benchmarks/bench_trace_throughput.py``).
+
+    ``timing`` (a :class:`FluidStats`) books padding and unpadding as
+    ``pack_s`` and the fill, until its rates are on the host, as
+    ``device_s``; ``annotate`` spans them (see :func:`interval`)."""
     if backend not in ("jnp", "kernel"):
         raise ValueError(f"fill_many wants a vectorized backend, got {backend!r}")
     if not problems:
         return []
     from repro.kernels import ops as kops  # deferred: core stays jax-free
 
-    b = len(problems)
-    f_max = max(max(p[0].shape[0] for p in problems), 1)
-    l_max = max(max(p[2].shape[0] for p in problems), 1)
-    if pad_to is not None:
-        f_max = max(f_max, int(pad_to[0]))
-        l_max = max(l_max, int(pad_to[1]))
-    d = np.zeros((b, f_max), dtype=np.float32)
-    routes = np.zeros((b, f_max, l_max), dtype=np.float32)
-    caps = np.ones((b, l_max), dtype=np.float32)
-    for i, (di, ri, ci) in enumerate(problems):
-        fi, li = ri.shape
-        d[i, :fi] = di
-        routes[i, :fi, :li] = ri
-        caps[i, :li] = ci
-    if backend == "jnp":
-        out = kops.progressive_fill_ref(d, routes, caps)
-    else:
-        out = kops.progressive_fill(d, routes, caps, interpret=interpret)
-    return [np.asarray(out[i, : p[0].shape[0]], dtype=float)
-            for i, p in enumerate(problems)]
+    with interval(timing, "pack_s", annotate, "fluid.pack"):
+        b = len(problems)
+        f_max = max(max(p[0].shape[0] for p in problems), 1)
+        l_max = max(max(p[2].shape[0] for p in problems), 1)
+        if pad_to is not None:
+            f_max = max(f_max, int(pad_to[0]))
+            l_max = max(l_max, int(pad_to[1]))
+        d = np.zeros((b, f_max), dtype=np.float32)
+        routes = np.zeros((b, f_max, l_max), dtype=np.float32)
+        caps = np.ones((b, l_max), dtype=np.float32)
+        for i, (di, ri, ci) in enumerate(problems):
+            fi, li = ri.shape
+            d[i, :fi] = di
+            routes[i, :fi, :li] = ri
+            caps[i, :li] = ci
+    with interval(timing, "device_s", annotate, "fluid.device"):
+        if backend == "jnp":
+            out = kops.progressive_fill_ref(d, routes, caps)
+        else:
+            out = kops.progressive_fill(d, routes, caps, interpret=interpret)
+    with interval(timing, "pack_s", annotate, "fluid.pack"):
+        return [np.asarray(out[i, : p[0].shape[0]], dtype=float)
+                for i, p in enumerate(problems)]
 
 
 def fill_corpus(
@@ -276,6 +338,8 @@ def fill_corpus(
     chunk: int = 64,
     bucket_shapes: bool = False,
     stats: Optional[CorpusStats] = None,
+    timing: Optional["FluidStats"] = None,
+    annotate=None,
 ) -> List[np.ndarray]:
     """Solve a large, ragged fill-problem corpus with size-bucketed batches.
 
@@ -295,40 +359,46 @@ def fill_corpus(
     Batch padding uses neutral dummy problems (one zero-demand flow).
 
     ``stats`` (a :class:`CorpusStats`) accumulates bucket occupancy /
-    padding waste so the batching losses are observable per run."""
+    padding waste so the batching losses are observable per run.
+    ``timing`` and ``annotate`` book this function's host work as
+    ``pack_s``, as :func:`fill_many` does."""
     if not problems:
         return []
-    order = sorted(range(len(problems)), key=lambda i: problems[i][0].shape[0])
-    out: List[Optional[np.ndarray]] = [None] * len(problems)
-    chunk = max(1, int(chunk))
-    if stats is not None:
-        stats.calls += 1
-        stats.problems += len(problems)
-        stats.flow_used += sum(p[0].shape[0] for p in problems)
-        stats.link_used += sum(p[2].shape[0] for p in problems)
-    dummy = (np.zeros(1, dtype=np.float32),
-             np.zeros((1, 1), dtype=np.float32),
-             np.ones(1, dtype=np.float32))
-    for s in range(0, len(order), chunk):
-        idx = order[s:s + chunk]
-        batch = [problems[i] for i in idx]
-        pad_to = None
-        if bucket_shapes:
-            pad_to = (_round_pow2(max(p[0].shape[0] for p in batch)),
-                      _round_pow2(max(p[2].shape[0] for p in batch)))
-            batch = batch + [dummy] * (chunk - len(batch))
-        rates = fill_many(batch, backend=backend, interpret=interpret,
-                          pad_to=pad_to)
+    with interval(timing, "pack_s", annotate, "fluid.pack"):
+        order = sorted(range(len(problems)),
+                       key=lambda i: problems[i][0].shape[0])
+        out: List[Optional[np.ndarray]] = [None] * len(problems)
+        chunk = max(1, int(chunk))
         if stats is not None:
-            stats.buckets += 1
-            f_pad = pad_to[0] if pad_to else max(
-                max(p[0].shape[0] for p in batch), 1)
-            l_pad = pad_to[1] if pad_to else max(
-                max(p[2].shape[0] for p in batch), 1)
-            stats.flow_slots += len(batch) * f_pad
-            stats.link_slots += len(batch) * l_pad
-        for i, r in zip(idx, rates):
-            out[i] = r
+            stats.calls += 1
+            stats.problems += len(problems)
+            stats.flow_used += sum(p[0].shape[0] for p in problems)
+            stats.link_used += sum(p[2].shape[0] for p in problems)
+        dummy = (np.zeros(1, dtype=np.float32),
+                 np.zeros((1, 1), dtype=np.float32),
+                 np.ones(1, dtype=np.float32))
+    for s in range(0, len(order), chunk):
+        with interval(timing, "pack_s", annotate, "fluid.pack"):
+            idx = order[s:s + chunk]
+            batch = [problems[i] for i in idx]
+            pad_to = None
+            if bucket_shapes:
+                pad_to = (_round_pow2(max(p[0].shape[0] for p in batch)),
+                          _round_pow2(max(p[2].shape[0] for p in batch)))
+                batch = batch + [dummy] * (chunk - len(batch))
+        rates = fill_many(batch, backend=backend, interpret=interpret,
+                          pad_to=pad_to, timing=timing, annotate=annotate)
+        with interval(timing, "pack_s", annotate, "fluid.pack"):
+            if stats is not None:
+                stats.buckets += 1
+                f_pad = pad_to[0] if pad_to else max(
+                    max(p[0].shape[0] for p in batch), 1)
+                l_pad = pad_to[1] if pad_to else max(
+                    max(p[2].shape[0] for p in batch), 1)
+                stats.flow_slots += len(batch) * f_pad
+                stats.link_slots += len(batch) * l_pad
+            for i, r in zip(idx, rates):
+                out[i] = r
     return out  # type: ignore[return-value]
 
 
@@ -381,11 +451,20 @@ def affinity_components(paths: Sequence[Tuple[str, ...]]) -> List[List[int]]:
 
 @dataclasses.dataclass
 class FluidStats:
-    """Memo counters of one engine (incremental re-fill observability)."""
+    """Memo counters of one engine (incremental re-fill observability) and,
+    while ``FluidEngine.timed`` is on, the seconds of ``solve_batch``:
+    ``batch_s`` its whole body; ``key_s`` content keys, memo lookups and
+    memo stores; ``pack_s`` the host side of the fill (problem matrices,
+    sorting, dummies, padding, unpadding); ``device_s`` from the call into
+    the fill until its rates are on the host, the one part in which the
+    device works."""
 
     hits: int = 0
     misses: int = 0
-    solves: int = 0  # non-incremental full solves
+    batch_s: float = 0.0
+    key_s: float = 0.0
+    pack_s: float = 0.0
+    device_s: float = 0.0
 
 
 class FluidEngine:
@@ -417,6 +496,10 @@ class FluidEngine:
         self._memo: Dict[tuple, np.ndarray] = {}
         self.stats = FluidStats()
         self.corpus_stats = CorpusStats()
+        # FluidStats' timers run while ``timed`` is on; ``annotate`` (a
+        # TraceAnnotation, see trace_annotation) also spans what they time
+        self.timed = False
+        self.annotate = None
         # oracle-parity sampling (bench_dynamic_throughput): with
         # sample_stride > 0 every stride-th solve_batch problem is kept as
         # (demands, paths, caps, rates) for offline fill_python comparison
@@ -454,6 +537,16 @@ class FluidEngine:
         one per component.  Returns per-problem rate vectors in caller
         order.  Returned arrays are shared with the memo: treat as
         read-only."""
+        timing = self.stats if self.timed else None
+        ann = self.annotate if timing is not None else None
+        if ann is not None:
+            batch_span = ann("fluid.solve_batch")
+            batch_span.__enter__()
+        if timing is not None:
+            t_start = time.perf_counter()
+            if ann is not None:
+                key_span = ann("fluid.key")
+                key_span.__enter__()
         out: List[Optional[np.ndarray]] = [None] * len(problems)
         keys: List[Optional[tuple]] = [None] * len(problems)
         miss: List[int] = []
@@ -470,6 +563,10 @@ class FluidEngine:
                     out[i] = hit
                     continue
             miss.append(i)
+        if timing is not None:
+            if ann is not None:
+                key_span.__exit__(None, None, None)
+            timing.key_s += time.perf_counter() - t_start
         if miss:
             self.stats.misses += len(miss)
             if self.backend == "python":
@@ -477,23 +574,30 @@ class FluidEngine:
                     d, p, c = problems[i]
                     out[i] = fill_python(np.asarray(d, dtype=float), p, c)
             else:
-                mats = [problem_matrix(*problems[i])[:3] for i in miss]
+                with interval(timing, "pack_s", ann, "fluid.pack"):
+                    mats = [problem_matrix(*problems[i])[:3] for i in miss]
                 rates = fill_corpus(mats, backend=self.backend,
                                     bucket_shapes=True,
-                                    stats=self.corpus_stats)
+                                    stats=self.corpus_stats,
+                                    timing=timing, annotate=ann)
                 for i, r in zip(miss, rates):
                     out[i] = r
             if self.incremental:
-                for i in miss:
-                    if len(self._memo) >= self.memo_max:
-                        self._memo.clear()
-                    self._memo[keys[i]] = out[i]
+                with interval(timing, "key_s", ann, "fluid.key"):
+                    for i in miss:
+                        if len(self._memo) >= self.memo_max:
+                            self._memo.clear()
+                        self._memo[keys[i]] = out[i]
         if self.sample_stride > 0:
             for i, prob in enumerate(problems):
                 self._sample_seen += 1
                 if (self._sample_seen % self.sample_stride == 0
                         and len(self.samples) < self.sample_max):
                     self.samples.append((*prob, out[i]))
+        if timing is not None:
+            timing.batch_s += time.perf_counter() - t_start
+        if ann is not None:
+            batch_span.__exit__(None, None, None)
         return out  # type: ignore[return-value]
 
     # --------------------------------------------------------------- internals
@@ -501,7 +605,6 @@ class FluidEngine:
                      cap_of: Callable[[str], float]) -> None:
         """The seed's ``_assign_rates`` body, verbatim (python backend) or
         one global vectorized solve (jnp/kernel with incremental off)."""
-        self.stats.solves += 1
         if self.backend == "python":
             if all(len(f.links) == 1 for f in flows):
                 by_link: Dict[str, List] = {}

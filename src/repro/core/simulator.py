@@ -37,7 +37,7 @@ from . import topology
 from .cluster import Cluster
 from .contention import LinkView
 from .controller import StopAndWaitController
-from .fluid import FluidEngine
+from .fluid import FluidEngine, interval, trace_annotation
 # rate-sharing primitives live in the backend-swappable fluid engine now;
 # re-exported here because they are part of the simulator's historical API
 from .fluid import _max_min_fair, _progressive_fill  # noqa: F401
@@ -113,7 +113,16 @@ class SimProfile:
     ``SimResult.profile`` and surfaced as rows of the dynamic-throughput
     bench artifact.  ``solves`` counts rate re-solves actually performed,
     ``skipped_assigns`` ticks where nothing was dirty — their ratio is the
-    dirty-tracking win."""
+    dirty-tracking win.
+
+    Inside ``assign`` (array loop, vectorized backends): ``components_s``
+    finds the affinity components (``components`` of them) and
+    ``problems_s`` builds the fill problems of the dirty ones
+    (``dirty_components``) before ``FluidEngine.solve_batch``, whose parts
+    ``FluidStats`` times.  ``admit_s`` is ``_try_schedule``'s body
+    (framework, offline recalculation, admission, realign); it is booked
+    also inside the phase that called it (``events`` for arrivals, ``step``
+    for pending-queue retries after a job completes)."""
 
     loop: str = ""
     ticks: int = 0
@@ -126,6 +135,11 @@ class SimProfile:
     steps: int = 0
     solves: int = 0
     skipped_assigns: int = 0
+    components_s: float = 0.0
+    problems_s: float = 0.0
+    components: int = 0
+    dirty_components: int = 0
+    admit_s: float = 0.0
 
     def as_dict(self) -> Dict[str, object]:
         return dataclasses.asdict(self)
@@ -296,6 +310,15 @@ class _FlowTable:
         self._free.append(s)
 
 
+def _next_span(span, annotate, name: str):
+    """Close ``span`` (if any) and open one named ``name`` after it."""
+    if span is not None:
+        span.__exit__(None, None, None)
+    span = annotate(name)
+    span.__enter__()
+    return span
+
+
 class ClusterSimulator:
     def __init__(
         self,
@@ -359,6 +382,9 @@ class ClusterSimulator:
         # bumps it), so steady-state iterations skip the rebuild
         self.fluid = FluidEngine(backend=config.fluid_backend,
                                  incremental=config.fluid_incremental)
+        self.fluid.timed = config.profile
+        # TraceAnnotation while a profiler session records (set per run())
+        self._annotate = None
         self._caps_fn: Optional[Callable[[str], float]] = None
         self._caps_epoch: int = -1
         self._events = collections.deque(
@@ -439,6 +465,12 @@ class ClusterSimulator:
 
     # ------------------------------------------------------- online arrivals
     def _try_schedule(self, wl) -> bool:
+        if self.profile is None:
+            return self._schedule(wl)
+        with interval(self.profile, "admit_s", self._annotate, "sim.admit"):
+            return self._schedule(wl)
+
+    def _schedule(self, wl) -> bool:
         assert self.framework is not None
         if self.framework.schedule_workload(wl):
             if self.controller is not None and self.offline_recalc:
@@ -652,6 +684,11 @@ class ClusterSimulator:
     # ------------------------------------------------------------- main loop
     def run(self) -> SimResult:
         self._validate_events()
+        # program spans go on the profiler's clock only while a session
+        # records: looked up once per call, never per tick
+        self._annotate = (trace_annotation() if self.profile is not None
+                          else None)
+        self.fluid.annotate = self._annotate
         if self._array_mode:
             return self._run_array()
         return self._run_legacy()
@@ -790,15 +827,21 @@ class ClusterSimulator:
         duration = cfg.duration_ms
         prof = self.profile
         perf = time.perf_counter
+        ann = self._annotate
+        span = None  # the open phase's span, while ann records
         tbl = self._flows
         dv = self._delivered_vec
         link_index = self._link_index
         while self.now < duration:
             t0 = perf() if prof is not None else 0.0
+            if ann is not None:
+                span = _next_span(span, ann, "sim.assign")
             self._assign_rates_array()
             if prof is not None:
                 t1 = perf()
                 prof.assign_s += t1 - t0
+                if ann is not None:
+                    span = _next_span(span, ann, "sim.next_event")
 
             # next event time: one min over job mirrors + one over flows
             nxt = duration
@@ -824,6 +867,8 @@ class ClusterSimulator:
             if prof is not None:
                 t2 = perf()
                 prof.next_event_s += t2 - t1
+                if ann is not None:
+                    span = _next_span(span, ann, "sim.advance")
 
             # advance flows; delivered-GB scatter replays the seed's
             # (job, flow, path-link) accumulation order, then background
@@ -852,6 +897,8 @@ class ClusterSimulator:
                 prof.ticks += 1
             if self.now >= duration:
                 break
+            if ann is not None:
+                span = _next_span(span, ann, "sim.events")
 
             # dynamic-environment events, in timestamp order
             while self._events and self._events[0].time_ms <= self.now + EPS:
@@ -864,6 +911,8 @@ class ClusterSimulator:
             if prof is not None:
                 t4 = perf()
                 prof.events_s += t4 - t3
+                if ann is not None:
+                    span = _next_span(span, ann, "sim.step")
 
             # job phase transitions: only DUE jobs step (the seed steps
             # every job every tick, but _step_job is a strict no-op unless
@@ -885,6 +934,8 @@ class ClusterSimulator:
             if prof is not None:
                 prof.step_s += perf() - t4
                 prof.steps += int(due.size)
+        if span is not None:
+            span.__exit__(None, None, None)
         return self._result()
 
     # ------------------------------------------- dirty-component rate solves
@@ -951,7 +1002,17 @@ class ClusterSimulator:
         ``fluid.solve_batch`` call (= at most one shape-bucketed
         ``fill_corpus`` dispatch per tick)."""
         tbl = self._flows
+        prof = self.profile
+        ann = self._annotate
+        if prof is not None:
+            t0 = time.perf_counter()
+            if ann is not None:
+                span = _next_span(None, ann, "fluid.components")
         comps = self._components(act)
+        if prof is not None:
+            t1 = time.perf_counter()
+            if ann is not None:
+                span = _next_span(span, ann, "fluid.problems")
         dirty_vec = None
         if not self._all_dirty:
             dirty_vec = np.zeros(len(self._link_ids), dtype=bool)
@@ -968,6 +1029,13 @@ class ClusterSimulator:
             caps = {l: cap_of(l) for p in paths for l in p}
             problems.append((tbl.demand[comp], paths, caps))
             targets.append(comp)
+        if prof is not None:
+            if ann is not None:
+                span.__exit__(None, None, None)
+            prof.components_s += t1 - t0
+            prof.problems_s += time.perf_counter() - t1
+            prof.components += len(comps)
+            prof.dirty_components += len(problems)
         if problems:
             for comp, rates in zip(targets, self.fluid.solve_batch(problems)):
                 tbl.rate[comp] = rates

@@ -11,9 +11,9 @@ Four layers of evidence that the vectorized hot path is safe:
     events sharing one timestamp, an arrival tied exactly with an event;
   * structured once-per-offender warnings for events naming unknown
     links/jobs (previously silently dropped);
-  * the machinery that rides along: ``SimConfig.profile`` phase counters,
-    ``FluidEngine.solve_batch`` memoization, and shape-bucketed
-    ``fill_corpus`` batching with occupancy stats.
+  * the machinery that rides along: ``SimConfig.profile`` phase counters
+    and the program's spans, ``FluidEngine.solve_batch`` memoization, and
+    shape-bucketed ``fill_corpus`` batching with occupancy stats.
 """
 import dataclasses
 import math
@@ -337,6 +337,119 @@ class TestProfile:
         sim = ClusterSimulator(small_cluster(), self._jobs(),
                                SimConfig(duration_ms=2_000.0))
         assert sim.run().profile is None
+
+    def _online(self, profile=True, duration_ms=30_000.0):
+        """Online arrivals on the testbed under the default scheduler, with
+        the vectorized fill: admissions, dirty components and fills."""
+        from repro.core.experiment import build_scheduler
+        from repro.core.trace import (generate_trace, trace_departure_events,
+                                      trace_to_jobs)
+        trace = generate_trace(
+            MODEL_FLEET, duration_s=600, total_gpus=13, target_load=0.9,
+            seed=2, job_duration_range_s=(60, 120))[:6]
+        cluster, _, _ = make_snapshot("S1")
+        wls = []
+        for j in trace_to_jobs(trace, MODEL_FLEET, time_scale=1.0,
+                               open_ended=True):
+            j.workload = j.name
+            for t in j.tasks:
+                t.workload = j.name
+            wls.append(Workload(name=j.name, jobs=[j]))
+        plugin, controller = build_scheduler(Policy("default"))
+        fw = SchedulingFramework(cluster, plugin)
+        cfg = SimConfig(duration_ms=duration_ms, seed=0, jitter_std=0.0,
+                        fluid_backend="jnp", profile=profile)
+        return ClusterSimulator(
+            cluster, [], cfg, controller=controller, registry=fw.registry,
+            framework=fw, arrivals=wls,
+            events=trace_departure_events(trace, time_scale=1.0))
+
+    def test_profile_splits_assign_and_admission(self):
+        """The parts of ``assign`` and of the rate solve are set and nest
+        inside their parents; admission is booked where arrivals come."""
+        sim = self._online()
+        p = sim.run().profile
+        st = sim.fluid.stats
+        assert p.components_s > 0.0 and p.problems_s > 0.0
+        assert 0 < p.dirty_components <= p.components
+        assert p.components_s + p.problems_s + st.batch_s <= p.assign_s
+        assert st.misses > 0
+        assert min(st.key_s, st.pack_s, st.device_s) > 0.0
+        assert st.key_s + st.pack_s + st.device_s <= st.batch_s
+        assert 0.0 < p.admit_s <= p.events_s + p.step_s
+
+    def test_profile_off_keeps_new_timers_at_zero(self):
+        sim = self._online(profile=False)
+        assert sim.run().profile is None
+        st = sim.fluid.stats
+        assert st.misses > 0  # the solve ran
+        assert (st.batch_s, st.key_s, st.pack_s, st.device_s) == (0.0,) * 4
+
+    def test_no_annotation_without_a_profiler_session(self, monkeypatch):
+        """Without a profiler session no span is constructed; with one
+        (``is_enabled`` forced true) the same counter sees them."""
+        import jax.profiler
+
+        made = []
+
+        class Counting(jax.profiler.TraceAnnotation):
+            def __init__(self, name, **kw):
+                made.append(name)
+                super().__init__(name, **kw)
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+        self._online(duration_ms=10_000.0).run()
+        assert made == []
+        monkeypatch.setattr(Counting, "is_enabled",
+                            staticmethod(lambda: True))
+        self._online(duration_ms=10_000.0).run()
+        assert {"sim.assign", "sim.admit", "fluid.components",
+                "fluid.solve_batch"} <= set(made)
+
+    def test_spans_on_the_profiler_clock(self, tmp_path):
+        """Under a CPU profiler session the host plane holds one
+        ``sim.assign`` span per tick and every ``fluid.*`` span inside
+        one; admission spans lie inside the events or step phase."""
+        import bisect
+
+        import jax
+
+        sim = self._online(duration_ms=10_000.0)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            res = sim.run()
+        finally:
+            jax.profiler.stop_trace()
+        path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+        data = jax.profiler.ProfileData.from_file(str(path))
+        spans = {}
+        for plane in data.planes:
+            if not plane.name.startswith("/host"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(("sim.", "fluid.")):
+                        spans.setdefault(ev.name, []).append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns))
+
+        def inside(child, parents):
+            parents = sorted(parents)
+            starts = [s for s, _ in parents]
+            for s, e in child:
+                k = bisect.bisect_right(starts, s) - 1
+                assert k >= 0 and e <= parents[k][1], (s, e)
+
+        assert len(spans["sim.assign"]) == res.profile.ticks
+        fluid_names = {n for n in spans if n.startswith("fluid.")}
+        assert {"fluid.components", "fluid.problems", "fluid.solve_batch",
+                "fluid.key", "fluid.pack", "fluid.device"} <= fluid_names
+        for name in fluid_names:
+            inside(spans[name], spans["sim.assign"])
+        for name in ("fluid.key", "fluid.pack", "fluid.device"):
+            inside(spans[name], spans["fluid.solve_batch"])
+        inside(spans["sim.admit"], spans["sim.events"] + spans["sim.step"])
 
 
 # ---------------------------------------------------------------------------

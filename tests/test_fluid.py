@@ -327,6 +327,89 @@ class TestFillCorpus:
 
 
 # ---------------------------------------------------------------------------
+# timers and spans of the rate solve (SimConfig.profile)
+# ---------------------------------------------------------------------------
+
+class _Recorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each span's
+    opening and closing in order."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        rec = self
+
+        class Span:
+            def __enter__(self):
+                rec.log.append(("open", name))
+
+            def __exit__(self, *exc):
+                rec.log.append(("close", name))
+
+        return Span()
+
+
+def _timers(stats):
+    return (stats.batch_s, stats.key_s, stats.pack_s, stats.device_s)
+
+
+class TestSolveTimers:
+    def _problems(self, n=6, seed=21):
+        rng = np.random.default_rng(seed)
+        return [random_problem(rng, fabric=True) for _ in range(n)]
+
+    def test_parts_nest_inside_the_batch(self):
+        eng = fluid.FluidEngine("jnp")
+        eng.timed = True
+        probs = self._problems()
+        eng.solve_batch(probs)          # misses: key, pack and device
+        st = eng.stats
+        assert st.misses == len(probs)
+        assert min(_timers(st)) > 0.0
+        assert st.key_s + st.pack_s + st.device_s <= st.batch_s
+        device = st.device_s
+        eng.solve_batch(probs)          # memo hits: no fill
+        assert st.hits == len(probs)
+        assert st.device_s == device
+
+    def test_untimed_engine_books_nothing(self):
+        eng = fluid.FluidEngine("jnp")
+        eng.solve_batch(self._problems())
+        assert _timers(eng.stats) == (0.0,) * 4
+        assert "solves" not in dataclasses.asdict(eng.stats)
+
+    def test_timing_leaves_the_answers_alone(self):
+        mats = [fluid.problem_matrix(*p)[:3] for p in self._problems(9)]
+        stats = fluid.FluidStats()
+        timed = fluid.fill_corpus(mats, backend="jnp", chunk=4,
+                                  bucket_shapes=True, timing=stats)
+        plain = fluid.fill_corpus(mats, backend="jnp", chunk=4,
+                                  bucket_shapes=True)
+        for a, b in zip(timed, plain):
+            np.testing.assert_array_equal(a, b)
+        assert stats.pack_s > 0.0 and stats.device_s > 0.0
+        assert (stats.batch_s, stats.key_s) == (0.0, 0.0)
+
+    def test_spans_nest_in_solve_batch(self):
+        """``fluid.solve_batch`` opens first and closes last; key, pack and
+        device spans open and close inside it, one at a time."""
+        rec = _Recorder()
+        eng = fluid.FluidEngine("jnp")
+        eng.timed, eng.annotate = True, rec
+        eng.solve_batch(self._problems())
+        log = rec.log
+        assert log[0] == ("open", "fluid.solve_batch")
+        assert log[-1] == ("close", "fluid.solve_batch")
+        inner = log[1:-1]
+        assert {n for _, n in inner} == {"fluid.key", "fluid.pack",
+                                         "fluid.device"}
+        # siblings: each span closes before the next one opens
+        assert inner[0::2] == [("open", n) for _, n in inner[0::2]]
+        assert inner[1::2] == [("close", n) for _, n in inner[0::2]]
+
+
+# ---------------------------------------------------------------------------
 # production trace generator
 # ---------------------------------------------------------------------------
 
