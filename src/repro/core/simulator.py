@@ -119,10 +119,14 @@ class SimProfile:
     finds the affinity components (``components`` of them) and
     ``problems_s`` builds the fill problems of the dirty ones
     (``dirty_components``) before ``FluidEngine.solve_batch``, whose parts
-    ``FluidStats`` times.  ``admit_s`` is ``_try_schedule``'s body
-    (framework, offline recalculation, admission, realign); it is booked
-    also inside the phase that called it (``events`` for arrivals, ``step``
-    for pending-queue retries after a job completes)."""
+    ``FluidStats`` times.  Those parts, and both component counters, run
+    only on ticks the whole-tick rate memo misses: ``rate_memo_hits`` and
+    ``rate_memo_misses`` split the vectorized path's ``solves`` between the
+    memo's answers and ``_assign_vectorized`` (DESIGN.md section 17).
+    ``admit_s`` is ``_try_schedule``'s body (framework, offline
+    recalculation, admission, realign); it is booked also inside the phase
+    that called it (``events`` for arrivals, ``step`` for pending-queue
+    retries after a job completes)."""
 
     loop: str = ""
     ticks: int = 0
@@ -140,6 +144,8 @@ class SimProfile:
     components: int = 0
     dirty_components: int = 0
     admit_s: float = 0.0
+    rate_memo_hits: int = 0
+    rate_memo_misses: int = 0
 
     def as_dict(self) -> Dict[str, object]:
         return dataclasses.asdict(self)
@@ -242,8 +248,12 @@ class _FlowTable:
     order-sensitive float reduction must replay — and the link incidence
     lives twice: as int rows of ``links`` (``-1``-padded, for vectorized
     delivered-GB scatters and component labeling) and as the original link
-    id tuples in ``paths`` (for solver inputs and dirty marking).  Slots
-    are recycled through a free list; capacity doubles on demand."""
+    id tuples in ``paths`` (for solver inputs and dirty marking).  ``cid``
+    interns each slot's (demand, path) content as an int, the per-flow part
+    of the whole-tick rate memo's key; ``add`` is the only writer of
+    ``demand`` and ``paths``, so an id never goes stale while its slot
+    lives.  Slots are recycled through a free list; capacity doubles on
+    demand."""
 
     def __init__(self, link_index: Dict[str, int], cap: int = 64) -> None:
         self.link_index = link_index
@@ -257,6 +267,8 @@ class _FlowTable:
         self.alive = np.zeros(cap, dtype=bool)
         self.links = np.full((cap, self.maxp), -1, dtype=np.int64)
         self.paths: List[Optional[Tuple[str, ...]]] = [None] * cap
+        self.cid = np.zeros(cap, dtype=np.int64)
+        self._cids: Dict[Tuple[float, Tuple[str, ...]], int] = {}
         self._free = list(range(cap - 1, -1, -1))
 
     def _grow(self) -> None:
@@ -268,9 +280,10 @@ class _FlowTable:
         job = np.full(new, -1, dtype=np.int64)
         job[:old] = self.job
         self.job = job
-        pos = np.zeros(new, dtype=np.int64)
-        pos[:old] = self.pos
-        self.pos = pos
+        for name in ("pos", "cid"):
+            arr = np.zeros(new, dtype=np.int64)
+            arr[:old] = getattr(self, name)
+            setattr(self, name, arr)
         alive = np.zeros(new, dtype=bool)
         alive[:old] = self.alive
         self.alive = alive
@@ -301,6 +314,11 @@ class _FlowTable:
         for k, l in enumerate(path):
             self.links[s, k] = self.link_index[l]
         self.paths[s] = path
+        content = (float(demand), path)
+        cid = self._cids.get(content)
+        if cid is None:
+            cid = self._cids[content] = len(self._cids)
+        self.cid[s] = cid
         return s
 
     def free(self, s: int) -> None:
@@ -387,6 +405,11 @@ class ClusterSimulator:
         self._annotate = None
         self._caps_fn: Optional[Callable[[str], float]] = None
         self._caps_epoch: int = -1
+        # whole-tick rate memo (vectorized incremental backends): every
+        # link's allocatable capacity as bytes, rebuilt lazily per cluster
+        # epoch, + the active flows' content ids -> the tick's rate vector
+        self._caps_bytes: Optional[bytes] = None
+        self._rate_memo: Dict[Tuple[bytes, bytes], np.ndarray] = {}
         self._events = collections.deque(
             events_mod.normalize_events(events, traffic_changes))
         self.delivered_gb: Dict[str, float] = {l: 0.0 for l in cluster.link_ids}
@@ -552,6 +575,7 @@ class ClusterSimulator:
 
             self._caps_fn = cap_of
             self._caps_epoch = epoch
+            self._caps_bytes = None
         return self._caps_fn
 
     def _assign_rates(self) -> None:
@@ -952,7 +976,10 @@ class ClusterSimulator:
         with the seed's ``_max_min_fair`` (groups in (job, pos) order);
         any multi-link path forces the seed's one global progressive fill.
         Vectorized backends: dirty affinity components are batched through
-        one memo-aware ``fluid.solve_batch`` per tick."""
+        one memo-aware ``fluid.solve_batch`` per tick; with the engine's
+        memo on, a whole-tick memo keyed by every link's capacity and the
+        active flows' content ids in (job, pos) order answers a recurring
+        flow mix before any of that runs."""
         act = self._active_slots()
         if act.size == 0:
             return
@@ -961,7 +988,6 @@ class ClusterSimulator:
                 self.profile.skipped_assigns += 1
             return
         tbl = self._flows
-        link0 = tbl.links[act, 0]
         single = bool((tbl.links[act, 1:] < 0).all())
         mode = "single" if single else "multi"
         if mode != self._last_fill_mode:
@@ -974,6 +1000,7 @@ class ClusterSimulator:
             self.profile.solves += 1
         if self.fluid.backend == "python":
             if single:
+                link0 = tbl.links[act, 0]
                 if self._all_dirty:
                     targets = np.unique(link0)
                 else:
@@ -991,10 +1018,43 @@ class ClusterSimulator:
                 paths = [tbl.paths[s] for s in act]
                 caps = {l: cap_of(l) for p in paths for l in p}
                 tbl.rate[act] = _progressive_fill(demands, paths, caps)
+        elif self.fluid.incremental:
+            self._assign_memoized(act, cap_of)
         else:
             self._assign_vectorized(act, cap_of)
         self._dirty_links.clear()
         self._all_dirty = False
+
+    def _assign_memoized(self, act: np.ndarray,
+                         cap_of: Callable[[str], float]) -> None:
+        """Whole-tick rate memo in front of ``_assign_vectorized``.
+
+        Each component's rates are a function of its content (the engine's
+        memo relies on it) and clean components hold rates solved for
+        their unchanged content, so the tick's rate vector is a function
+        of the caps and the ordered flow contents: a hit writes the stored
+        vector, a miss solves the dirty components and stores the result.
+        Bounded by the engine's ``memo_max``, cleared when full like the
+        engine's memo."""
+        tbl = self._flows
+        if self._caps_bytes is None:
+            self._caps_bytes = np.array(
+                [cap_of(l) for l in self._link_ids]).tobytes()
+        key = (self._caps_bytes, tbl.cid[act].tobytes())
+        memo = self._rate_memo
+        prof = self.profile
+        rates = memo.get(key)
+        if rates is not None:
+            tbl.rate[act] = rates
+            if prof is not None:
+                prof.rate_memo_hits += 1
+            return
+        self._assign_vectorized(act, cap_of)
+        if len(memo) >= self.fluid.memo_max:
+            memo.clear()
+        memo[key] = tbl.rate[act]
+        if prof is not None:
+            prof.rate_memo_misses += 1
 
     def _assign_vectorized(self, act: np.ndarray,
                            cap_of: Callable[[str], float]) -> None:

@@ -453,6 +453,134 @@ class TestProfile:
 
 
 # ---------------------------------------------------------------------------
+# whole-tick rate memo (vectorized backends)
+# ---------------------------------------------------------------------------
+
+class TestRateMemo:
+    TOL = 5e-3  # the fill parity tolerance (TestSolveBatch, test_fluid)
+
+    def _cfg(self, backend="jnp", duration_ms=6_000.0):
+        return SimConfig(duration_ms=duration_ms, seed=0, jitter_std=0.0,
+                         fluid_backend=backend, profile=True)
+
+    def _jobs(self):
+        # two periodic jobs on the same two host links
+        return [make_job("a", n_tasks=2, period_ms=100, duty=0.4,
+                         bw_gbps=20.0, n_iterations=60),
+                make_job("b", n_tasks=2, period_ms=130, duty=0.3,
+                         bw_gbps=10.0, n_iterations=60)]
+
+    def _sim(self, backend="jnp", duration_ms=6_000.0, **kw):
+        jobs = self._jobs()
+        cl, registry = _scheduled(jobs)
+        return ClusterSimulator(cl, jobs, self._cfg(backend, duration_ms),
+                                registry=registry, **kw)
+
+    def _spy(self, sim):
+        """Record every rate solve: (time, hit, ordered content ids, the
+        fill problem of the active flows, the rates written)."""
+        solves = []
+        inner = sim._assign_rates_array
+        prof = sim.profile
+
+        def spied():
+            before = (prof.rate_memo_hits, prof.rate_memo_misses)
+            inner()
+            after = (prof.rate_memo_hits, prof.rate_memo_misses)
+            if after == before:
+                return
+            tbl = sim._flows
+            act = sim._active_slots()
+            cap_of = sim._allocatable()
+            paths = [tbl.paths[s] for s in act]
+            caps = {l: cap_of(l) for p in paths for l in p}
+            solves.append((sim.now, after[0] > before[0],
+                           tbl.cid[act].tobytes(),
+                           (tbl.demand[act].copy(), paths, caps),
+                           tbl.rate[act].copy()))
+
+        sim._assign_rates_array = spied
+        return solves
+
+    def test_recurring_mix_hits(self):
+        p = self._sim().run().profile
+        assert p.rate_memo_hits > 0
+        assert p.rate_memo_hits > p.rate_memo_misses
+
+    def test_hits_and_misses_count_every_solve(self):
+        p = self._sim().run().profile
+        assert p.solves > 0
+        assert p.rate_memo_hits + p.rate_memo_misses == p.solves
+        # the component path runs on misses only
+        assert 0 < p.dirty_components <= p.components
+
+    def test_capacity_change_forces_miss(self):
+        """A recurring mix after a capacity change misses once per mix on
+        the new caps, and its rates are the oracle's on the new caps."""
+        t_ev = 3_000.0
+        sim = self._sim(events=[LinkCapacityChange(t_ev, link="n0",
+                                                   capacity_gbps=12.0)])
+        solves = self._spy(sim)
+        sim.run()
+        before = [s for s in solves if s[0] < t_ev]
+        after = [s for s in solves if s[0] >= t_ev]
+        assert before and after
+        seen_before = {cid for _, _, cid, _, _ in before}
+        assert any(hit for _, hit, _, _, _ in before)
+        first_after = {}
+        for _, hit, cid, _, _ in after:
+            first_after.setdefault(cid, hit)
+        # every mix misses the first time it runs on the new caps, those
+        # that recur from before the change included; later runs hit
+        assert not any(first_after.values())
+        assert set(first_after) & seen_before
+        assert sum(not hit for _, hit, _, _, _ in after) == len(first_after)
+        for _, _, _, (d, paths, caps), rates in after:
+            assert caps["n0"] == 12.0
+            gold = fluid.fill_python(d, paths, caps)
+            np.testing.assert_allclose(rates, gold, atol=self.TOL, rtol=0)
+
+    def test_content_ids_intern_demand_and_path(self):
+        from repro.core.simulator import _FlowTable
+        tbl = _FlowTable({"n0": 0, "n1": 1}, cap=2)
+        a = tbl.add(0, 0, 10.0, 1.0, ("n0",))
+        b = tbl.add(0, 1, 12.0, 1.0, ("n0",))
+        c = tbl.add(1, 0, 10.0, 1.0, ("n1",))
+        d = tbl.add(1, 1, 10.0, 1.0, ("n0",))  # grows the table
+        assert tbl.cap == 4
+        assert len({tbl.cid[a], tbl.cid[b], tbl.cid[c]}) == 3
+        assert tbl.cid[d] == tbl.cid[a]
+
+    def test_memo_clears_at_memo_max(self):
+        sim = self._sim()
+        sim.fluid.memo_max = 2
+        sizes = []
+        inner = sim._assign_rates_array
+
+        def spied():
+            inner()
+            sizes.append(len(sim._rate_memo))
+
+        sim._assign_rates_array = spied
+        p = sim.run().profile
+        assert p.rate_memo_misses > 2
+        assert max(sizes) == 2
+        # a miss into a full memo clears it before storing
+        assert 1 in sizes[sizes.index(2):]
+
+    def test_iteration_times_match_python_oracle(self):
+        jnp_res = self._sim("jnp").run()
+        py_res = self._sim("python").run()
+        assert jnp_res.profile.rate_memo_hits > 0
+        assert py_res.profile.rate_memo_hits == 0
+        assert py_res.profile.rate_memo_misses == 0
+        assert jnp_res.iterations_done == py_res.iterations_done
+        for job, want in py_res.durations_ms.items():
+            np.testing.assert_allclose(jnp_res.durations_ms[job], want,
+                                       rtol=self.TOL)
+
+
+# ---------------------------------------------------------------------------
 # batched multi-problem solves + shape-bucketed corpus batching
 # ---------------------------------------------------------------------------
 
