@@ -19,7 +19,15 @@ a ``with`` block:
   its second pod's node;
 - ``slow_compute``: the event loop runs every compute phase 1% long;
 - ``skipped_step``: the event loop leaves one due job in 50 unstepped
-  until its next tick.
+  until its next tick;
+- ``shifted_start``: under a controller, an aligned job starts 1 ms after
+  its circle offset asks;
+- ``dropped_pause``: one stop-and-wait realign in 20 is skipped;
+- ``dropped_inject``: compute phases leave out the controller's injected
+  idle.
+
+The last three act in the simulator after the controller has answered, so
+the answers the harness records are the sound ones.
 
 The benchmark's own runs never import this module; :func:`main` takes the
 readings that the limits are set from.
@@ -118,6 +126,36 @@ def installed(kind: str):
                 step(self, st)
 
         patch(simulator.ClusterSimulator, "_step_job", skipping)
+    elif kind == "shifted_start":
+        admit = simulator.ClusterSimulator._admit_job
+
+        def late(self, job):
+            admit(self, job)
+            ctl = self.controller
+            if ctl is not None and ctl.job_alignment(job.name) is not None:
+                st = self.jobs[job.name]
+                st.start_time += 1.0
+                st.phase_end += 1.0
+                self._sync_job(st)
+
+        patch(simulator.ClusterSimulator, "_admit_job", late)
+    elif kind == "dropped_pause":
+        realign = simulator.ClusterSimulator._apply_realign
+        calls = [0]
+
+        def dropping(self, jname):
+            calls[0] += 1
+            if calls[0] % 20:
+                realign(self, jname)
+
+        patch(simulator.ClusterSimulator, "_apply_realign", dropping)
+    elif kind == "dropped_inject":
+        enter = simulator.ClusterSimulator._enter_compute
+
+        def no_inject(self, st, inject):
+            enter(self, st, 0.0)
+
+        patch(simulator.ClusterSimulator, "_enter_compute", no_inject)
     elif kind == "frozen":
         run = simulator.ClusterSimulator.run
 

@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import importlib.util
 import json
 import math
 import random
@@ -32,7 +31,7 @@ import numpy as np
 
 from . import devtrace, reference, traffic
 from .probes import CHUNK_SPAN, Probes
-from .spec import BENCH_DIR, Cell
+from .spec import BENCH_DIR, Cell, load_part
 
 WINDOW_SPAN = "bench.window"
 OPEN_ENDED_ITERATIONS = 1_000_000_000
@@ -174,12 +173,7 @@ def precompile(mix: dict, backend: str) -> int:
 # ----------------------------------------------------------------- metrics
 def load_reader(name: str, bench_dir: Path = BENCH_DIR):
     """The ``read`` function of ``bench/metrics/<name>.py``."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_part("metrics", name, "read", bench_dir)
 
 
 def read_metrics(entries: List[dict], win, bench_dir: Path) -> Dict[str, dict]:
@@ -200,8 +194,7 @@ def checks(cell: Cell, probes: Probes, sim, jobs, win) -> Dict[str, dict]:
     for demands, paths, caps, rates in probes.samples:
         want = reference.fill(demands, paths, caps)
         err = max([err] + [abs(float(r) - w) for r, w in zip(rates, want)])
-    admit = reference.ADMISSION_CHECKS[limits["admission"]]
-    bad_admissions = sum(admit(rec) for rec in probes.admissions)
+    bad_admissions = sum(cell.admission(rec) for rec in probes.admissions)
     gap = progress_gap(cell, probes, sim, jobs, win.edges)
     return {
         "fill_err_gbps": {"value": err, "limit": limits["fill_err_gbps"]},
@@ -215,7 +208,10 @@ def checks(cell: Cell, probes: Probes, sim, jobs, win) -> Dict[str, dict]:
 def snapshot(sim, probes: Probes) -> dict:
     """The program's state at a chunk edge, as the reference follows it:
     per admitted job still running, its workers, its phase, the end of a
-    timed phase, and the Gb left on the flow from each worker."""
+    timed phase, the Gb left on the flow from each worker, a realign
+    pending for its next compute phase and a pause pending there; the
+    controller's answers then in force; and how many admissions,
+    controller answers and realigns had been recorded."""
     tbl = sim._flows
     jobs = {}
     for name, st in sim.jobs.items():
@@ -227,21 +223,26 @@ def snapshot(sim, probes: Probes) -> dict:
         jobs[name] = {"workers": probes.placed[name], "phase": st.phase,
                       "end": None if math.isinf(st.phase_end)
                       else float(st.phase_end),
-                      "left": left}
+                      "left": left, "pending": bool(st.realign_pending),
+                      "pause": float(st.pending_pause_ms)}
     return {"t_ms": float(sim.now), "jobs": jobs,
-            "admissions": len(probes.admissions)}
+            "control": probes.control_state(),
+            "admissions": len(probes.admissions),
+            "controls": len(probes.control),
+            "realigns": len(probes.realigns)}
 
 
-def program_completions(sim, probes: Probes, cfg: dict) -> Dict[str, list]:
+def program_completions(sim, probes: Probes) -> Dict[str, list]:
     """When each iteration of each admitted job ended in the program's run:
-    its start (admission plus start-up) plus its iteration times so far."""
-    startup = float(cfg["sim"]["startup_ms"])
+    its start (admission plus start-up, plus the wait for its circle offset
+    under a controller) plus its iteration times so far."""
     out = {}
     for rec in probes.admissions:
         if rec["admitted"]:
-            t = rec["t_ms"] + startup
+            st = sim.jobs[rec["job"]]
+            t = st.start_time
             ends = []
-            for d in sim.jobs[rec["job"]].durations_ms:
+            for d in st.durations_ms:
                 t += d
                 ends.append(t)
             out[rec["job"]] = ends
@@ -265,17 +266,25 @@ def progress_gap(cell: Cell, probes: Probes, sim, jobs, edges) -> float:
         name = job_name(spec, i)
         comm = f["period_ms"] * f["duty"]
         spec_of[name] = {"compute_ms": f["period_ms"] - comm, "comm_ms": comm,
-                         "bw_gbps": f["bw_gbps"]}
+                         "bw_gbps": f["bw_gbps"],
+                         "high": bool(spec.high_priority)}
         departures[name] = departure_ms(spec, ts)
-    done = program_completions(sim, probes, cfg)
+    done = program_completions(sim, probes)
     gap = 0.0
     for edge in edges:
         t0, t1 = edge["t_ms"], edge["t_ms"] + horizon
-        later = [(r["t_ms"], r["job"], r["placed"])
+        later = [(r["t_ms"], r["job"], r["placed"], r["control_after"])
                  for r in probes.admissions[edge["admissions"]:]
                  if r["admitted"]]
+        control = [(c["t_ms"], c["state"])
+                   for c in probes.control[edge["controls"]:]
+                   if c["t_ms"] < t1]
+        realigns = [(r["t_ms"], r["jobs"])
+                    for r in probes.realigns[edge["realigns"]:]
+                    if r["t_ms"] < t1]
         ref = reference.follow(spec_of, edge, later, departures,
-                               cfg["cluster"], t1, startup)
+                               cfg["cluster"], t1, startup, control,
+                               realigns)
         got = {name: [x for x in done.get(name, []) if t0 <= x < t1]
                for name in ref}
         gap = max(gap, reference.progress_gap(got, ref, t1))
@@ -329,7 +338,7 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
     mix = cell.traffic
     sim, jobs = build(cell, seed, backend)
     probes = Probes(sim, seed=seed, sample_solves=int(mix["sample_solves"]),
-                    trace=trace)
+                    trace=trace, policy=cell.config["policy"])
     n_shapes = precompile(mix, backend or cell.config["sim"]["fluid_backend"])
     ts = float(mix["time_scale"])
     chunk_ms = float(mix["chunk_sim_s"]) * 1e3

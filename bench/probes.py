@@ -6,8 +6,15 @@ what the checks need afterwards.
 
 - admission: every ``ClusterSimulator._try_schedule`` call of the run
   (an attempt; retries from the pending queue and refusals included) with
-  the time, the cluster state it started from and the placement it left,
-  and the window's calls counted;
+  the time, the cluster state it started from (free resources, link
+  capacities, every live task with its traffic and priority, the
+  configuration's ``policy``, the controller's answers) and the placement
+  and controller answers it left, and the window's calls counted;
+- the stop-and-wait controller, where the policy has one: its answers in
+  force (each live job's alignment and injected idle) after every attempt
+  and every eviction, link change or traffic change it hears of, and the
+  realigns each of its drift and phase-error reports asks for, with the
+  time, for the progress reference to follow;
 - every ``FluidEngine.solve_batch`` call: a count, non-finite answers, and
   a reservoir sample of its problems with their answers, drawn from the
   run's seed;
@@ -22,7 +29,7 @@ from __future__ import annotations
 
 import contextlib
 import random
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -38,8 +45,9 @@ _COMPILE_EVENTS = {
 
 class Probes:
     def __init__(self, sim, *, seed: int, sample_solves: int,
-                 trace: bool) -> None:
+                 trace: bool, policy: Optional[dict] = None) -> None:
         self.sim = sim
+        self.policy = policy
         self.on = False
         self.trace = trace
         self._rng = random.Random(seed)
@@ -48,6 +56,10 @@ class Probes:
         self.admit_calls = 0
         self.admissions: List[dict] = []
         self.placed: Dict[str, List[str]] = {}
+        # the controller's answers in force from ``t_ms`` on, and the
+        # realigns its reports asked for
+        self.control: List[dict] = []
+        self.realigns: List[dict] = []
         self.solve_calls = 0
         self.solve_problems = 0
         self.failed = 0
@@ -92,12 +104,15 @@ class Probes:
             before["job"] = wl.jobs[0].name
             before["admitted"] = bool(ok)
             before["placed"] = [t.node for t in wl.all_tasks()]
+            before["control_after"] = self._record_control()
             self.admissions.append(before)
             if ok:
                 self.placed[before["job"]] = before["placed"]
             return ok
 
         sim._try_schedule = recorded_try_schedule
+        if sim.controller is not None:
+            self._wrap_controller(sim.controller)
 
         engine = sim.fluid
         solve_batch = engine.solve_batch
@@ -141,6 +156,33 @@ class Probes:
 
         jax.monitoring.register_event_duration_secs_listener(on_event)
 
+    def _wrap_controller(self, ctl) -> None:
+        """Record the controller's answers as the program gets them: the
+        state in force after each call that can change it outside an
+        admission attempt, and the realigns each report asks for."""
+        sim = self.sim
+
+        def changing(fn):
+            def recorded(*a, **kw):
+                out = fn(*a, **kw)
+                self._record_control()
+                return out
+            return recorded
+
+        def reporting(fn):
+            def recorded(*a, **kw):
+                actions = fn(*a, **kw)
+                if actions:
+                    self.realigns.append({"t_ms": sim.now,
+                                          "jobs": [x.job for x in actions]})
+                return actions
+            return recorded
+
+        for name in ("on_evict", "on_link_change", "report_traffic_change"):
+            setattr(ctl, name, changing(getattr(ctl, name)))
+        for name in ("report_iteration", "report_phase_error"):
+            setattr(ctl, name, reporting(getattr(ctl, name)))
+
     def close(self) -> None:
         """Undo the module-level wrap (instance wraps die with the
         simulator)."""
@@ -159,8 +201,32 @@ class Probes:
             if j < self.sample_size:
                 self.samples[j] = item
 
+    def control_state(self) -> Optional[dict]:
+        """The controller's answers for every live job: ``align``, its
+        ``(offset_ms, period_eff_ms)`` where ``job_alignment`` gives one,
+        and ``inject``, its ``injected_ms`` where it has one; None without
+        a controller."""
+        ctl = self.sim.controller
+        if ctl is None:
+            return None
+        align, inject = {}, {}
+        for name in self.sim.framework.registry.jobs:
+            a = ctl.job_alignment(name)
+            if a is not None:
+                align[name] = (float(a[0]), float(a[1]))
+            if name in ctl.injected_ms:
+                inject[name] = float(ctl.injected_ms[name])
+        return {"align": align, "inject": inject}
+
+    def _record_control(self) -> Optional[dict]:
+        state = self.control_state()
+        if state is not None:
+            self.control.append({"t_ms": self.sim.now, "state": state})
+        return state
+
     def _admission_state(self, wl) -> dict:
         cl = self.sim.framework.cluster
+        reg = self.sim.framework.registry
         topo = cl.topology
         free, cap, alloc = {}, {}, {}
         for name in cl.node_names:
@@ -179,6 +245,14 @@ class Probes:
                               t.resources.gpu),
                       "bw": t.traffic.bw_gbps, "spread": t.spread}
                      for t in wl.all_tasks()],
+            "tasks": [{"job": t.job, "worker": t.node, "priority": t.priority,
+                       "period_ms": t.traffic.period_ms,
+                       "duty": t.traffic.duty, "bw_gbps": t.traffic.bw_gbps}
+                      for t in reg.tasks.values()],
+            "link_capacity": {l: cl.link_capacity(l) for l in cl.link_ids},
+            "link_alloc": {l: cl.link_alloc(l) for l in cl.link_ids},
+            "policy": self.policy,
+            "control_before": self.control_state(),
         }
 
 

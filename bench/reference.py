@@ -5,20 +5,20 @@ recomputes the answer the configuration's semantics call for.
 - :func:`fill` -- max-min fair rates by progressive filling (float64): all
   unfrozen flows grow together, a flow freezes when its demand is met or a
   link on its path is full.
-- :func:`least_allocated` -- the Kubernetes default scheduler's choice for
-  a job, pod by pod: NodeResourcesFit plus the spread cap filter, the
-  LeastAllocated score, ties to the lowest node index, all or nothing.
 - :func:`follow` -- the fluid model of the configuration, followed event
-  by event in float64 from the admissions: the completion time of every
-  iteration of every job.  :func:`progress_gap` compares it with the
-  program's.
+  by event in float64 from the admissions, with the stop-and-wait
+  arithmetic of a controller where the policy has one: the completion
+  time of every iteration of every job.  :func:`progress_gap` compares it
+  with the program's.
+
+Admission references are files of their own, ``bench/admission/<name>.py``,
+named by a configuration's ``check.admission``.
 """
 from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence, Tuple
 
 EPS = 1e-9
-RESOURCES = ("cpu", "mem", "gpu")
 
 
 def fill(demands: Sequence[float], paths: Sequence[Sequence[str]],
@@ -48,52 +48,6 @@ def fill(demands: Sequence[float], paths: Sequence[Sequence[str]],
             break
         active = still
     return rates
-
-
-def _fits(req: Sequence[float], free: Sequence[float]) -> bool:
-    return all(r <= f for r, f in zip(req, free))
-
-
-def least_allocated(rec: dict) -> Tuple[bool, Optional[List[str]]]:
-    """The default scheduler's outcome for one admission attempt ``rec``
-    (see ``probes.AdmissionRecorder``): (admitted, node per pod)."""
-    nodes = rec["nodes"]
-    free = {n: list(rec["free"][n]) for n in nodes}
-    per_node: Dict[str, int] = {}
-    placed: List[str] = []
-    for pod in rec["pods"]:
-        best, best_key = None, None
-        for idx, n in enumerate(nodes):
-            if pod["spread"] > 0 and per_node.get(n, 0) >= pod["spread"]:
-                continue
-            if not _fits(pod["req"], free[n]):
-                continue
-            cap = rec["capacity"][n]
-            terms = [(free[n][k] - pod["req"][k]) / cap[k]
-                     for k in range(len(RESOURCES)) if cap[k] > 0]
-            score = 100.0 * (sum(terms) / len(terms)) if terms else 0.0
-            key = (score, -idx)
-            if best_key is None or key > best_key:
-                best, best_key = n, key
-        if best is None:
-            return False, None
-        free[best] = [f - r for f, r in zip(free[best], pod["req"])]
-        per_node[best] = per_node.get(best, 0) + 1
-        placed.append(best)
-    return True, placed
-
-
-def least_allocated_mismatch(rec: dict) -> int:
-    """1 when the program's outcome differs from :func:`least_allocated`."""
-    ok, placed = least_allocated(rec)
-    if ok != rec["admitted"]:
-        return 1
-    return int(ok and placed != rec["placed"])
-
-
-ADMISSION_CHECKS = {
-    "least_allocated": least_allocated_mismatch,
-}
 
 
 # ----------------------------------------------------------------- progress
@@ -135,24 +89,45 @@ def job_flows(nodes: Sequence[str], bw_gbps: float,
 
 def follow(jobs: Dict[str, dict], start: dict, admissions: Sequence[tuple],
            departures: Dict[str, float], layout: dict, until_ms: float,
-           startup_ms: float = 0.0) -> Dict[str, List[float]]:
+           startup_ms: float = 0.0, control: Sequence[tuple] = (),
+           realigns: Sequence[tuple] = ()) -> Dict[str, List[float]]:
     """Completion time (ms) of every iteration that ends from
     ``start["t_ms"]`` until ``until_ms``, per job.
 
-    ``jobs`` maps a job to its ``compute_ms``, ``comm_ms`` and per-pod
-    ``bw_gbps``.  ``start`` is the state followed from: its time and, per
-    job then admitted, its ``workers``, ``phase`` (``waiting``,
-    ``compute`` or ``comm``), the end of a timed phase (``end``, None
-    while flows move) and the Gb ``left`` on the flow of each worker.
-    ``admissions`` holds ``(time_ms, job, workers)`` of every later
-    admission in order; ``departures`` the time each job leaves.  A job
-    admitted at t starts at t + ``startup_ms``, computes for its compute
-    time, then moves ``demand x comm time`` over each of its flows at the
-    max-min fair rates of all flows then active; the iteration ends when
-    its last flow ends, and the next begins at once."""
+    ``jobs`` maps a job to its ``compute_ms``, ``comm_ms``, per-pod
+    ``bw_gbps`` and whether it is of ``high`` priority.  ``start`` is the
+    state followed from: its time; per job then admitted, its ``workers``,
+    ``phase`` (``waiting``, ``compute``, ``paused`` or ``comm``), the end
+    of a timed phase (``end``, None while flows move), the Gb ``left`` on
+    the flow of each worker, a realign ``pending`` for its next compute
+    phase and a ``pause`` due there; and the controller's answers then in
+    force (``control``, None without a controller).  ``admissions`` holds
+    ``(time_ms, job, workers[, answers after it])`` of every later
+    admission in order; ``departures`` the time each job leaves.
+
+    A job admitted at t starts at t + ``startup_ms``, computes for its
+    compute time, then moves ``demand x comm time`` over each of its flows
+    at the max-min fair rates of all flows then active; the iteration ends
+    when its last flow ends, and the next begins at once.
+
+    Under a stop-and-wait controller the answers are timed inputs:
+    ``control`` holds ``(time_ms, answers)`` in force from then on, and
+    ``realigns`` ``(time_ms, jobs)`` that its drift reports asked for.
+    Answers are ``{"align": {job: (offset_ms, period_eff_ms)}, "inject":
+    {job: ms}}``.  The follower applies the paper's arithmetic itself: an
+    aligned job's start waits until its first comm phase lands on
+    ``offset (mod period_eff)``; every compute phase lasts ``compute_ms``
+    plus the job's injected idle; after an admission every other live
+    low-priority job realigns, as does each job a report names: in compute
+    (or paused) its phase end moves on to the next time ``t = offset (mod
+    period_eff)`` and it is paused; otherwise the realign waits for the
+    start of its next compute phase, which then ends at such a time."""
     caps, leaf_of = links_of(layout)
     adm = sorted(admissions, key=lambda a: a[0])
     dep = sorted((t, name) for name, t in departures.items())
+    ctl = sorted(control, key=lambda c: c[0])
+    ral = sorted(realigns, key=lambda r: r[0])
+    answers = start.get("control")
     t = float(start["t_ms"])
     live: Dict[str, dict] = {}
     flows: List[list] = []          # [job, demand, remaining Gb, path]
@@ -161,14 +136,43 @@ def follow(jobs: Dict[str, dict], start: dict, admissions: Sequence[tuple],
         spec = jobs[name]
         live[name] = {"phase": st["phase"], "end": st["end"],
                       "flows": job_flows(st["workers"], spec["bw_gbps"],
-                                         leaf_of)}
+                                         leaf_of),
+                      "pending": st.get("pending", False),
+                      "pause": st.get("pause", 0.0)}
         out[name] = []
         for demand, path in live[name]["flows"]:
             left = st["left"].get(path[0], 0.0)
             if st["phase"] == "comm" and left > EPS:
                 flows.append([name, demand, left, path])
+
+    def realign(name: str, given: Optional[dict]) -> None:
+        st = live.get(name)
+        align = None if given is None else given["align"].get(name)
+        if st is None or align is None:
+            return
+        offset, period = align
+        if st["phase"] in ("compute", "paused"):
+            st["end"] += (offset - (st["end"] % period)) % period
+            st["phase"] = "paused"
+        else:
+            st["pending"] = True
+
+    def enter_compute(name: str, st: dict) -> None:
+        dur = jobs[name]["compute_ms"]
+        if answers is not None:
+            dur += answers["inject"].get(name, 0.0)
+        dur += st["pause"]
+        st["pause"] = 0.0
+        if st["pending"]:
+            align = None if answers is None else answers["align"].get(name)
+            if align is not None:
+                offset, period = align
+                dur += (offset - ((t + dur) % period)) % period
+            st["pending"] = False
+        st.update(phase="compute", end=t + dur)
+
     rates: List[float] = [0.0] * len(flows)
-    ai = di = 0
+    ai = di = ci = ri = 0
     dirty = True
     while True:
         if dirty:
@@ -179,6 +183,8 @@ def follow(jobs: Dict[str, dict], start: dict, admissions: Sequence[tuple],
             nxt = min(nxt, adm[ai][0])
         if di < len(dep):
             nxt = min(nxt, dep[di][0])
+        if ri < len(ral):
+            nxt = min(nxt, ral[ri][0])
         for st in live.values():
             if st["end"] is not None:
                 nxt = min(nxt, st["end"])
@@ -193,6 +199,9 @@ def follow(jobs: Dict[str, dict], start: dict, admissions: Sequence[tuple],
         t = nxt
         if t >= until_ms:
             return out
+        while ci < len(ctl) and ctl[ci][0] <= t + EPS:
+            answers = ctl[ci][1]
+            ci += 1
         while di < len(dep) and dep[di][0] <= t + EPS:
             name = dep[di][1]
             di += 1
@@ -202,12 +211,29 @@ def follow(jobs: Dict[str, dict], start: dict, admissions: Sequence[tuple],
                 rates = [r for _, r in kept]
                 dirty = True
         while ai < len(adm) and adm[ai][0] <= t + EPS:
-            _, name, workers = adm[ai]
+            name, workers = adm[ai][1], adm[ai][2]
+            after = adm[ai][3] if len(adm[ai]) > 3 else None
             ai += 1
-            live[name] = {"phase": "waiting", "end": t + startup_ms,
+            begin = t + startup_ms
+            align = None if after is None else after["align"].get(name)
+            if align is not None:
+                offset, period = align
+                first_comm = (begin + jobs[name]["compute_ms"]
+                              + after["inject"].get(name, 0.0))
+                begin += (offset - first_comm) % period
+            live[name] = {"phase": "waiting", "end": begin,
                           "flows": job_flows(workers, jobs[name]["bw_gbps"],
-                                             leaf_of)}
+                                             leaf_of),
+                          "pending": False, "pause": 0.0}
             out[name] = []
+            if after is not None:
+                for other in live:
+                    if other != name and not jobs[other].get("high"):
+                        realign(other, after)
+        while ri < len(ral) and ral[ri][0] <= t + EPS:
+            for name in ral[ri][1]:
+                realign(name, answers)
+            ri += 1
         if any(f[2] <= EPS for f in flows):
             kept = [(f, r) for f, r in zip(flows, rates) if f[2] > EPS]
             flows = [f for f, _ in kept]
@@ -218,8 +244,8 @@ def follow(jobs: Dict[str, dict], start: dict, admissions: Sequence[tuple],
             spec = jobs[name]
             due = st["end"] is not None and t + EPS >= st["end"]
             if st["phase"] == "waiting" and due:
-                st.update(phase="compute", end=t + spec["compute_ms"])
-            elif st["phase"] == "compute" and due:
+                enter_compute(name, st)
+            elif st["phase"] in ("compute", "paused") and due:
                 if st["flows"]:
                     for demand, path in st["flows"]:
                         flows.append([name, demand,
@@ -232,7 +258,7 @@ def follow(jobs: Dict[str, dict], start: dict, admissions: Sequence[tuple],
             elif st["phase"] == "comm" and (
                     due or (st["end"] is None and name not in busy)):
                 out[name].append(t)
-                st.update(phase="compute", end=t + spec["compute_ms"])
+                enter_compute(name, st)
 
 
 def progress_gap(program: Dict[str, List[float]],

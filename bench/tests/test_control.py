@@ -11,22 +11,27 @@ from bench.spec import load_cell
 
 TRACE = "testbed-k8s.tiny-trace"
 PEAK = "tiny-fabric.tiny-peak"
+METRONOME = "tiny-metronome.tiny-metro"
+# the Metronome cell's stream is run to its end (~23 simulated s): a
+# dropped realign shows only where one falls on a realign that moves a job
+SECONDS = {METRONOME: 600.0}
 
 
 def _run(root, cell, seed, kind=None):
     c = load_cell(cell, root / "BENCHMARK.json", root / "bench")
+    seconds = SECONDS.get(cell, 1.0)
     t = time.perf_counter()
     if kind is None:
-        return harness.run(c, seed=seed, seconds=1.0, trace=False,
+        return harness.run(c, seed=seed, seconds=seconds, trace=False,
                            root=root, t_start=t, rehearse=True,
                            bench_dir=root / "bench")
     with control.installed(kind):
-        return harness.run(c, seed=seed, seconds=1.0, trace=False,
+        return harness.run(c, seed=seed, seconds=seconds, trace=False,
                            root=root, t_start=t, rehearse=True,
                            bench_dir=root / "bench")
 
 
-@pytest.mark.parametrize("cell", [TRACE, PEAK])
+@pytest.mark.parametrize("cell", [TRACE, PEAK, METRONOME])
 @pytest.mark.parametrize("seed", [4000000021, 4000000022])
 def test_sound_run_is_correct(tiny_checkout, cell, seed):
     out = _run(tiny_checkout, cell, seed)
@@ -57,4 +62,13 @@ def test_fault_is_not_correct(tiny_checkout, cell, kind, number):
     out = _run(tiny_checkout, cell, 4000000024, kind)
     assert not out["correct"]
     c = out["checks"][number]
+    assert c["value"] > c["limit"]
+
+
+@pytest.mark.parametrize("kind", ["shifted_start", "dropped_pause",
+                                  "dropped_inject"])
+def test_controller_fault_is_not_correct(tiny_checkout, kind):
+    out = _run(tiny_checkout, METRONOME, 4000000024, kind)
+    assert not out["correct"]
+    c = out["checks"]["progress_gap_ms"]
     assert c["value"] > c["limit"]

@@ -10,7 +10,8 @@ import sys
 
 import pytest
 
-CELLS = ["testbed-k8s.tiny-trace", "tiny-fabric.tiny-peak"]
+CELLS = ["testbed-k8s.tiny-trace", "tiny-fabric.tiny-peak",
+         "tiny-metronome.tiny-metro"]
 
 
 def _run(root, *args):
