@@ -24,10 +24,19 @@ a ``with`` block:
   its circle offset asks;
 - ``dropped_pause``: one stop-and-wait realign in 20 is skipped;
 - ``dropped_inject``: compute phases leave out the controller's injected
-  idle.
+  idle;
+- ``worse_node``: the Metronome plugin's best-scored node of the first pod
+  with a choice is scored below every other, so the pod goes to a
+  Filter-passing node the reference ranks lower;
+- ``false_refusal``: every fourth admission attempt is refused outright;
+- ``shifted_offset``: the job admitted last is aligned one circle slot
+  (base / Di-Pre) after the offset the controller worked out;
+- ``unstretched_period``: a job whose circle injects idle is aligned on its
+  own period instead of the stretched one.
 
-The last three act in the simulator after the controller has answered, so
-the answers the harness records are the sound ones.
+``shifted_start``, ``dropped_pause`` and ``dropped_inject`` act in the
+simulator after the controller has answered, so the answers the harness
+records are the sound ones; the last four change what is recorded.
 
 The benchmark's own runs never import this module; :func:`main` takes the
 readings that the limits are set from.
@@ -156,6 +165,61 @@ def installed(kind: str):
             enter(self, st, 0.0)
 
         patch(simulator.ClusterSimulator, "_enter_compute", no_inject)
+    elif kind == "worse_node":
+        from repro.core.scheduler import MetronomePlugin
+        score_nodes = MetronomePlugin.score_nodes
+
+        done = [False]
+
+        def demoted(self, ctx, cluster, pod, nodes, registry):
+            out = score_nodes(self, ctx, cluster, pod, nodes, registry)
+            if len(out) > 1 and not done[0]:
+                done[0] = True
+                top = max(out, key=lambda n: (out[n], -cluster.index(n)))
+                out[top] = -1.0
+            return out
+
+        patch(MetronomePlugin, "score_nodes", demoted)
+    elif kind == "false_refusal":
+        from repro.core import framework
+        schedule_workload = framework.SchedulingFramework.schedule_workload
+        calls = [0]
+
+        def refusing(self, wl):
+            calls[0] += 1
+            if calls[0] % 4 == 0:
+                return False
+            return schedule_workload(self, wl)
+
+        patch(framework.SchedulingFramework, "schedule_workload", refusing)
+    elif kind in ("shifted_offset", "unstretched_period"):
+        from repro.core.controller import StopAndWaitController
+        admit = simulator.ClusterSimulator._admit_job
+        job_alignment = StopAndWaitController.job_alignment
+        last, period = [None], {}
+
+        def noting(self, job):
+            last[0] = job.name
+            period[job.name] = job.traffic.period_ms
+            admit(self, job)
+
+        def misaligned(self, job):
+            a = job_alignment(self, job)
+            if a is None:
+                return a
+            off, pe = a
+            if kind == "unstretched_period":
+                if self.injected_ms.get(job, 0.0) > 0 and job in period:
+                    return off % period[job], period[job]
+                return a
+            if job != last[0]:
+                return a
+            base = next(st.scheme.base_ms for st in self.links.values()
+                        if job in st.scheme.jobs)
+            return (off + base / self.di_pre) % pe, pe
+
+        patch(simulator.ClusterSimulator, "_admit_job", noting)
+        patch(StopAndWaitController, "job_alignment", misaligned)
     elif kind == "frozen":
         run = simulator.ClusterSimulator.run
 

@@ -410,8 +410,15 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
     metrics = read_metrics(entries, win, bench_dir) if error is None else {}
     attempted = probes.admit_calls + probes.solve_calls
     failed = probes.failed + (1 if error else 0)
-    compared = (checks(cell, probes, sim, jobs, win)
-                if error is None else {})
+    t_check = time.perf_counter()
+    compared = {}
+    if error is None:
+        try:
+            compared = checks(cell, probes, sim, jobs, win)
+        except Exception:  # a run its references cannot judge is not correct
+            error = traceback.format_exc()
+            failed += 1
+    check_s = time.perf_counter() - t_check
     correct = error is None and all(
         c["value"] <= c["limit"] for c in compared.values())
     counts = {
@@ -423,6 +430,7 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
         "chunks_sim_s": win.sim_s, "window_wall_s": wall,
         "iterations": iterations, "fill_shapes_warmed": n_shapes,
         "window_compiles": probes.compiles,
+        "admissions_judged": len(probes.admissions), "check_s": check_s,
     }
     print("bench counts: " + json.dumps(counts), file=sys.stderr)
     if error:

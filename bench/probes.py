@@ -6,10 +6,13 @@ what the checks need afterwards.
 
 - admission: every ``ClusterSimulator._try_schedule`` call of the run
   (an attempt; retries from the pending queue and refusals included) with
-  the time, the cluster state it started from (free resources, link
-  capacities, every live task with its traffic and priority, the
-  configuration's ``policy``, the controller's answers) and the placement
-  and controller answers it left, and the window's calls counted;
+  the time, the cluster state it started from and the placement and
+  controller answers it left, and the window's calls counted.  The record
+  (see :meth:`Probes._admission_state`) holds what an admission reference
+  needs: free resources, link capacities, each incoming pod's demand and
+  traffic, every live task, the jobs' priorities and submission times, the
+  workload's dependencies, the latency between nodes, the policy and the
+  Metronome plugin's Score constants, and the controller's answers;
 - the stop-and-wait controller, where the policy has one: its answers in
   force (each live job's alignment and injected idle) after every attempt
   and every eviction, link change or traffic change it hears of, and the
@@ -28,6 +31,7 @@ what the host was doing in each idle gap.
 from __future__ import annotations
 
 import contextlib
+import functools
 import random
 from typing import Dict, List, Optional
 
@@ -225,6 +229,17 @@ class Probes:
         return state
 
     def _admission_state(self, wl) -> dict:
+        """The cluster as attempt ``wl`` finds it.  ``pods`` are the
+        workload's pods in ``wl.all_tasks()`` order (each with its job,
+        priority, period, duty, demand, resources and spread); ``tasks``
+        every live task in registry order, the order in which the program
+        groups a link's jobs; ``submit_s`` each live and incoming job's
+        submission time, which breaks Eq. 16's priority ties (earliest
+        first); ``dependencies`` the workload's AppGroup job pairs;
+        ``latency`` tau between every two nodes, in ``nodes`` order (the
+        cluster's matrix, copied whole);
+        ``score_params`` the Metronome plugin's constants (None under
+        another plugin)."""
         cl = self.sim.framework.cluster
         reg = self.sim.framework.registry
         topo = cl.topology
@@ -238,22 +253,45 @@ class Probes:
         leaf_of = dict(getattr(topo, "leaf_of", {}) or {})
         uplinks = {leaf_of[n]: topo.uplink_of(n).alloc_bw
                    for n in leaf_of if topo.uplink_of(n) is not None}
+        submit = {name: job.submit_time_s for name, job in reg.jobs.items()}
+        submit.update((job.name, job.submit_time_s) for job in wl.jobs)
         return {
             "nodes": list(cl.node_names), "free": free, "capacity": cap,
             "alloc_bw": alloc, "leaf_of": leaf_of, "uplink_alloc": uplinks,
             "pods": [{"req": (t.resources.cpu, t.resources.mem,
                               t.resources.gpu),
-                      "bw": t.traffic.bw_gbps, "spread": t.spread}
+                      "bw": t.traffic.bw_gbps, "spread": t.spread,
+                      "job": t.job, "priority": t.priority,
+                      "period_ms": t.traffic.period_ms,
+                      "duty": t.traffic.duty}
                      for t in wl.all_tasks()],
             "tasks": [{"job": t.job, "worker": t.node, "priority": t.priority,
                        "period_ms": t.traffic.period_ms,
                        "duty": t.traffic.duty, "bw_gbps": t.traffic.bw_gbps}
                       for t in reg.tasks.values()],
+            "submit_s": submit,
+            "dependencies": [list(pair) for pair in wl.dependencies],
+            "latency": cl.latency.tolist(),
             "link_capacity": {l: cl.link_capacity(l) for l in cl.link_ids},
             "link_alloc": {l: cl.link_alloc(l) for l in cl.link_ids},
             "policy": self.policy,
+            "score_params": self.score_params,
             "control_before": self.control_state(),
         }
+
+    @functools.cached_property
+    def score_params(self) -> Optional[dict]:
+        """The Metronome plugin's Score constants as the live plugin holds
+        them (read once: the plugin is fixed for the run); None under
+        another plugin."""
+        from repro.core.scheduler import MetronomePlugin
+
+        plugin = self.sim.framework.plugin
+        if not isinstance(plugin, MetronomePlugin):
+            return None
+        return {"di_pre": plugin.di_pre, "g_t_ms": plugin.g_t_ms,
+                "e_t_frac": plugin.e_t_frac,
+                "rotation_mode": plugin.rotation_mode, "joint": plugin.joint}
 
 
 def fill_bytes(problems) -> int:

@@ -5,9 +5,9 @@
 ``tiny_checkout`` builds a checkout in a temporary directory that holds a
 copy of ``bench/``, the program's ``src/`` (linked), and a BENCHMARK.json
 whose three small cells are made only of new files: two configurations
-(one under the full Metronome policy, with an admission reference that
-passes every record), three traffic mixes and a metric that the
-repository does not have.  It is how a later change adds a cell, and it
+(one under the full Metronome policy, judged by the Metronome admission
+reference), three traffic mixes and a metric that the repository does not
+have.  It is how a later change adds a cell, and it
 is small enough for a test to run.
 """
 from __future__ import annotations
@@ -33,14 +33,6 @@ TINY_FABRIC = {
     "oversubscription": 2.0,
 }
 
-PASSES_ALL = '''"""Passes every admission record: a stand-in until the Metronome
-policy has a reference of its own."""
-
-
-def mismatch(rec):
-    return 0
-'''
-
 METRONOME_CELL = "tiny-metronome.tiny-metro"
 
 NEW_METRIC = '''"""Event-loop ticks per wall second over the window."""
@@ -52,8 +44,8 @@ def read(win):
 
 
 def tiny_files(root: Path) -> None:
-    """Write the tiny cells' configuration, traffic, admission and metric
-    files and a BENCHMARK.json that names them beside the real cell: a
+    """Write the tiny cells' configuration, traffic and metric files and
+    a BENCHMARK.json that names them beside the real cell: a
     small leaf-spine under a production day's peak and, under the full
     Metronome policy, under a compressed trace followed whole; and the
     testbed under a stream of short jobs."""
@@ -65,9 +57,8 @@ def tiny_files(root: Path) -> None:
     (bench / "configs" / "tiny-fabric.json").write_text(json.dumps(cfg))
     metro = copy.deepcopy(cfg)
     metro.update(name="tiny-metronome", policy={"scheduler": "metronome"})
-    metro["check"]["admission"] = "passes_all"
+    metro["check"]["admission"] = "metronome"
     (bench / "configs" / "tiny-metronome.json").write_text(json.dumps(metro))
-    (bench / "admission" / "passes_all.py").write_text(PASSES_ALL)
     for name, why in (("tiny-fabric", "a test-sized leaf-spine"),
                       ("tiny-metronome", "the same under the full policy")):
         spec["configs"].append({

@@ -65,6 +65,29 @@ def test_fault_is_not_correct(tiny_checkout, cell, kind, number):
     assert c["value"] > c["limit"]
 
 
+@pytest.mark.parametrize("seed", [4000000061, 4000000062, 4000000063,
+                                  4000000064, 4000000065])
+def test_metronome_admissions_match_the_reference(tiny_checkout, seed):
+    """Every attempt of a stream run to its end, judged by the Metronome
+    admission reference."""
+    out = _run(tiny_checkout, METRONOME, seed)
+    assert out["checks"]["bad_admissions"]["value"] == 0
+
+
+@pytest.mark.parametrize("kind", ["altered_placement", "worse_node",
+                                  "false_refusal", "shifted_offset",
+                                  "unstretched_period"])
+def test_admission_fault_is_not_correct(tiny_checkout, kind):
+    """Each fault reads more bad admissions than the sound run of the same
+    seed (which the program's own faults keep above 0; PERF.md, section
+    7)."""
+    sound = _run(tiny_checkout, METRONOME, 4000000024)
+    out = _run(tiny_checkout, METRONOME, 4000000024, kind)
+    assert not out["correct"]
+    assert (out["checks"]["bad_admissions"]["value"]
+            > sound["checks"]["bad_admissions"]["value"])
+
+
 @pytest.mark.parametrize("kind", ["shifted_start", "dropped_pause",
                                   "dropped_inject"])
 def test_controller_fault_is_not_correct(tiny_checkout, kind):

@@ -37,6 +37,19 @@ def test_attempt_record_holds_what_an_admission_reference_needs(
             assert set(task) == {"job", "worker", "priority", "period_ms",
                                  "duty", "bw_gbps"}
             assert task["worker"] in rec["nodes"]
+        for pod in rec["pods"]:
+            assert set(pod) == {"req", "bw", "spread", "job", "priority",
+                                "period_ms", "duty"}
+            assert pod["job"] == rec["job"]
+        assert rec["dependencies"] == []
+        n = len(rec["nodes"])
+        assert len(rec["latency"]) == n
+        assert all(len(row) == n for row in rec["latency"])
+        assert {t["job"] for t in rec["tasks"]} | {rec["job"]} <= set(
+            rec["submit_s"])
+        assert rec["score_params"] == {
+            "di_pre": 72, "g_t_ms": 5.0, "e_t_frac": 0.10,
+            "rotation_mode": "intermediate", "joint": True}
         for key in ("control_before", "control_after"):
             state = rec[key]
             assert set(state) == {"align", "inject"}
@@ -81,7 +94,7 @@ def test_no_controller_records_no_answers(tiny_checkout):
     probes.close()
     assert probes.admissions
     assert all(r["control_before"] is None and r["control_after"] is None
-               for r in probes.admissions)
+               and r["score_params"] is None for r in probes.admissions)
     assert probes.control == [] and probes.realigns == []
 
 
@@ -96,5 +109,5 @@ def test_unknown_admission_reference_fails_at_load(tiny_checkout, tmp_path):
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
     with pytest.raises(SystemExit,
                        match=r"no admission/no_such_reference\.py.*"
-                             r"least_allocated.*passes_all"):
+                             r"least_allocated.*metronome"):
         load_cell(METRONOME, tmp_path / "BENCHMARK.json", tmp_path / "bench")
