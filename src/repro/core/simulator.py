@@ -126,7 +126,10 @@ class SimProfile:
     ``admit_s`` is ``_try_schedule``'s body (framework, offline
     recalculation, admission, realign); it is booked also inside the phase
     that called it (``events`` for arrivals, ``step`` for pending-queue
-    retries after a job completes)."""
+    retries after a job completes).  ``active_hits`` and ``active_misses``
+    split the array loop's ticks on which the set of active flow slots
+    changed between the membership cache's answers and rebuilds of the
+    ordered active set (``_active_slots``)."""
 
     loop: str = ""
     ticks: int = 0
@@ -146,6 +149,8 @@ class SimProfile:
     admit_s: float = 0.0
     rate_memo_hits: int = 0
     rate_memo_misses: int = 0
+    active_hits: int = 0
+    active_misses: int = 0
 
     def as_dict(self) -> Dict[str, object]:
         return dataclasses.asdict(self)
@@ -188,8 +193,11 @@ class JobState:
     finish_time: Optional[float] = None
     comm_extra_ms: float = 0.0  # latency penalty tail of the comm phase
     # array event loop: position in the simulator's job arrays (admission
-    # order) and the flow-table slots of the current comm phase
+    # order); the flow-table slots the job owns from its first comm phase
+    # until it is done, departs, stalls or its flow specs change; those
+    # same slots while a comm phase is on (None otherwise)
     index: int = -1
+    owned: Optional["_OwnedSlots"] = None
     flow_slots: Optional[np.ndarray] = None
     # fault injection / drift (DESIGN.md section 19): failed hosts this
     # job has tasks on (non-empty <=> STALLED); silent multiplier on the
@@ -250,10 +258,14 @@ class _FlowTable:
     delivered-GB scatters and component labeling) and as the original link
     id tuples in ``paths`` (for solver inputs and dirty marking).  ``cid``
     interns each slot's (demand, path) content as an int, the per-flow part
-    of the whole-tick rate memo's key; ``add`` is the only writer of
-    ``demand`` and ``paths``, so an id never goes stale while its slot
-    lives.  Slots are recycled through a free list; capacity doubles on
-    demand."""
+    of the whole-tick rate memo's key.
+
+    A job owns one slot per flow, in flow order, from ``reserve`` to
+    ``free``; ``reserve`` is the only writer of a slot's content
+    (``demand``, ``job``, ``pos``, ``links``, ``paths``, ``cid``), so a
+    content id never goes stale while its slot is owned, and the loop
+    writes only ``remaining``, ``rate`` and ``alive`` between the two.
+    Released slots return to a free list; capacity doubles on demand."""
 
     def __init__(self, link_index: Dict[str, int], cap: int = 64) -> None:
         self.link_index = link_index
@@ -294,38 +306,57 @@ class _FlowTable:
         self._free.extend(range(new - 1, old - 1, -1))
         self.cap = new
 
-    def add(self, job_idx: int, pos: int, demand: float, remaining: float,
-            path: Tuple[str, ...]) -> int:
-        if not self._free:
-            self._grow()
-        if len(path) > self.maxp:
-            wider = np.full((self.cap, len(path)), -1, dtype=np.int64)
-            wider[:, : self.maxp] = self.links
-            self.links = wider
-            self.maxp = len(path)
-        s = self._free.pop()
-        self.demand[s] = demand
-        self.remaining[s] = remaining
-        self.rate[s] = 0.0
-        self.job[s] = job_idx
-        self.pos[s] = pos
-        self.alive[s] = True
-        self.links[s, :] = -1
-        for k, l in enumerate(path):
-            self.links[s, k] = self.link_index[l]
-        self.paths[s] = path
-        content = (float(demand), path)
-        cid = self._cids.get(content)
-        if cid is None:
-            cid = self._cids[content] = len(self._cids)
-        self.cid[s] = cid
-        return s
+    def reserve(self, job_idx: int, demands: Sequence[float],
+                paths: Sequence[Tuple[str, ...]]) -> np.ndarray:
+        """Give job ``job_idx`` one slot per flow, in position order, and
+        write each slot's content; the slots start not alive."""
+        slots = np.empty(len(paths), dtype=np.int64)
+        for pos, (demand, path) in enumerate(zip(demands, paths)):
+            if not self._free:
+                self._grow()
+            if len(path) > self.maxp:
+                wider = np.full((self.cap, len(path)), -1, dtype=np.int64)
+                wider[:, : self.maxp] = self.links
+                self.links = wider
+                self.maxp = len(path)
+            s = self._free.pop()
+            self.demand[s] = demand
+            self.remaining[s] = 0.0
+            self.rate[s] = 0.0
+            self.job[s] = job_idx
+            self.pos[s] = pos
+            self.alive[s] = False
+            self.links[s, :] = -1
+            for k, l in enumerate(path):
+                self.links[s, k] = self.link_index[l]
+            self.paths[s] = path
+            content = (float(demand), path)
+            cid = self._cids.get(content)
+            if cid is None:
+                cid = self._cids[content] = len(self._cids)
+            self.cid[s] = cid
+            slots[pos] = s
+        return slots
 
-    def free(self, s: int) -> None:
-        self.alive[s] = False
-        self.job[s] = -1
-        self.paths[s] = None
-        self._free.append(s)
+    def free(self, slots: np.ndarray) -> None:
+        for s in slots.tolist():
+            self.alive[s] = False
+            self.job[s] = -1
+            self.paths[s] = None
+            self._free.append(s)
+
+
+@dataclasses.dataclass
+class _OwnedSlots:
+    """A job's flow-table slots and what a comm start reads of them: the
+    flow specs they were written from, the demands in spec order, every
+    link a path crosses, and the slots as bits of the membership key."""
+
+    specs: list
+    slots: np.ndarray
+    demand: np.ndarray
+    links: frozenset
+    bits: int
 
 
 def _next_span(span, annotate, name: str):
@@ -443,11 +474,19 @@ class ClusterSimulator:
         self._dirty_links: Set[str] = set()
         self._all_dirty = True
         self._last_fill_mode: Optional[str] = None
-        # cached (job, pos)-ordered active slots + flattened path incidence
-        self._order_stale = True
+        # membership key: bit s is set while slot s is alive with volume
+        # left.  The (job, pos)-ordered active set, its flattened path
+        # incidence, fill mode and content-id bytes are cached per key
+        # (cleared whenever a slot's content is written); _act_bits is the
+        # key of the set in force, -1 once its content may be stale
+        self._active_bits = 0
+        self._act_bits = 0
+        self._active_cache: Dict[int, tuple] = {}
         self._act = np.empty(0, dtype=np.int64)
         self._flat_links = np.empty(0, dtype=np.int64)
         self._flat_rows = np.empty(0, dtype=np.int64)
+        self._act_single = True
+        self._act_cids = b""
         self._warned: Set[Tuple[str, str]] = set()
         # (arrival_ms, workload) queue for online scheduling
         self._arrivals = collections.deque(sorted(
@@ -626,46 +665,84 @@ class ClusterSimulator:
         return self._link_view.flows_for(job, cache_epoch=self.cluster.epoch)
 
     def _start_comm_flows(self, st: JobState, comm_ms: float) -> bool:
-        """Create the job's comm-phase flows; False for single-node jobs.
+        """Start the job's comm-phase flows; False for single-node jobs.
 
-        Array mode registers table slots keyed (job index, spec position) —
-        the seed's flow iteration order — and marks the touched links dirty;
-        legacy mode builds the historical FlowState objects."""
+        Array mode fills the job's own table slots (reserved at its first
+        comm phase, or anew when its flow specs changed), which carry the
+        (job index, spec position) key — the seed's flow iteration order —
+        with the phase's volumes, sets their membership bits and marks the
+        touched links dirty; legacy mode builds the historical FlowState
+        objects."""
         if not self._array_mode:
             st.flows = self._make_flows(st.job, comm_ms)
             return bool(st.flows)
         specs = self._flow_specs(st.job)
+        own = st.owned
+        if own is not None and own.specs is not specs:
+            if own.specs == specs:
+                own.specs = specs  # an equal rebuild after an epoch bump
+            else:
+                self._release_slots(st)
+                own = None
         if not specs:
             return False
+        if own is None:
+            own = self._reserve_slots(st, specs)
         tbl = self._flows
-        slots = np.empty(len(specs), dtype=np.int64)
-        unfinished = 0
-        for k, fs in enumerate(specs):
-            remaining = fs.demand_gbps * comm_ms / 1e3
-            slots[k] = tbl.add(st.index, k, fs.demand_gbps, remaining,
-                               fs.links)
-            if remaining > EPS:
-                unfinished += 1
-            self._dirty_links.update(fs.links)
+        slots = own.slots
+        remaining = own.demand * comm_ms / 1e3
+        tbl.remaining[slots] = remaining
+        tbl.rate[slots] = 0.0
+        tbl.alive[slots] = True
+        vol = remaining > EPS
+        unfinished = int(np.count_nonzero(vol))
+        if unfinished == slots.size:
+            self._active_bits |= own.bits
+        else:
+            for s in slots[vol].tolist():
+                self._active_bits |= 1 << s
+        self._dirty_links.update(own.links)
         st.flow_slots = slots
         self._jhasflows[st.index] = True
         self._junfin[st.index] = unfinished
-        self._order_stale = True
         return True
 
+    def _reserve_slots(self, st: JobState, specs) -> _OwnedSlots:
+        """Give the job one table slot per flow spec.  Writing a slot's
+        content invalidates every cached active set."""
+        demand = np.array([fs.demand_gbps for fs in specs], dtype=float)
+        paths = [fs.links for fs in specs]
+        slots = self._flows.reserve(st.index, demand.tolist(), paths)
+        st.owned = _OwnedSlots(
+            specs=specs, slots=slots, demand=demand,
+            links=frozenset(l for p in paths for l in p),
+            bits=sum(1 << s for s in slots.tolist()))
+        # the set in force may name these slots too, under the same key
+        self._active_cache.clear()
+        self._act_bits = -1
+        return st.owned
+
+    def _release_slots(self, st: JobState) -> None:
+        """Return the job's slots (done, departed, stalled, or its flow
+        specs changed); its flows must be cleared already."""
+        if st.owned is not None:
+            self._flows.free(st.owned.slots)
+            st.owned = None
+
     def _clear_flows(self, st: JobState) -> None:
-        """Release the job's flows (comm end / departure); still-active
-        flows leave their links, so those links' rates are invalidated."""
+        """End the job's comm-phase flows (comm end / departure / stall);
+        still-active flows leave their links, so those links' rates are
+        invalidated.  The job keeps its slots."""
         if not self._array_mode:
             st.flows = []
             return
         if st.flow_slots is not None:
             tbl = self._flows
-            for s in st.flow_slots:
+            for s in st.flow_slots.tolist():
                 if tbl.remaining[s] > EPS:
                     self._dirty_links.update(tbl.paths[s])
-                tbl.free(s)
-            self._order_stale = True
+            tbl.alive[st.flow_slots] = False
+            self._active_bits &= ~st.owned.bits
         st.flow_slots = None
         self._jhasflows[st.index] = False
         self._junfin[st.index] = 0
@@ -684,26 +761,45 @@ class ClusterSimulator:
         """Alive flows with volume left, in (job index, position) order —
         the seed's iteration order, which the order-sensitive float
         reductions (delivered-GB accumulation, per-link grouping) replay
-        exactly.  Rebuilt only when flow membership changes; alongside it
-        the flattened (slot row, path link) incidence used by the
-        delivered-GB scatter-add."""
-        if self._order_stale:
-            tbl = self._flows
-            alive = np.nonzero(tbl.alive)[0]
-            act = alive[tbl.remaining[alive] > EPS]
-            if act.size:
-                act = act[np.lexsort((tbl.pos[act], tbl.job[act]))]
-                sub = tbl.links[act]
-                mask = sub >= 0
-                rows, _ = np.nonzero(mask)
-                self._flat_links = sub[mask]
-                self._flat_rows = rows
-            else:
-                self._flat_links = np.empty(0, dtype=np.int64)
-                self._flat_rows = np.empty(0, dtype=np.int64)
-            self._act = act
-            self._order_stale = False
+        exactly.  Looked up by the membership key when it changed, and
+        rebuilt only when the key is not cached; alongside it the
+        flattened (slot row, path link) incidence used by the delivered-GB
+        scatter-add, the fill mode and the content-id bytes."""
+        bits = self._active_bits
+        if bits != self._act_bits:
+            cache = self._active_cache
+            entry = cache.get(bits)
+            prof = self.profile
+            if entry is None:
+                entry = self._build_active()
+                if len(cache) >= self.fluid.memo_max:
+                    cache.clear()
+                cache[bits] = entry
+                if prof is not None:
+                    prof.active_misses += 1
+            elif prof is not None:
+                prof.active_hits += 1
+            (self._act, self._flat_links, self._flat_rows, self._act_single,
+             self._act_cids) = entry
+            self._act_bits = bits
         return self._act
+
+    def _build_active(self) -> tuple:
+        """The cache entry of the active set, rebuilt from the table."""
+        tbl = self._flows
+        alive = np.nonzero(tbl.alive)[0]
+        act = alive[tbl.remaining[alive] > EPS]
+        if act.size:
+            act = act[np.lexsort((tbl.pos[act], tbl.job[act]))]
+            sub = tbl.links[act]
+            mask = sub >= 0
+            rows, _ = np.nonzero(mask)
+            flat_links = sub[mask]
+            single = bool((sub[:, 1:] < 0).all())
+        else:
+            rows = flat_links = np.empty(0, dtype=np.int64)
+            single = True
+        return act, flat_links, rows, single, tbl.cid[act].tobytes()
 
     # ------------------------------------------------------------- main loop
     def run(self) -> SimResult:
@@ -906,10 +1002,12 @@ class ClusterSimulator:
                     fin = new_rem <= EPS
                     if fin.any():
                         done_slots = act[fin]
-                        for s in done_slots:
+                        gone = 0
+                        for s in done_slots.tolist():
                             self._dirty_links.update(tbl.paths[s])
+                            gone |= 1 << s
+                        self._active_bits &= ~gone
                         np.subtract.at(self._junfin, tbl.job[done_slots], 1)
-                        self._order_stale = True
                 for bg in self.background:
                     dv[link_index[bg.link_id]] += bg.rate_gbps * dt / 1e3
             self.now = nxt
@@ -988,7 +1086,7 @@ class ClusterSimulator:
                 self.profile.skipped_assigns += 1
             return
         tbl = self._flows
-        single = bool((tbl.links[act, 1:] < 0).all())
+        single = self._act_single
         mode = "single" if single else "multi"
         if mode != self._last_fill_mode:
             # per-link and global fills agree mathematically but not
@@ -1040,7 +1138,7 @@ class ClusterSimulator:
         if self._caps_bytes is None:
             self._caps_bytes = np.array(
                 [cap_of(l) for l in self._link_ids]).tobytes()
-        key = (self._caps_bytes, tbl.cid[act].tobytes())
+        key = (self._caps_bytes, self._act_cids)
         memo = self._rate_memo
         prof = self.profile
         rates = memo.get(key)
@@ -1288,6 +1386,7 @@ class ClusterSimulator:
                 st.stall_hosts.add(host)
                 if st.phase != STALLED:
                     self._clear_flows(st)
+                    self._release_slots(st)
                     st.phase = STALLED
                     st.phase_end = math.inf
                     st.comm_extra_ms = 0.0
@@ -1343,6 +1442,7 @@ class ClusterSimulator:
         if st.phase == DONE:
             return
         self._clear_flows(st)
+        self._release_slots(st)
         st.phase = DONE
         st.finish_time = self.now
         self._sync_job(st)
@@ -1528,6 +1628,7 @@ class ClusterSimulator:
             st.phase = DONE
             st.finish_time = self.now
             self._sync_job(st)
+            self._release_slots(st)
             return
         st.iter_start = self.now
         self._enter_compute(st, inject)

@@ -25,13 +25,14 @@ from repro.configs.metronome_testbed import (DYNAMIC_SNAPSHOTS, MODEL_FLEET,
                                              dynamic_scenario, make_snapshot,
                                              snapshot_scenario)
 from repro.core import fluid
-from repro.core.events import (BackgroundFlowChange, LinkCapacityChange,
+from repro.core.events import (BackgroundFlowChange, HostFailure,
+                               HostRecovery, JobDeparture, LinkCapacityChange,
                                TrafficChange, UnknownEventTargetWarning)
 from repro.core.experiment import Policy, run
 from repro.core.cluster import Cluster, Node, Resources
 from repro.core.framework import SchedulingFramework
 from repro.core.scheduler import MetronomePlugin
-from repro.core.simulator import COMM, ClusterSimulator, SimConfig
+from repro.core.simulator import EPS, COMM, ClusterSimulator, SimConfig
 from repro.core.workload import Workload, make_job
 
 CFG = SimConfig(duration_ms=20_000.0, seed=3, jitter_std=0.01)
@@ -83,8 +84,9 @@ def _scheduled(jobs):
     return cl, fw.registry
 
 
-def _both_loops(jobs_factory, cfg, **sim_kwargs):
-    """Run the same scheduled setup through both loops."""
+def _both_loops(jobs_factory, cfg, prepare=None, **sim_kwargs):
+    """Run the same scheduled setup through both loops; ``prepare`` sees
+    each simulator before it runs."""
     out = []
     for loop in ("array", "legacy"):
         jobs = jobs_factory()
@@ -93,6 +95,8 @@ def _both_loops(jobs_factory, cfg, **sim_kwargs):
             cl, jobs, dataclasses.replace(cfg, event_loop=loop),
             registry=registry,
             **{k: (v() if callable(v) else v) for k, v in sim_kwargs.items()})
+        if prepare is not None:
+            prepare(sim)
         out.append((sim, sim.run()))
     return out
 
@@ -543,10 +547,8 @@ class TestRateMemo:
     def test_content_ids_intern_demand_and_path(self):
         from repro.core.simulator import _FlowTable
         tbl = _FlowTable({"n0": 0, "n1": 1}, cap=2)
-        a = tbl.add(0, 0, 10.0, 1.0, ("n0",))
-        b = tbl.add(0, 1, 12.0, 1.0, ("n0",))
-        c = tbl.add(1, 0, 10.0, 1.0, ("n1",))
-        d = tbl.add(1, 1, 10.0, 1.0, ("n0",))  # grows the table
+        a, b = tbl.reserve(0, [10.0, 12.0], [("n0",), ("n0",)])
+        c, d = tbl.reserve(1, [10.0, 10.0], [("n1",), ("n0",)])  # grows
         assert tbl.cap == 4
         assert len({tbl.cid[a], tbl.cid[b], tbl.cid[c]}) == 3
         assert tbl.cid[d] == tbl.cid[a]
@@ -578,6 +580,287 @@ class TestRateMemo:
         for job, want in py_res.durations_ms.items():
             np.testing.assert_allclose(jnp_res.durations_ms[job], want,
                                        rtol=self.TOL)
+
+
+# ---------------------------------------------------------------------------
+# job-owned flow slots and the membership cache of the active set
+# ---------------------------------------------------------------------------
+
+def _uncached(sim):
+    """Empty the membership cache before every tick, so each change of
+    the active set rebuilds it."""
+    inner = sim._assign_rates_array
+
+    def cleared():
+        sim._active_cache.clear()
+        inner()
+
+    sim._assign_rates_array = cleared
+
+
+def _record_rates(sim):
+    """Every tick's time, ordered active slots and the rates in them."""
+    ticks = []
+    inner = sim._assign_rates_array
+
+    def spied():
+        inner()
+        act = sim._active_slots()
+        ticks.append((sim.now, act.tolist(), sim._flows.rate[act].tobytes()))
+
+    sim._assign_rates_array = spied
+    return ticks
+
+
+def _record_reservations(sim):
+    """(time, job, slots, cached active sets left) of every reservation."""
+    got = []
+    inner = sim._reserve_slots
+
+    def spied(st, specs):
+        own = inner(st, specs)
+        got.append((sim.now, st.name, own.slots.tolist(),
+                    len(sim._active_cache)))
+        return own
+
+    sim._reserve_slots = spied
+    return got
+
+
+def _check_membership(sim):
+    """Before every tick: the membership key names exactly the alive
+    slots with volume left, the cached active set in force is that set in
+    (job, pos) order, and a job shows its slots to the harness
+    (``flow_slots``) during a comm phase with flows and only then."""
+    inner = sim._assign_rates_array
+    checked = []
+
+    def spied():
+        tbl = sim._flows
+        want = np.nonzero(tbl.alive & (tbl.remaining > EPS))[0]
+        mask = sim._active_bits
+        assert {s for s in range(tbl.cap) if mask >> s & 1} == set(want)
+        act = sim._active_slots()
+        assert sorted(act.tolist()) == sorted(want.tolist())
+        keys = list(zip(tbl.job[act].tolist(), tbl.pos[act].tolist()))
+        assert keys == sorted(keys)
+        for st in sim._jobs_list:
+            with_flows = st.phase == COMM and bool(sim._jhasflows[st.index])
+            assert (st.flow_slots is not None) == with_flows
+            if st.flow_slots is not None:
+                assert st.flow_slots is st.owned.slots
+        checked.append(sim.now)
+        inner()
+
+    sim._assign_rates_array = spied
+    return checked
+
+
+class TestMembershipCache:
+    def _jobs(self):
+        return [make_job("a", n_tasks=2, period_ms=100, duty=0.4,
+                         bw_gbps=20.0, n_iterations=60),
+                make_job("b", n_tasks=2, period_ms=130, duty=0.3,
+                         bw_gbps=10.0, n_iterations=60)]
+
+    def _sim(self, backend, prepare=()):
+        jobs = self._jobs()
+        cl, registry = _scheduled(jobs)
+        sim = ClusterSimulator(
+            cl, jobs, SimConfig(duration_ms=6_000.0, seed=0, jitter_std=0.0,
+                                fluid_backend=backend, profile=True),
+            registry=registry)
+        for fn in prepare:
+            fn(sim)
+        return sim
+
+    @pytest.mark.parametrize("backend", ["python", "jnp"])
+    def test_recurring_mix_hits_bitwise_as_uncached(self, backend):
+        """A recurring flow mix is answered by the membership cache, and
+        every tick's rates and the delivered bytes are bitwise those of
+        the same run rebuilding the active set on every change."""
+        cached = self._sim(backend)
+        ticks = _record_rates(cached)
+        res = cached.run()
+        rebuilt = self._sim(backend, prepare=[_uncached])
+        ticks_rebuilt = _record_rates(rebuilt)
+        res_rebuilt = rebuilt.run()
+        p, q = res.profile, res_rebuilt.profile
+        assert p.active_hits > p.active_misses > 0
+        assert q.active_hits == 0
+        assert q.active_misses == p.active_hits + p.active_misses
+        assert ticks == ticks_rebuilt
+        assert cached._delivered_vec.tobytes() == \
+            rebuilt._delivered_vec.tobytes()
+        sim_equal(res, res_rebuilt)
+
+    def test_slots_owned_across_iterations(self):
+        """Each job reserves its slots once and keeps them through every
+        later iteration; its content ids never change."""
+        sim = self._sim("jnp")
+        got = _record_reservations(sim)
+        res = sim.run()
+        assert sorted(name for _, name, _, _ in got) == ["a", "b"]
+        assert min(res.iterations_done.values()) > 10
+
+    def test_departure_reuses_slots_and_clears_cache(self):
+        """A job departs, a later arrival takes its released slots: the
+        reservation writes their content and empties the cache, and the
+        python backend stays bit-for-bit with the legacy loop."""
+        cfg = SimConfig(duration_ms=8_000.0, seed=0, jitter_std=0.0)
+        runs = []
+        for loop in ("array", "legacy"):
+            cl = small_cluster()
+            fw = SchedulingFramework(cl, MetronomePlugin())
+            jobs = [make_job("early", n_tasks=2, period_ms=100, duty=0.4,
+                             bw_gbps=20.0, n_iterations=200),
+                    make_job("stay", n_tasks=2, period_ms=130, duty=0.3,
+                             bw_gbps=10.0, n_iterations=200)]
+            for j in jobs:
+                assert fw.schedule_workload(wl(j))
+            late = make_job("late", n_tasks=2, period_ms=90, duty=0.5,
+                            bw_gbps=15.0, n_iterations=200,
+                            submit_time_s=3.0)
+            sim = ClusterSimulator(
+                cl, jobs, dataclasses.replace(cfg, event_loop=loop),
+                registry=fw.registry, framework=fw, arrivals=[wl(late)],
+                events=[JobDeparture(2_000.0, job="early")])
+            got = _record_reservations(sim) if loop == "array" else None
+            checked = _check_membership(sim) if loop == "array" else None
+            runs.append((sim, sim.run(), got, checked))
+        (sa, ra, got, checked), (sl, rl, _, _) = runs
+        sim_equal(ra, rl)
+        assert checked
+        first = {name: (t, slots, left) for t, name, slots, left
+                 in reversed(got)}
+        assert set(first) == {"early", "stay", "late"}
+        assert first["late"][0] >= 3_000.0
+        assert set(first["late"][1]) & set(first["early"][1])
+        assert first["late"][2] == 0  # the cache was emptied
+        assert sa.jobs["early"].owned is None
+        assert ra.iterations_done["late"] > 0
+
+    def test_host_failure_releases_and_rereserves(self):
+        """A host failure stalls the jobs on it and releases their slots;
+        on recovery they reserve anew, emptying the cache, and the python
+        backend stays bit-for-bit with the legacy loop."""
+        cfg = SimConfig(duration_ms=6_000.0, seed=0, jitter_std=0.0)
+        evs = [HostFailure(2_000.0, host="n0"),
+               HostRecovery(2_500.0, host="n0")]
+        seen = {}
+
+        def prepare(sim):
+            if sim._array_mode:
+                seen["got"] = _record_reservations(sim)
+                seen["checked"] = _check_membership(sim)
+
+        (sa, ra), (sl, rl) = _both_loops(self._jobs, cfg, prepare=prepare,
+                                         events=lambda: list(evs))
+        sim_equal(ra, rl)
+        got = seen["got"]
+        assert seen["checked"]
+        for name in ("a", "b"):
+            times = [t for t, n, _, _ in got if n == name]
+            assert len(times) == 2 and times[1] >= 2_500.0
+        assert all(left == 0 for t, _, _, left in got if t >= 2_500.0)
+        assert min(ra.iterations_done.values()) > 0
+
+    def test_changed_specs_reserve_anew(self):
+        """When a job's flow specs change between comm phases it releases
+        its slots and reserves new ones with the new content, in both
+        loops alike."""
+        cfg = SimConfig(duration_ms=4_000.0, seed=0, jitter_std=0.0)
+        seen = {}
+
+        def prepare(sim):
+            inner = sim._flow_specs
+
+            def specs(job):
+                out = inner(job)
+                if sim.now < 2_000.0 or job.name != "a":
+                    return out
+                return [dataclasses.replace(fs, demand_gbps=fs.demand_gbps / 2)
+                        for fs in out]
+
+            sim._flow_specs = specs
+            if sim._array_mode:
+                seen["got"] = _record_reservations(sim)
+
+        (sa, ra), (sl, rl) = _both_loops(self._jobs, cfg, prepare=prepare)
+        sim_equal(ra, rl)
+        assert [n for _, n, _, _ in seen["got"]].count("a") == 2
+        own = sa.jobs["a"].owned
+        assert sa._flows.demand[own.slots].tolist() == [10.0, 10.0]
+
+    def test_reuse_under_the_same_key_rebuilds(self):
+        """A job's slots go to another job and come back active before the
+        next lookup, so the key is the one in force: the set is rebuilt
+        with the new owner's order and content ids."""
+        sim = self._sim("jnp")
+        a, b = sim.jobs["a"], sim.jobs["b"]
+        assert sim._start_comm_flows(a, 50.0)
+        before = sim._active_slots().copy()
+        key, cids_before = sim._active_bits, sim._act_cids
+        sim._clear_flows(a)
+        sim._release_slots(a)
+        assert sim._start_comm_flows(b, 50.0)
+        assert sim._active_bits == key
+        assert b.owned.slots.tolist() == before[::-1].tolist()
+        act = sim._active_slots()
+        want = sim._build_active()
+        assert act.tolist() == want[0].tolist() == b.owned.slots.tolist()
+        assert sim._act_cids == want[4] != cids_before
+        assert sim.profile.active_misses == 2
+
+    def test_cache_bounded_by_memo_max(self):
+        sim = self._sim("jnp")
+        sim.fluid.memo_max = 2
+        sizes = []
+        inner = sim._assign_rates_array
+
+        def spied():
+            inner()
+            sizes.append(len(sim._active_cache))
+
+        sim._assign_rates_array = spied
+        p = sim.run().profile
+        assert p.active_misses > 2
+        assert max(sizes) == 2
+
+    def test_membership_key_on_online_trace(self):
+        """Arrivals, departures and the vectorized fill: the key and the
+        cached set agree with a rebuild on every tick."""
+        sim = TestProfile()._online(duration_ms=30_000.0)
+        checked = _check_membership(sim)
+        p = sim.run().profile
+        assert len(checked) == p.ticks
+        assert p.active_hits > 0 and p.active_misses > 0
+
+    def test_flow_table_reserve_release_reuse(self):
+        from repro.core.simulator import _FlowTable
+        tbl = _FlowTable({"n0": 0, "n1": 1, "up": 2}, cap=4)
+        a = tbl.reserve(0, [10.0, 12.0], [("n0",), ("n1", "up")])
+        b = tbl.reserve(1, [5.0], [("n1",)])
+        assert tbl.job[a].tolist() == [0, 0] and tbl.pos[a].tolist() == [0, 1]
+        assert not tbl.alive[np.r_[a, b]].any()
+        assert tbl.links[a[1]].tolist() == [1, 2]
+        cid_a0 = tbl.cid[a[0]]
+        tbl.free(a)
+        assert tbl.job[a].tolist() == [-1, -1]
+        assert tbl.paths[a[0]] is None and tbl.paths[a[1]] is None
+        c = tbl.reserve(2, [12.0, 7.0, 10.0, 3.0],
+                        [("n0", "up", "n1"), ("n0",), ("n0",), ("n1",)])
+        assert set(a.tolist()) <= set(c.tolist())  # released slots reused
+        assert tbl.cap == 8  # the fifth slot in use grew the table
+        assert tbl.maxp == 3  # a longer path widened the link rows
+        assert tbl.job[c].tolist() == [2, 2, 2, 2]
+        assert tbl.pos[c].tolist() == [0, 1, 2, 3]
+        assert tbl.demand[c].tolist() == [12.0, 7.0, 10.0, 3.0]
+        assert tbl.links[c[0]].tolist() == [0, 2, 1]
+        assert tbl.links[c[1]].tolist() == [0, -1, -1]
+        assert tbl.links[b[0]].tolist() == [1, -1, -1]
+        assert tbl.cid[c[2]] == cid_a0  # (10.0, ("n0",)) again
+        assert len({tbl.cid[s] for s in c}) == 4
 
 
 # ---------------------------------------------------------------------------
