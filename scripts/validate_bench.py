@@ -67,11 +67,11 @@ def main(argv) -> int:
         return 0
     if kind == "dynamic_throughput":
         rows = doc.get("rows", [])
-        best = max((r.get("speedup_vs_legacy") or 0.0 for r in rows
-                    if r.get("loop") == "array"), default=0.0)
+        worst = max((r.get("max_abs_err_vs_oracle") or 0.0 for r in rows),
+                    default=0.0)
         print(f"{path}: OK — schema v{doc['schema_version']}, "
-              f"dynamic_throughput, {len(rows)} rows, best array speedup "
-              f"{best:.1f}x")
+              f"dynamic_throughput, {len(rows)} rows, worst oracle error "
+              f"{worst:.3g}")
         return 0
     if kind == "robustness":
         rows = doc.get("rows", [])

@@ -41,7 +41,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -177,7 +177,7 @@ def fill_python(
 ) -> np.ndarray:
     """The golden-oracle solve of one fill problem (float64, per-flow loop).
 
-    Mirrors the seed's ``_assign_rates`` dispatch exactly: all-single-link
+    Mirrors the seed's rate-assignment dispatch exactly: all-single-link
     problems take the per-link water-filling fast path, anything else the
     global progressive fill."""
     demands = np.asarray(demands, dtype=float)
@@ -403,7 +403,7 @@ def fill_corpus(
 
 
 # ---------------------------------------------------------------------------
-# affinity components (incremental re-fill)
+# incremental re-fill
 # ---------------------------------------------------------------------------
 
 def _first_seen_links(paths: Sequence[Tuple[str, ...]]) -> List[str]:
@@ -417,36 +417,6 @@ def _first_seen_links(paths: Sequence[Tuple[str, ...]]) -> List[str]:
                 seen.add(l)
                 out.append(l)
     return out
-
-
-def affinity_components(paths: Sequence[Tuple[str, ...]]) -> List[List[int]]:
-    """Partition flows into link-connected components (union-find over the
-    links their paths cross).  Components are ordered by their first flow's
-    index; flows keep their relative order inside each component."""
-    parent: Dict[str, str] = {}
-
-    def find(x: str) -> str:
-        root = x
-        while parent[root] != root:
-            root = parent[root]
-        while parent[x] != root:
-            parent[x], x = root, parent[x]
-        return root
-
-    for p in paths:
-        for l in p:
-            parent.setdefault(l, l)
-        for l in p[1:]:
-            parent[find(p[0])] = find(l)
-    comps: Dict[str, List[int]] = {}
-    order: List[str] = []
-    for i, p in enumerate(paths):
-        root = find(p[0])
-        if root not in comps:
-            comps[root] = []
-            order.append(root)
-        comps[root].append(i)
-    return [comps[r] for r in order]
 
 
 @dataclasses.dataclass
@@ -470,18 +440,17 @@ class FluidStats:
 class FluidEngine:
     """Backend-swappable progressive-filling engine.
 
-    ``assign(flows, cap_of)`` sets ``flow.rate_gbps`` on every flow object
-    (anything with ``demand_gbps`` / ``links`` / ``rate_gbps`` attributes,
-    e.g. the simulator's ``FlowState``) given a per-link allocatable
-    capacity function.
+    ``solve_batch(problems)`` solves the simulator's ``(demands, paths,
+    caps)`` fill problems — one per dirty affinity component of a tick —
+    in one dispatch; ``fill`` solves one problem without the memo.
 
     ``incremental=None`` picks the backend default: OFF for ``python``
-    (the global solve is the bit-for-bit seed path — see the module
-    docstring) and ON for the vectorized backends, where each affinity
-    component's solution is memoized under a content key of its demands,
-    paths and link capacities.  An event that touches one component leaves
-    every other component's key — and therefore its memoized rates —
-    intact."""
+    (the simulator's python path does not call ``solve_batch``; see the
+    module docstring) and ON for the vectorized backends, where each
+    affinity component's solution is memoized under a content key of its
+    demands, paths and link capacities.  An event that touches one
+    component leaves every other component's key — and therefore its
+    memoized rates — intact."""
 
     def __init__(self, backend: str = "python",
                  incremental: Optional[bool] = None,
@@ -509,15 +478,6 @@ class FluidEngine:
         self._sample_seen = 0
 
     # ------------------------------------------------------------- public API
-    def assign(self, flows: Sequence, cap_of: Callable[[str], float]) -> None:
-        if not flows:
-            return
-        if not self.incremental:
-            self._assign_full(flows, cap_of)
-            return
-        for comp in affinity_components([f.links for f in flows]):
-            self._assign_component([flows[i] for i in comp], cap_of)
-
     def fill(self, demands: np.ndarray, paths: Sequence[Tuple[str, ...]],
              caps: Dict[str, float]) -> np.ndarray:
         """Solve one fill problem with this engine's backend (no memo)."""
@@ -529,7 +489,7 @@ class FluidEngine:
     def solve_batch(self, problems: Sequence[tuple]) -> List[np.ndarray]:
         """Solve many ``(demands, paths, caps)`` problems in ONE dispatch.
 
-        The array event loop's dirty-component path: every dirty affinity
+        The event loop's dirty-component path: every dirty affinity
         component of one tick arrives here together; memoized components
         (content key: demands, paths, link capacities) return instantly,
         and ALL misses go through a single shape-bucketed
@@ -599,62 +559,3 @@ class FluidEngine:
         if ann is not None:
             batch_span.__exit__(None, None, None)
         return out  # type: ignore[return-value]
-
-    # --------------------------------------------------------------- internals
-    def _assign_full(self, flows: Sequence,
-                     cap_of: Callable[[str], float]) -> None:
-        """The seed's ``_assign_rates`` body, verbatim (python backend) or
-        one global vectorized solve (jnp/kernel with incremental off)."""
-        if self.backend == "python":
-            if all(len(f.links) == 1 for f in flows):
-                by_link: Dict[str, List] = {}
-                for f in flows:
-                    by_link.setdefault(f.node, []).append(f)
-                for node_name, group in by_link.items():
-                    demands = np.array([f.demand_gbps for f in group])
-                    rates = _max_min_fair(demands, cap_of(node_name))
-                    for f, r in zip(group, rates):
-                        f.rate_gbps = float(r)
-                return
-            caps = {l: cap_of(l) for f in flows for l in f.links}
-            demands = np.array([f.demand_gbps for f in flows])
-            rates = _progressive_fill(demands, [f.links for f in flows], caps)
-            for f, r in zip(flows, rates):
-                f.rate_gbps = float(r)
-            return
-        caps = {l: cap_of(l) for f in flows for l in f.links}
-        rates = self.fill(np.array([f.demand_gbps for f in flows]),
-                          [f.links for f in flows], caps)
-        for f, r in zip(flows, rates):
-            f.rate_gbps = float(r)
-
-    def _assign_component(self, flows: Sequence,
-                          cap_of: Callable[[str], float]) -> None:
-        links: List[str] = []
-        seen = set()
-        for f in flows:
-            for l in f.links:
-                if l not in seen:
-                    seen.add(l)
-                    links.append(l)
-        caps = {l: cap_of(l) for l in links}
-        key = (self.backend,
-               tuple((f.demand_gbps, f.links) for f in flows),
-               tuple(caps[l] for l in links))
-        rates = self._memo.get(key)
-        if rates is None:
-            self.stats.misses += 1
-            if self.backend == "python":
-                rates = fill_python(
-                    np.array([f.demand_gbps for f in flows]),
-                    [f.links for f in flows], caps)
-            else:
-                rates = self.fill(np.array([f.demand_gbps for f in flows]),
-                                  [f.links for f in flows], caps)
-            if len(self._memo) >= self.memo_max:
-                self._memo.clear()
-            self._memo[key] = rates
-        else:
-            self.stats.hits += 1
-        for f, r in zip(flows, rates):
-            f.rate_gbps = float(r)

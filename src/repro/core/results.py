@@ -400,17 +400,14 @@ def validate_trace_throughput_dict(doc: Mapping[str, Any]) -> List[str]:
 
 def to_dynamic_throughput_dict(rows: Sequence[Mapping[str, Any]], *,
                                smoke: bool = False) -> Dict[str, Any]:
-    """The ``BENCH_dynamic_throughput.json`` payload: one row per
-    (event-loop, fluid-backend) combination driving the full dynamic event
-    loop over the 10k-job production trace
-    (``benchmarks/bench_dynamic_throughput.py``).
+    """The ``BENCH_dynamic_throughput.json`` payload: one row per fluid
+    backend driving the full dynamic event loop over the 10k-job
+    production trace (``benchmarks/bench_dynamic_throughput.py``).
 
-    ``speedup_vs_legacy`` on the array rows is the acceptance metric the
-    CI gate reads (>= 10x end-to-end on the non-smoke trace);
     ``max_abs_err_vs_oracle`` audits sampled in-loop solves of vectorized
     backends against ``fill_python`` re-solves (0 for the python oracle,
-    which is instead bit-for-bit by construction — pinned in
-    ``tests/test_event_loop.py``).  ``profile`` carries the per-phase
+    whose results are instead pinned bit-for-bit by
+    ``tests/goldens/sim_goldens.json``).  ``profile`` carries the per-phase
     counters/timings of ``SimConfig.profile``; ``corpus`` the
     ``fluid.CorpusStats`` bucket occupancy, so batch-padding waste is in
     the artifact rather than silent."""
@@ -420,13 +417,11 @@ def to_dynamic_throughput_dict(rows: Sequence[Mapping[str, Any]], *,
         corpus = r.get("corpus")
         out.append(
             {"name": str(r["name"]),
-             "loop": str(r["loop"]),
              "backend": str(r["backend"]),
              "n_jobs": int(r["n_jobs"]),
              "n_events": int(r["n_events"]),
              "ticks": int(r["ticks"]),
              "seconds": _f(float(r["seconds"])),
-             "speedup_vs_legacy": _f(float(r["speedup_vs_legacy"])),
              "max_abs_err_vs_oracle": _f(float(r["max_abs_err_vs_oracle"])),
              "profile": dict(profile) if profile is not None else None,
              "corpus": dict(corpus) if corpus is not None else None,
@@ -455,21 +450,20 @@ def validate_dynamic_throughput_dict(doc: Mapping[str, Any]) -> List[str]:
         problems.append("'rows' missing or not a list")
         return problems
     if not rows:
-        problems.append("'rows' is empty — no loop/backend was benchmarked")
-    loops = set()
+        problems.append("'rows' is empty — no backend was benchmarked")
     for ri, row in enumerate(rows):
         where = f"rows[{ri}]"
         if not isinstance(row, Mapping):
             problems.append(f"{where} is not an object")
             continue
-        for key in ("name", "loop", "backend", "origin"):
+        for key in ("name", "backend", "origin"):
             if not isinstance(row.get(key), str):
                 problems.append(f"{where}.{key} missing or not a string")
         for key in ("n_jobs", "n_events", "ticks"):
             v = row.get(key)
             if not isinstance(v, int) or isinstance(v, bool):
                 problems.append(f"{where}.{key} missing or not an int")
-        for key in ("seconds", "speedup_vs_legacy", "max_abs_err_vs_oracle"):
+        for key in ("seconds", "max_abs_err_vs_oracle"):
             v = row.get(key)
             if not isinstance(v, (int, float)) or isinstance(v, bool):
                 problems.append(f"{where}.{key} missing or not a number")
@@ -477,11 +471,6 @@ def validate_dynamic_throughput_dict(doc: Mapping[str, Any]) -> List[str]:
             v = row.get(key)
             if v is not None and not isinstance(v, Mapping):
                 problems.append(f"{where}.{key} neither null nor an object")
-        loops.add(row.get("loop"))
-    if rows and "legacy" not in loops:
-        problems.append("no 'legacy' baseline row — speedups are unanchored")
-    if rows and "array" not in loops:
-        problems.append("no 'array' row — the optimized loop was not timed")
     return problems
 
 

@@ -78,18 +78,10 @@ class SimConfig:
     sample_interval_ms: float = 1000.0
     monitor: bool = True  # enable the continuous monitoring mechanism
     # rate-sharing backend of the fluid engine (core/fluid.py):
-    # 'python' (the bit-for-bit seed path), 'jnp', or 'kernel'
+    # 'python' (the float64 oracle, bit-for-bit the seed path and the
+    # pinned goldens), 'jnp', or 'kernel'; the vectorized backends memoize
+    # per affinity component and per tick (DESIGN.md section 17)
     fluid_backend: str = "python"
-    # None picks the backend default (off for python, on for vectorized);
-    # True memoizes per affinity component so events re-fill only the
-    # component they touch
-    fluid_incremental: Optional[bool] = None
-    # event-loop implementation (DESIGN.md section 17): 'array' keeps flow
-    # state in contiguous arrays with dirty-link rate invalidation (the
-    # production hot path, bit-for-bit equal to the seed on the python
-    # backend); 'legacy' is the pre-array per-object loop, retained as the
-    # parity oracle and the benchmark's pre-optimization reference
-    event_loop: str = "array"
     # collect per-phase counters/timings into SimResult.profile
     profile: bool = False
     # observation channel for the control plane (DESIGN.md section 19):
@@ -115,7 +107,7 @@ class SimProfile:
     ``skipped_assigns`` ticks where nothing was dirty — their ratio is the
     dirty-tracking win.
 
-    Inside ``assign`` (array loop, vectorized backends): ``components_s``
+    Inside ``assign`` (vectorized backends): ``components_s``
     finds the affinity components (``components`` of them) and
     ``problems_s`` builds the fill problems of the dirty ones
     (``dirty_components``) before ``FluidEngine.solve_batch``, whose parts
@@ -127,11 +119,10 @@ class SimProfile:
     recalculation, admission, realign); it is booked also inside the phase
     that called it (``events`` for arrivals, ``step`` for pending-queue
     retries after a job completes).  ``active_hits`` and ``active_misses``
-    split the array loop's ticks on which the set of active flow slots
-    changed between the membership cache's answers and rebuilds of the
-    ordered active set (``_active_slots``)."""
+    split the ticks on which the set of active flow slots changed between
+    the membership cache's answers and rebuilds of the ordered active set
+    (``_active_slots``)."""
 
-    loop: str = ""
     ticks: int = 0
     assign_s: float = 0.0
     next_event_s: float = 0.0
@@ -162,27 +153,10 @@ class SimProfile:
 
 
 @dataclasses.dataclass
-class FlowState:
-    job: str
-    node: str  # source host link
-    demand_gbps: float
-    remaining_gb: float
-    rate_gbps: float = 0.0
-    # full link path (source host link first, then fabric links); defaults
-    # to the host link only — the seed's star model
-    links: Tuple[str, ...] = ()
-
-    def __post_init__(self) -> None:
-        if not self.links:
-            self.links = (self.node,)
-
-
-@dataclasses.dataclass
 class JobState:
     job: Job
     phase: str = WAITING
     phase_end: float = math.inf
-    flows: List[FlowState] = dataclasses.field(default_factory=list)
     iter_index: int = 0
     iter_start: float = 0.0
     durations_ms: List[float] = dataclasses.field(default_factory=list)
@@ -192,8 +166,8 @@ class JobState:
     start_time: float = 0.0
     finish_time: Optional[float] = None
     comm_extra_ms: float = 0.0  # latency penalty tail of the comm phase
-    # array event loop: position in the simulator's job arrays (admission
-    # order); the flow-table slots the job owns from its first comm phase
+    # position in the simulator's job arrays (admission order); the
+    # flow-table slots the job owns from its first comm phase
     # until it is done, departs, stalls or its flow specs change; those
     # same slots while a comm phase is on (None otherwise)
     index: int = -1
@@ -249,7 +223,7 @@ _COMM_CODE = _PHASE_CODE[COMM]
 class _FlowTable:
     """Array-resident flow state (struct-of-arrays with a free list).
 
-    The array event loop's single source of truth for per-flow state:
+    The event loop's single source of truth for per-flow state:
     ``demand``/``remaining``/``rate`` are float64 (the oracle's precision),
     ``job``/``pos`` key each slot to (job admission index, position inside
     the job's flow list) — the seed's iteration order, which every
@@ -429,14 +403,13 @@ class ClusterSimulator:
         # backend-swappable rate-sharing core; the allocatable-capacity map
         # is cached per cluster epoch (every capacity/background mutation
         # bumps it), so steady-state iterations skip the rebuild
-        self.fluid = FluidEngine(backend=config.fluid_backend,
-                                 incremental=config.fluid_incremental)
+        self.fluid = FluidEngine(backend=config.fluid_backend)
         self.fluid.timed = config.profile
         # TraceAnnotation while a profiler session records (set per run())
         self._annotate = None
         self._caps_fn: Optional[Callable[[str], float]] = None
         self._caps_epoch: int = -1
-        # whole-tick rate memo (vectorized incremental backends): every
+        # whole-tick rate memo (vectorized backends): every
         # link's allocatable capacity as bytes, rebuilt lazily per cluster
         # epoch, + the active flows' content ids -> the tick's rate vector
         self._caps_bytes: Optional[bytes] = None
@@ -446,17 +419,12 @@ class ClusterSimulator:
         self.delivered_gb: Dict[str, float] = {l: 0.0 for l in cluster.link_ids}
         self.now = 0.0
         self.rejected: List[str] = []
-        if config.event_loop not in ("array", "legacy"):
-            raise ValueError(
-                f"unknown event_loop {config.event_loop!r}; "
-                "expected 'array' or 'legacy'")
-        self._array_mode = config.event_loop == "array"
         self.profile: Optional[SimProfile] = (
-            SimProfile(loop=config.event_loop) if config.profile else None)
+            SimProfile() if config.profile else None)
         # ---- array-resident state (DESIGN.md section 17) ----
         # link registry: contiguous delivered-GB vector aligned with the
         # cluster's link ids (the dict above stays the external view and is
-        # synced at _result time in array mode)
+        # synced at _result time)
         self._link_ids: List[str] = list(cluster.link_ids)
         self._link_index: Dict[str, int] = {
             l: i for i, l in enumerate(self._link_ids)}
@@ -569,19 +537,6 @@ class ClusterSimulator:
             self._pending = still
 
     # --------------------------------------------------------------- traffic
-    def _make_flows(self, job: Job, comm_ms: float) -> List[FlowState]:
-        """One flow per used host link; the path extends over the source
-        leaf's uplink when the job spans leaves.  The flow specification
-        (which links, how much demand) comes from the unified contention
-        layer — the simulator only adds volume (demand x ACTUAL comm
-        time, which silent drift may have moved off the declared
-        profile)."""
-        return [
-            FlowState(job.name, fs.node, fs.demand_gbps,
-                      fs.demand_gbps * comm_ms / 1e3, links=fs.links)
-            for fs in self._flow_specs(job)
-        ]
-
     def _latency_penalty(self, job: Job) -> float:
         nodes = job.nodes_used()
         if len(nodes) <= 1:
@@ -616,20 +571,6 @@ class ClusterSimulator:
             self._caps_epoch = epoch
             self._caps_bytes = None
         return self._caps_fn
-
-    def _assign_rates(self) -> None:
-        """Max-min fair share over each flow's link path, capped at r^BW.
-
-        Delegates to the backend-swappable fluid engine (``core/fluid.py``).
-        Star topology (every path a single host link): per-link water
-        filling, numerically identical to the seed. Multi-link paths
-        (fabric uplinks): progressive filling with per-link bottlenecks.
-        """
-        active = [f for st in self.jobs.values() for f in st.flows
-                  if f.remaining_gb > EPS]
-        if not active:
-            return
-        self.fluid.assign(active, self._allocatable())
 
     # ------------------------------------------------- array-resident state
     def _register_job(self, st: JobState) -> None:
@@ -667,15 +608,15 @@ class ClusterSimulator:
     def _start_comm_flows(self, st: JobState, comm_ms: float) -> bool:
         """Start the job's comm-phase flows; False for single-node jobs.
 
-        Array mode fills the job's own table slots (reserved at its first
-        comm phase, or anew when its flow specs changed), which carry the
-        (job index, spec position) key — the seed's flow iteration order —
-        with the phase's volumes, sets their membership bits and marks the
-        touched links dirty; legacy mode builds the historical FlowState
-        objects."""
-        if not self._array_mode:
-            st.flows = self._make_flows(st.job, comm_ms)
-            return bool(st.flows)
+        One flow per used host link, its path extended over the source
+        leaf's uplink when the job spans leaves; the specs (links, demand)
+        come from the unified contention layer, the volume is demand x the
+        ACTUAL comm time (which silent drift may have moved off the
+        declared profile).  Fills the job's own table slots (reserved at
+        its first comm phase, or anew when its flow specs changed), which
+        carry the (job index, spec position) key — the seed's flow
+        iteration order — sets their membership bits and marks the touched
+        links dirty."""
         specs = self._flow_specs(st.job)
         own = st.owned
         if own is not None and own.specs is not specs:
@@ -733,9 +674,6 @@ class ClusterSimulator:
         """End the job's comm-phase flows (comm end / departure / stall);
         still-active flows leave their links, so those links' rates are
         invalidated.  The job keeps its slots."""
-        if not self._array_mode:
-            st.flows = []
-            return
         if st.flow_slots is not None:
             tbl = self._flows
             for s in st.flow_slots.tolist():
@@ -746,16 +684,6 @@ class ClusterSimulator:
         st.flow_slots = None
         self._jhasflows[st.index] = False
         self._junfin[st.index] = 0
-
-    def _job_has_flows(self, st: JobState) -> bool:
-        if self._array_mode:
-            return bool(self._jhasflows[st.index])
-        return bool(st.flows)
-
-    def _job_flows_done(self, st: JobState) -> bool:
-        if self._array_mode:
-            return self._junfin[st.index] == 0
-        return all(f.remaining_gb <= EPS for f in st.flows)
 
     def _active_slots(self) -> np.ndarray:
         """Alive flows with volume left, in (job index, position) order —
@@ -809,9 +737,7 @@ class ClusterSimulator:
         self._annotate = (trace_annotation() if self.profile is not None
                           else None)
         self.fluid.annotate = self._annotate
-        if self._array_mode:
-            return self._run_array()
-        return self._run_legacy()
+        return self._run_array()
 
     def _validate_events(self) -> None:
         """Boundary validation of the event stream (DESIGN.md section 19).
@@ -853,96 +779,13 @@ class ClusterSimulator:
             self._events = collections.deque(
                 ev for i, ev in enumerate(self._events) if i not in drop)
 
-    def _run_legacy(self) -> SimResult:
-        """The pre-array per-object event loop, preserved verbatim: the
-        parity oracle of the array loop (pinned bit-for-bit by
-        ``tests/test_event_loop.py``) and the ``bench_dynamic_throughput``
-        pre-optimization reference."""
-        cfg = self.config
-        prof = self.profile
-        perf = time.perf_counter
-        while self.now < cfg.duration_ms:
-            t0 = perf() if prof is not None else 0.0
-            self._assign_rates()
-            if prof is not None:
-                t1 = perf()
-                prof.assign_s += t1 - t0
-                prof.solves += 1
-            # next event time
-            nxt = cfg.duration_ms
-            for st in self.jobs.values():
-                if st.phase in (COMPUTE, PAUSED, WAITING):
-                    nxt = min(nxt, st.phase_end)
-                elif st.phase == COMM:
-                    if st.flows:
-                        for f in st.flows:
-                            if f.remaining_gb > EPS and f.rate_gbps > EPS:
-                                nxt = min(nxt, self.now + f.remaining_gb / f.rate_gbps * 1e3)
-                    else:
-                        nxt = min(nxt, st.phase_end)
-            if self._events:
-                nxt = min(nxt, self._events[0].time_ms)
-            if self._arrivals:
-                nxt = min(nxt, self._arrivals[0][0])
-            nxt = max(nxt, self.now)  # no time travel
-            dt = nxt - self.now
-            if prof is not None:
-                t2 = perf()
-                prof.next_event_s += t2 - t1
-
-            # advance flows and accounting
-            if dt > 0:
-                for st in self.jobs.values():
-                    for f in st.flows:
-                        if f.remaining_gb > EPS:
-                            moved = min(f.remaining_gb, f.rate_gbps * dt / 1e3)
-                            f.remaining_gb -= moved
-                            for l in f.links:
-                                self.delivered_gb[l] += moved
-                for bg in self.background:
-                    self.delivered_gb[bg.link_id] += bg.rate_gbps * dt / 1e3
-            self.now = nxt
-            if self.telemetry is not None:
-                self.telemetry.now_ms = self.now
-            if prof is not None:
-                t3 = perf()
-                prof.advance_s += t3 - t2
-                prof.ticks += 1
-            if self.now >= cfg.duration_ms:
-                break
-
-            # dynamic-environment events (traffic / background / capacity /
-            # departures), in timestamp order
-            while self._events and self._events[0].time_ms <= self.now + EPS:
-                self._apply_event(self._events.popleft())
-                if prof is not None:
-                    prof.events_applied += 1
-
-            # online arrivals (may add jobs)
-            self._process_arrivals()
-            if prof is not None:
-                t4 = perf()
-                prof.events_s += t4 - t3
-
-            # job phase transitions
-            done_before = {n for n, s in self.jobs.items() if s.phase == DONE}
-            for st in list(self.jobs.values()):
-                self._step_job(st)
-            for name, st in list(self.jobs.items()):
-                if st.phase == DONE and name not in done_before:
-                    self._on_job_done(st)
-            if prof is not None:
-                prof.step_s += perf() - t4
-                prof.steps += len(self.jobs)
-        return self._result()
-
     def _run_array(self) -> SimResult:
-        """The array event loop: identical tick structure to the legacy
-        loop, but every per-job/per-flow scan is a vectorized reduction
-        over the flat mirrors and rates re-solve only when dirty.  With
-        ``fluid_backend='python'`` the outputs are bit-for-bit equal to
-        ``_run_legacy`` (the oracle-parity contract, DESIGN.md section
-        17)."""
+        """The event loop.  Each tick: assign rates (re-solved only where
+        dirty), find the next event (one min over the job mirrors, one
+        over the active flows), advance the flows, apply due events and
+        arrivals, and step the due jobs.  With ``fluid_backend='python'``
+        the outputs are bit-for-bit those pinned in
+        ``tests/goldens/sim_goldens.json`` (DESIGN.md section 17)."""
         cfg = self.config
         duration = cfg.duration_ms
         prof = self.profile
@@ -1038,7 +881,7 @@ class ClusterSimulator:
 
             # job phase transitions: only DUE jobs step (the seed steps
             # every job every tick, but _step_job is a strict no-op unless
-            # due — pinned by the oracle-parity tests), in admission order
+            # due — pinned by the value goldens), in admission order
             n = len(self._jobs_list)
             thresh = self.now + EPS
             due_mask = self._jnext[:n] <= thresh
@@ -1074,10 +917,10 @@ class ClusterSimulator:
         with the seed's ``_max_min_fair`` (groups in (job, pos) order);
         any multi-link path forces the seed's one global progressive fill.
         Vectorized backends: dirty affinity components are batched through
-        one memo-aware ``fluid.solve_batch`` per tick; with the engine's
-        memo on, a whole-tick memo keyed by every link's capacity and the
-        active flows' content ids in (job, pos) order answers a recurring
-        flow mix before any of that runs."""
+        one memo-aware ``fluid.solve_batch`` per tick, behind a whole-tick
+        memo keyed by every link's capacity and the active flows' content
+        ids in (job, pos) order, which answers a recurring flow mix before
+        any of that runs."""
         act = self._active_slots()
         if act.size == 0:
             return
@@ -1116,10 +959,8 @@ class ClusterSimulator:
                 paths = [tbl.paths[s] for s in act]
                 caps = {l: cap_of(l) for p in paths for l in p}
                 tbl.rate[act] = _progressive_fill(demands, paths, caps)
-        elif self.fluid.incremental:
-            self._assign_memoized(act, cap_of)
         else:
-            self._assign_vectorized(act, cap_of)
+            self._assign_memoized(act, cap_of)
         self._dirty_links.clear()
         self._all_dirty = False
 
@@ -1368,9 +1209,9 @@ class ClusterSimulator:
     def _apply_host_failure(self, ev: events_mod.HostFailure) -> None:
         """A worker dies: its host link fails and every job with a task
         on it stalls — flows drop (their links' rates re-solve), the
-        interrupted iteration is abandoned, and the job stays inert (both
-        loops: STALLED never appears in next-event reductions) until
-        every failed host of the job recovers."""
+        interrupted iteration is abandoned, and the job stays inert
+        (STALLED never appears in next-event reductions) until every
+        failed host of the job recovers."""
         host = ev.host
         if host not in self.cluster.nodes:
             self._warn_unknown("host", host)
@@ -1564,8 +1405,8 @@ class ClusterSimulator:
             self._sync_job(st)
             return
         if st.phase == COMM:
-            if self._job_has_flows(st):
-                if self._job_flows_done(st):
+            if self._jhasflows[st.index]:
+                if self._junfin[st.index] == 0:
                     # flows done -> latency tail, then iteration completes
                     if st.comm_extra_ms > 0:
                         self._clear_flows(st)
@@ -1678,11 +1519,10 @@ class ClusterSimulator:
 
     # ---------------------------------------------------------------- metrics
     def _result(self) -> SimResult:
-        if self._array_mode:
-            # delivered-GB lived in the float64 vector during the run (same
-            # addition sequence as the legacy dict); publish it back
-            for l, i in self._link_index.items():
-                self.delivered_gb[l] = float(self._delivered_vec[i])
+        # delivered-GB lived in the float64 vector during the run (the
+        # seed's addition sequence); publish it back
+        for l, i in self._link_index.items():
+            self.delivered_gb[l] = float(self._delivered_vec[i])
         elapsed = max(self.now, 1.0)
         link_ids = self.cluster.link_ids
         link_util = {}

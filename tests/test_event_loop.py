@@ -2,10 +2,12 @@
 
 Four layers of evidence that the vectorized hot path is safe:
 
-  * oracle parity — ``event_loop='array'`` (the default) must reproduce
-    ``event_loop='legacy'`` (the pre-array per-object loop, retained
-    verbatim) BIT-FOR-BIT on every pinned golden (S1–S5, F2, F4, J1, D1,
-    D2) and on an online production-trace run with arrivals/departures;
+  * value goldens — the loop reproduces BIT-FOR-BIT the results stored in
+    ``tests/goldens/sim_goldens.json`` (recorded from the per-object loop
+    the array loop replaced; ``tests/goldens/record.py``) on every pinned
+    scenario (S1–S5, F2, F4, J1, D1, D2), on an online production-trace
+    run with arrivals/departures, and on the edge-case and flow-slot runs
+    below;
   * edge cases the vectorized reductions must not regress: starved flows
     with zero rate (no finish event until the duration cap), multiple
     events sharing one timestamp, an arrival tied exactly with an event;
@@ -21,6 +23,7 @@ import math
 import numpy as np
 import pytest
 
+from goldens import record
 from repro.configs.metronome_testbed import (DYNAMIC_SNAPSHOTS, MODEL_FLEET,
                                              dynamic_scenario, make_snapshot,
                                              snapshot_scenario)
@@ -36,7 +39,6 @@ from repro.core.simulator import EPS, COMM, ClusterSimulator, SimConfig
 from repro.core.workload import Workload, make_job
 
 CFG = SimConfig(duration_ms=20_000.0, seed=3, jitter_std=0.01)
-LEGACY = dataclasses.replace(CFG, event_loop="legacy")
 
 PINNED = ["S1", "S2", "S3", "S4", "S5", "F2", "F4", "J1"]
 
@@ -64,6 +66,23 @@ def sim_equal(a, b):
     assert a.reconfigurations == b.reconfigurations
 
 
+def assert_golden(name, out):
+    """``out`` (an experiment result or a (simulator, SimResult) pair)
+    equals the fixture's entry ``name`` bit for bit."""
+    res, extras = record.result_of(out)
+    want, want_extras = record.golden(name)
+    sim_equal(res, want)
+    assert extras == want_extras
+
+
+def pinned(runs, name, **hooks):
+    """Call ``runs[name]`` (with ``hooks``), assert what it ran against
+    the fixture, and return what it returned."""
+    out = runs[name](**hooks)
+    assert_golden(name, out)
+    return out
+
+
 def small_cluster(n=2, bw=25.0):
     nodes = [Node(f"n{i}", Resources(cpu=32, mem=256, gpu=4), bw_gbps=bw)
              for i in range(n)]
@@ -84,77 +103,185 @@ def _scheduled(jobs):
     return cl, fw.registry
 
 
-def _both_loops(jobs_factory, cfg, prepare=None, **sim_kwargs):
-    """Run the same scheduled setup through both loops; ``prepare`` sees
-    each simulator before it runs."""
-    out = []
-    for loop in ("array", "legacy"):
-        jobs = jobs_factory()
-        cl, registry = _scheduled(jobs)
-        sim = ClusterSimulator(
-            cl, jobs, dataclasses.replace(cfg, event_loop=loop),
-            registry=registry,
-            **{k: (v() if callable(v) else v) for k, v in sim_kwargs.items()})
-        if prepare is not None:
-            prepare(sim)
-        out.append((sim, sim.run()))
-    return out
+def _one_run(jobs, cfg, prepare=None, **sim_kwargs):
+    """Schedule ``jobs`` and run them; ``prepare`` sees the simulator
+    before it runs.  Returns (simulator, SimResult)."""
+    cl, registry = _scheduled(jobs)
+    sim = ClusterSimulator(cl, jobs, cfg, registry=registry, **sim_kwargs)
+    if prepare is not None:
+        prepare(sim)
+    return sim, sim.run()
 
 
 # ---------------------------------------------------------------------------
-# oracle parity: array loop bit-for-bit against the retained legacy loop
+# the runs pinned by value (tests/goldens/sim_goldens.json)
+# ---------------------------------------------------------------------------
+
+def _static(sid):
+    return lambda: run(snapshot_scenario(sid, n_iterations=30),
+                       Policy("metronome"), CFG)
+
+
+def _dynamic(sid):
+    return lambda: run(dynamic_scenario(sid, n_iterations=30),
+                       Policy("metronome"), CFG)
+
+
+def _online_trace():
+    """Arrivals + departures through the full online path."""
+    from repro.core.harness import run_trace_experiment
+    from repro.core.trace import (generate_trace, trace_departure_events,
+                                  trace_to_jobs)
+    trace = generate_trace(
+        MODEL_FLEET, duration_s=600, total_gpus=13, target_load=0.8,
+        seed=2, job_duration_range_s=(60, 120))[:6]
+    evs = trace_departure_events(trace, time_scale=1.0)
+    cluster, _, _ = make_snapshot("S1")
+    jobs = trace_to_jobs(trace, MODEL_FLEET, time_scale=1.0,
+                         open_ended=True)
+    wls = [Workload(name=j.name, jobs=[j]) for j in jobs]
+    for w in wls:
+        for j in w.jobs:
+            j.workload = w.name
+            for t in j.tasks:
+                t.workload = w.name
+    cfg = SimConfig(duration_ms=900_000, seed=0, jitter_std=0.01)
+    return run_trace_experiment("metronome", cluster, wls, cfg,
+                                events=list(evs))
+
+
+EDGE_CFG = SimConfig(duration_ms=10_000.0, seed=0, jitter_std=0.0)
+
+
+def _edge_job(name="j", **kw):
+    kw.setdefault("n_tasks", 2)
+    kw.setdefault("period_ms", 100)
+    kw.setdefault("duty", 0.4)
+    kw.setdefault("bw_gbps", 20.0)
+    kw.setdefault("n_iterations", 5)
+    return make_job(name, **kw)
+
+
+def _starved_flow():
+    """Background traffic claims n0's full capacity."""
+    return _one_run([_edge_job()], EDGE_CFG, events=[
+        BackgroundFlowChange(50.0, link="n0", rate_gbps=25.0)])
+
+
+def _shared_timestamp():
+    """Three events due at one tick."""
+    return _one_run([_edge_job(n_iterations=40)], EDGE_CFG, events=[
+        BackgroundFlowChange(5_000.0, link="n0", rate_gbps=10.0),
+        LinkCapacityChange(5_000.0, link="n1", allocatable_gbps=12.0),
+        TrafficChange(5_000.0, job="j", duty_mult=1.5)])
+
+
+def _arrival_tied_with_event():
+    """An online arrival at exactly an event's timestamp."""
+    cl = small_cluster()
+    fw = SchedulingFramework(cl, MetronomePlugin())
+    early = _edge_job("early", n_iterations=80)
+    assert fw.schedule_workload(wl(early))
+    sim = ClusterSimulator(
+        cl, [early], EDGE_CFG, registry=fw.registry, framework=fw,
+        arrivals=[wl(_edge_job("late", submit_time_s=5.0))],
+        events=[BackgroundFlowChange(5_000.0, link="n0", rate_gbps=5.0)])
+    return sim, sim.run()
+
+
+def _pair_jobs():
+    # two periodic jobs on the same two host links
+    return [make_job("a", n_tasks=2, period_ms=100, duty=0.4,
+                     bw_gbps=20.0, n_iterations=60),
+            make_job("b", n_tasks=2, period_ms=130, duty=0.3,
+                     bw_gbps=10.0, n_iterations=60)]
+
+
+def _departure_reuse(prepare=None):
+    """A job departs and a later arrival takes its released slots."""
+    cl = small_cluster()
+    fw = SchedulingFramework(cl, MetronomePlugin())
+    jobs = [make_job("early", n_tasks=2, period_ms=100, duty=0.4,
+                     bw_gbps=20.0, n_iterations=200),
+            make_job("stay", n_tasks=2, period_ms=130, duty=0.3,
+                     bw_gbps=10.0, n_iterations=200)]
+    for j in jobs:
+        assert fw.schedule_workload(wl(j))
+    late = make_job("late", n_tasks=2, period_ms=90, duty=0.5,
+                    bw_gbps=15.0, n_iterations=200, submit_time_s=3.0)
+    sim = ClusterSimulator(
+        cl, jobs, SimConfig(duration_ms=8_000.0, seed=0, jitter_std=0.0),
+        registry=fw.registry, framework=fw, arrivals=[wl(late)],
+        events=[JobDeparture(2_000.0, job="early")])
+    if prepare is not None:
+        prepare(sim)
+    return sim, sim.run()
+
+
+def _host_flap(prepare=None):
+    """n0 fails and recovers under both jobs."""
+    return _one_run(
+        _pair_jobs(), SimConfig(duration_ms=6_000.0, seed=0, jitter_std=0.0),
+        prepare=prepare, events=[HostFailure(2_000.0, host="n0"),
+                                 HostRecovery(2_500.0, host="n0")])
+
+
+def _changed_specs(prepare=None):
+    """Job a's flow specs halve their demand from 2 s on."""
+    def halve(sim):
+        inner = sim._flow_specs
+
+        def specs(job):
+            out = inner(job)
+            if sim.now < 2_000.0 or job.name != "a":
+                return out
+            return [dataclasses.replace(fs, demand_gbps=fs.demand_gbps / 2)
+                    for fs in out]
+
+        sim._flow_specs = specs
+        if prepare is not None:
+            prepare(sim)
+
+    return _one_run(
+        _pair_jobs(), SimConfig(duration_ms=4_000.0, seed=0, jitter_std=0.0),
+        prepare=halve)
+
+
+GOLDEN_RUNS = {
+    **{sid: _static(sid) for sid in PINNED},
+    **{sid: _dynamic(sid) for sid in DYNAMIC_SNAPSHOTS},
+    "online_trace": _online_trace,
+    "edge.starved_flow": _starved_flow,
+    "edge.shared_timestamp": _shared_timestamp,
+    "edge.arrival_tied_with_event": _arrival_tied_with_event,
+    "slots.departure_reuse": _departure_reuse,
+    "slots.host_flap": _host_flap,
+    "slots.changed_specs": _changed_specs,
+}
+
+
+# ---------------------------------------------------------------------------
+# value goldens: the array loop bit-for-bit against the fixture
 # ---------------------------------------------------------------------------
 
 class TestOracleParity:
     @pytest.mark.parametrize("sid", PINNED)
     def test_static_snapshots(self, sid):
-        scen = snapshot_scenario(sid, n_iterations=30)
-        arr = run(scen, Policy("metronome"), CFG)
-        leg = run(scen, Policy("metronome"), LEGACY)
-        sim_equal(arr.sim, leg.sim)
-        assert arr.accepted == leg.accepted
-        assert arr.placements == leg.placements
+        pinned(GOLDEN_RUNS, sid)
 
     @pytest.mark.parametrize("sid", DYNAMIC_SNAPSHOTS)
     def test_dynamic_snapshots(self, sid):
-        scen = dynamic_scenario(sid, n_iterations=30)
-        arr = run(scen, Policy("metronome"), CFG)
-        leg = run(scen, Policy("metronome"), LEGACY)
-        sim_equal(arr.sim, leg.sim)
-        assert arr.accepted == leg.accepted
+        pinned(GOLDEN_RUNS, sid)
 
     def test_online_trace_with_departures(self):
-        """Arrivals + departures through the full online path: both loops
-        admit, run, and truncate identically."""
-        from repro.core.harness import run_trace_experiment
-        from repro.core.trace import (generate_trace, trace_departure_events,
-                                      trace_to_jobs)
-        trace = generate_trace(
-            MODEL_FLEET, duration_s=600, total_gpus=13, target_load=0.8,
-            seed=2, job_duration_range_s=(60, 120))[:6]
-        evs = trace_departure_events(trace, time_scale=1.0)
-        results = []
-        for loop in ("array", "legacy"):
-            cluster, _, _ = make_snapshot("S1")
-            jobs = trace_to_jobs(trace, MODEL_FLEET, time_scale=1.0,
-                                 open_ended=True)
-            wls = [Workload(name=j.name, jobs=[j]) for j in jobs]
-            for w in wls:
-                for j in w.jobs:
-                    j.workload = w.name
-                    for t in j.tasks:
-                        t.workload = w.name
-            cfg = SimConfig(duration_ms=900_000, seed=0, jitter_std=0.01,
-                            event_loop=loop)
-            results.append(run_trace_experiment(
-                "metronome", cluster, wls, cfg, events=list(evs)))
-        sim_equal(results[0].sim, results[1].sim)
-        assert results[0].rejected == results[1].rejected
+        """Arrivals + departures through the full online path: admitted,
+        run and truncated as pinned."""
+        pinned(GOLDEN_RUNS, "online_trace")
 
-    def test_unknown_event_loop_rejected(self):
-        with pytest.raises(ValueError, match="unknown event_loop"):
-            ClusterSimulator(small_cluster(), [],
-                             SimConfig(event_loop="turbo"))
+    def test_goldens_cover_every_run(self):
+        """The fixture holds exactly the runs the tests look up: no entry
+        is orphaned and no run is left unpinned."""
+        assert set(record.load()) == set(record.runs())
 
 
 # ---------------------------------------------------------------------------
@@ -162,72 +289,34 @@ class TestOracleParity:
 # ---------------------------------------------------------------------------
 
 class TestEdgeCases:
-    CFG = SimConfig(duration_ms=10_000.0, seed=0, jitter_std=0.0)
-
-    def _job(self, name="j", **kw):
-        kw.setdefault("n_tasks", 2)
-        kw.setdefault("period_ms", 100)
-        kw.setdefault("duty", 0.4)
-        kw.setdefault("bw_gbps", 20.0)
-        kw.setdefault("n_iterations", 5)
-        return make_job(name, **kw)
+    CFG = EDGE_CFG
 
     def test_starved_flow_never_finishes(self):
         """Background traffic claims a link's full capacity: the flow rate
         is zero, no finish event ever fires, and the loop still terminates
-        at the duration cap (in both loops, identically)."""
-        evs = [BackgroundFlowChange(50.0, link="n0", rate_gbps=25.0)]
-        (sa, ra), (sl, rl) = _both_loops(
-            lambda: [self._job()], self.CFG, events=lambda: list(evs))
-        sim_equal(ra, rl)
-        for sim, res in ((sa, ra), (sl, rl)):
-            st = sim.jobs["j"]
-            assert st.phase == COMM  # stuck mid-comm at the cap
-            assert math.isnan(res.finish_times_ms["j"])
-            assert res.iterations_done["j"] == 0
-            assert sim.now == pytest.approx(self.CFG.duration_ms)
+        at the duration cap."""
+        sim, res = pinned(GOLDEN_RUNS, "edge.starved_flow")
+        st = sim.jobs["j"]
+        assert st.phase == COMM  # stuck mid-comm at the cap
+        assert math.isnan(res.finish_times_ms["j"])
+        assert res.iterations_done["j"] == 0
+        assert sim.now == pytest.approx(self.CFG.duration_ms)
 
     def test_multiple_events_share_one_timestamp(self):
         """All events due at one tick drain together, in stream order."""
-        evs = [BackgroundFlowChange(5_000.0, link="n0", rate_gbps=10.0),
-               LinkCapacityChange(5_000.0, link="n1", allocatable_gbps=12.0),
-               TrafficChange(5_000.0, job="j", duty_mult=1.5)]
-        (sa, ra), (sl, rl) = _both_loops(
-            lambda: [self._job(n_iterations=40)], self.CFG,
-            events=lambda: list(evs))
-        sim_equal(ra, rl)
-        for sim in (sa, sl):
-            assert sim.cluster.node("n0").allocatable_gbps == pytest.approx(15.0)
-            assert sim.cluster.node("n1").allocatable_gbps == pytest.approx(12.0)
-            # duty 0.4 * 1.5 -> comm 60ms of the 100ms period
-            assert sim.jobs["j"].job.traffic.duty == pytest.approx(0.6)
+        sim, _ = pinned(GOLDEN_RUNS, "edge.shared_timestamp")
+        assert sim.cluster.node("n0").allocatable_gbps == pytest.approx(15.0)
+        assert sim.cluster.node("n1").allocatable_gbps == pytest.approx(12.0)
+        # duty 0.4 * 1.5 -> comm 60ms of the 100ms period
+        assert sim.jobs["j"].job.traffic.duty == pytest.approx(0.6)
 
     def test_arrival_tied_with_event_time(self):
         """An online arrival at exactly an event's timestamp: the event
-        applies and the job is admitted in the same tick, identically in
-        both loops."""
-        def arrivals():
-            late = self._job("late", submit_time_s=5.0)
-            return [wl(late)]
-
-        results = []
-        for loop in ("array", "legacy"):
-            cl = small_cluster()
-            fw = SchedulingFramework(cl, MetronomePlugin())
-            early = self._job("early", n_iterations=80)
-            assert fw.schedule_workload(wl(early))
-            sim = ClusterSimulator(
-                cl, [early], dataclasses.replace(self.CFG, event_loop=loop),
-                registry=fw.registry, framework=fw, arrivals=arrivals(),
-                events=[BackgroundFlowChange(5_000.0, link="n0",
-                                             rate_gbps=5.0)])
-            results.append((sim, sim.run()))
-        (sa, ra), (sl, rl) = results
-        sim_equal(ra, rl)
-        for sim, res in results:
-            assert "late" in sim.jobs
-            assert res.iterations_done["late"] > 0
-            assert sim.cluster.node("n0").allocatable_gbps == pytest.approx(20.0)
+        applies and the job is admitted in the same tick."""
+        sim, res = pinned(GOLDEN_RUNS, "edge.arrival_tied_with_event")
+        assert "late" in sim.jobs
+        assert res.iterations_done["late"] > 0
+        assert sim.cluster.node("n0").allocatable_gbps == pytest.approx(20.0)
 
 
 # ---------------------------------------------------------------------------
@@ -297,9 +386,9 @@ class TestUnknownTargetWarnings:
 # ---------------------------------------------------------------------------
 
 class TestProfile:
-    def _cfg(self, loop):
+    def _cfg(self, backend="python"):
         return SimConfig(duration_ms=10_000.0, seed=0, jitter_std=0.0,
-                         event_loop=loop, profile=True)
+                         fluid_backend=backend, profile=True)
 
     def _jobs(self):
         return [make_job("a", n_tasks=2, period_ms=100, duty=0.4,
@@ -307,14 +396,15 @@ class TestProfile:
                 make_job("b", n_tasks=2, period_ms=130, duty=0.3,
                          bw_gbps=10.0, n_iterations=40, submit_time_s=0.013)]
 
-    @pytest.mark.parametrize("loop", ["array", "legacy"])
-    def test_profile_populated(self, loop):
+    @pytest.mark.parametrize("backend", ["python", "jnp"])
+    def test_profile_populated(self, backend):
         jobs = self._jobs()
         cl, registry = _scheduled(jobs)
-        sim = ClusterSimulator(cl, jobs, self._cfg(loop), registry=registry)
+        sim = ClusterSimulator(cl, jobs, self._cfg(backend),
+                               registry=registry)
         res = sim.run()
         p = res.profile
-        assert p is not None and p.loop == loop
+        assert p is not None and sim.fluid.backend == backend
         assert p.ticks > 0 and p.steps > 0 and p.solves > 0
         phases = p.phase_seconds()
         assert set(phases) == {"assign", "next_event", "advance", "events",
@@ -331,8 +421,7 @@ class TestProfile:
             make_job("c", n_tasks=1, period_ms=17, duty=0.3, bw_gbps=1.0,
                      n_iterations=400)]
         cl, registry = _scheduled(jobs)
-        sim = ClusterSimulator(cl, jobs, self._cfg("array"),
-                               registry=registry)
+        sim = ClusterSimulator(cl, jobs, self._cfg(), registry=registry)
         p = sim.run().profile
         assert p.skipped_assigns > 0
         assert p.solves + p.skipped_assigns <= p.ticks
@@ -657,14 +746,8 @@ def _check_membership(sim):
 
 
 class TestMembershipCache:
-    def _jobs(self):
-        return [make_job("a", n_tasks=2, period_ms=100, duty=0.4,
-                         bw_gbps=20.0, n_iterations=60),
-                make_job("b", n_tasks=2, period_ms=130, duty=0.3,
-                         bw_gbps=10.0, n_iterations=60)]
-
     def _sim(self, backend, prepare=()):
-        jobs = self._jobs()
+        jobs = _pair_jobs()
         cl, registry = _scheduled(jobs)
         sim = ClusterSimulator(
             cl, jobs, SimConfig(duration_ms=6_000.0, seed=0, jitter_std=0.0,
@@ -706,31 +789,17 @@ class TestMembershipCache:
     def test_departure_reuses_slots_and_clears_cache(self):
         """A job departs, a later arrival takes its released slots: the
         reservation writes their content and empties the cache, and the
-        python backend stays bit-for-bit with the legacy loop."""
-        cfg = SimConfig(duration_ms=8_000.0, seed=0, jitter_std=0.0)
-        runs = []
-        for loop in ("array", "legacy"):
-            cl = small_cluster()
-            fw = SchedulingFramework(cl, MetronomePlugin())
-            jobs = [make_job("early", n_tasks=2, period_ms=100, duty=0.4,
-                             bw_gbps=20.0, n_iterations=200),
-                    make_job("stay", n_tasks=2, period_ms=130, duty=0.3,
-                             bw_gbps=10.0, n_iterations=200)]
-            for j in jobs:
-                assert fw.schedule_workload(wl(j))
-            late = make_job("late", n_tasks=2, period_ms=90, duty=0.5,
-                            bw_gbps=15.0, n_iterations=200,
-                            submit_time_s=3.0)
-            sim = ClusterSimulator(
-                cl, jobs, dataclasses.replace(cfg, event_loop=loop),
-                registry=fw.registry, framework=fw, arrivals=[wl(late)],
-                events=[JobDeparture(2_000.0, job="early")])
-            got = _record_reservations(sim) if loop == "array" else None
-            checked = _check_membership(sim) if loop == "array" else None
-            runs.append((sim, sim.run(), got, checked))
-        (sa, ra, got, checked), (sl, rl, _, _) = runs
-        sim_equal(ra, rl)
-        assert checked
+        python backend stays bit-for-bit with the pinned run."""
+        seen = {}
+
+        def prepare(sim):
+            seen["got"] = _record_reservations(sim)
+            seen["checked"] = _check_membership(sim)
+
+        sa, ra = pinned(GOLDEN_RUNS, "slots.departure_reuse",
+                        prepare=prepare)
+        got = seen["got"]
+        assert seen["checked"]
         first = {name: (t, slots, left) for t, name, slots, left
                  in reversed(got)}
         assert set(first) == {"early", "stay", "late"}
@@ -743,20 +812,14 @@ class TestMembershipCache:
     def test_host_failure_releases_and_rereserves(self):
         """A host failure stalls the jobs on it and releases their slots;
         on recovery they reserve anew, emptying the cache, and the python
-        backend stays bit-for-bit with the legacy loop."""
-        cfg = SimConfig(duration_ms=6_000.0, seed=0, jitter_std=0.0)
-        evs = [HostFailure(2_000.0, host="n0"),
-               HostRecovery(2_500.0, host="n0")]
+        backend stays bit-for-bit with the pinned run."""
         seen = {}
 
         def prepare(sim):
-            if sim._array_mode:
-                seen["got"] = _record_reservations(sim)
-                seen["checked"] = _check_membership(sim)
+            seen["got"] = _record_reservations(sim)
+            seen["checked"] = _check_membership(sim)
 
-        (sa, ra), (sl, rl) = _both_loops(self._jobs, cfg, prepare=prepare,
-                                         events=lambda: list(evs))
-        sim_equal(ra, rl)
+        _, ra = pinned(GOLDEN_RUNS, "slots.host_flap", prepare=prepare)
         got = seen["got"]
         assert seen["checked"]
         for name in ("a", "b"):
@@ -767,27 +830,14 @@ class TestMembershipCache:
 
     def test_changed_specs_reserve_anew(self):
         """When a job's flow specs change between comm phases it releases
-        its slots and reserves new ones with the new content, in both
-        loops alike."""
-        cfg = SimConfig(duration_ms=4_000.0, seed=0, jitter_std=0.0)
+        its slots and reserves new ones with the new content, and the run
+        stays bit-for-bit with the pinned one."""
         seen = {}
 
         def prepare(sim):
-            inner = sim._flow_specs
+            seen["got"] = _record_reservations(sim)
 
-            def specs(job):
-                out = inner(job)
-                if sim.now < 2_000.0 or job.name != "a":
-                    return out
-                return [dataclasses.replace(fs, demand_gbps=fs.demand_gbps / 2)
-                        for fs in out]
-
-            sim._flow_specs = specs
-            if sim._array_mode:
-                seen["got"] = _record_reservations(sim)
-
-        (sa, ra), (sl, rl) = _both_loops(self._jobs, cfg, prepare=prepare)
-        sim_equal(ra, rl)
+        sa, _ = pinned(GOLDEN_RUNS, "slots.changed_specs", prepare=prepare)
         assert [n for _, n, _, _ in seen["got"]].count("a") == 2
         own = sa.jobs["a"].owned
         assert sa._flows.demand[own.slots].tolist() == [10.0, 10.0]

@@ -8,18 +8,18 @@ Three layers of evidence that the backend swap is safe:
   * scenario-level parity — the pinned snapshots' actual fill problems
     (``LinkView.fill_problem``) through all three backends;
   * bit-for-bit goldens — ``Policy(sim_backend='python')`` must reproduce
-    the default simulation EXACTLY on every pinned scenario (S1–S5, F2,
-    F4, J1, D1, D2): the refactor moved the seed's per-flow loop, it must
-    not have changed it.
+    EXACTLY the results pinned in ``tests/goldens/sim_goldens.json`` on
+    every pinned scenario (S1–S5, F2, F4, J1, D1, D2): the refactor moved
+    the seed's per-flow loop, it must not have changed it.
 
 Plus the machinery that rides along: incremental per-component memoization
-(``FluidStats``), size-bucketed corpus batching (``fill_corpus``), the
+(``FluidStats``), the simulator's affinity components, size-bucketed
+corpus batching (``fill_corpus``), the
 production-trace generator, the ``Policy.sim_backend`` knob, process-mode
 sweeps and the content-keyed sweep cache.
 """
 import dataclasses
 import json
-import math
 import os
 
 import numpy as np
@@ -36,9 +36,11 @@ from repro.core.controller import StopAndWaitController
 from repro.core.experiment import Policy, run, sweep
 from repro.core.framework import SchedulingFramework
 from repro.core.scheduler import MetronomePlugin
-from repro.core.simulator import SimConfig
+from repro.core.cluster import make_fabric_cluster
+from repro.core.simulator import ClusterSimulator, SimConfig
 from repro.core.trace import (active_jobs_at, generate_production_trace,
                               TraceJobSpec)
+from test_event_loop import assert_golden
 
 CFG = SimConfig(duration_ms=20_000.0, seed=3, jitter_std=0.01)
 N_ITER = 30
@@ -192,44 +194,18 @@ class TestScenarioParity:
 # bit-for-bit goldens: backend='python' IS the seed path
 # ---------------------------------------------------------------------------
 
-def _sim_equal(a, b):
-    """Bit-for-bit SimResult equality (NaN-aware float maps)."""
-    def eq(x, y):
-        if isinstance(x, float) and isinstance(y, float):
-            return (math.isnan(x) and math.isnan(y)) or x == y
-        return x == y
-
-    def map_eq(x, y):
-        return set(x) == set(y) and all(eq(x[k], y[k]) for k in x)
-
-    assert a.durations_ms == b.durations_ms
-    assert map_eq(a.time_per_1000_iters_s, b.time_per_1000_iters_s)
-    assert map_eq(a.link_utilization, b.link_utilization)
-    assert eq(a.avg_bw_utilization, b.avg_bw_utilization)
-    assert a.readjustments == b.readjustments
-    assert map_eq(a.finish_times_ms, b.finish_times_ms)
-    assert eq(a.total_completion_ms, b.total_completion_ms)
-    assert a.iterations_done == b.iterations_done
-    assert a.reconfigurations == b.reconfigurations
-
-
 class TestPythonBackendGoldens:
     @pytest.mark.parametrize("sid", PINNED)
     def test_static_snapshots(self, sid):
         scen = snapshot_scenario(sid, n_iterations=N_ITER)
-        default = run(scen, Policy("metronome"), CFG)
-        explicit = run(scen, Policy("metronome", sim_backend="python"), CFG)
-        _sim_equal(default.sim, explicit.sim)
-        assert default.accepted == explicit.accepted
-        assert default.placements == explicit.placements
+        assert_golden(sid, run(scen, Policy("metronome", sim_backend="python"),
+                               CFG))
 
     @pytest.mark.parametrize("sid", DYNAMIC_SNAPSHOTS)
     def test_dynamic_snapshots(self, sid):
         scen = dynamic_scenario(sid, n_iterations=N_ITER)
-        default = run(scen, Policy("metronome"), CFG)
-        explicit = run(scen, Policy("metronome", sim_backend="python"), CFG)
-        _sim_equal(default.sim, explicit.sim)
-        assert default.accepted == explicit.accepted
+        assert_golden(sid, run(scen, Policy("metronome", sim_backend="python"),
+                               CFG))
 
     def test_policy_name_encodes_backend(self):
         assert Policy("metronome").name == "metronome"
@@ -243,55 +219,54 @@ class TestPythonBackendGoldens:
 # incremental per-component memoization
 # ---------------------------------------------------------------------------
 
-class _Flow:
-    def __init__(self, node, demand, links):
-        self.node = node
-        self.demand_gbps = demand
-        self.links = links
-        self.rate_gbps = 0.0
-
-
 class TestIncrementalEngine:
-    def _flows(self):
-        # two affinity components: {hA} and {hB, uplink:1}
-        return [_Flow("hA", 10.0, ("hA",)),
-                _Flow("hA", 6.0, ("hA",)),
-                _Flow("hB", 8.0, ("hB", "uplink:1")),
-                _Flow("hB", 5.0, ("hB",))]
+    CAPS = {"leaf0-host0": 12.0, "leaf1-host0": 10.0, "uplink:leaf1": 6.0}
+
+    def _problems(self, caps):
+        """The two affinity components of four flows: {leaf0-host0} and
+        {leaf1-host0, uplink:leaf1}."""
+        a, b, up = "leaf0-host0", "leaf1-host0", "uplink:leaf1"
+        return [(np.array([10.0, 6.0]), [(a,), (a,)], {a: caps[a]}),
+                (np.array([8.0, 5.0]), [(b, up), (b,)],
+                 {b: caps[b], up: caps[up]})]
 
     def test_components(self):
-        comps = fluid.affinity_components(
-            [f.links for f in self._flows()])
-        assert comps == [[0, 1], [2, 3]]
+        """The simulator's label propagation finds the two components of
+        the same four flows, reserved in its flow table."""
+        cluster = make_fabric_cluster(n_leaves=2, hosts_per_leaf=1)
+        sim = ClusterSimulator(cluster, [], SimConfig())
+        slots = np.concatenate([
+            sim._flows.reserve(0, d.tolist(), paths)
+            for d, paths, _ in self._problems(self.CAPS)])
+        comps = sim._components(slots)
+        assert [c.tolist() for c in comps] == [[0, 1], [2, 3]]
+        assert slots.tolist() == [0, 1, 2, 3]
 
     def test_memo_hits_and_selective_invalidation(self):
         eng = fluid.FluidEngine("python", incremental=True)
-        caps = {"hA": 12.0, "hB": 10.0, "uplink:1": 6.0}
-        flows = self._flows()
-        eng.assign(flows, caps.__getitem__)
+        caps = dict(self.CAPS)
+        first = eng.solve_batch(self._problems(caps))
         assert (eng.stats.misses, eng.stats.hits) == (2, 0)
-        first = [f.rate_gbps for f in flows]
 
-        eng.assign(flows, caps.__getitem__)  # unchanged: both memoized
+        again = eng.solve_batch(self._problems(caps))  # both memoized
         assert (eng.stats.misses, eng.stats.hits) == (2, 2)
-        assert [f.rate_gbps for f in flows] == first
+        assert [r.tolist() for r in again] == [r.tolist() for r in first]
 
-        caps["uplink:1"] = 3.0  # touches ONLY the {hB} component
-        eng.assign(flows, caps.__getitem__)
+        caps["uplink:leaf1"] = 3.0  # touches ONLY the second component
+        third = eng.solve_batch(self._problems(caps))
         assert (eng.stats.misses, eng.stats.hits) == (3, 3)
-        assert [f.rate_gbps for f in flows[:2]] == first[:2]
-        assert flows[2].rate_gbps < first[2]
+        assert third[0].tolist() == first[0].tolist()
+        assert third[1][0] < first[1][0]
 
     def test_incremental_matches_full_solve(self):
-        caps = {"hA": 12.0, "hB": 10.0, "uplink:1": 6.0}
-        inc, full = self._flows(), self._flows()
-        fluid.FluidEngine("python", incremental=True).assign(
-            inc, caps.__getitem__)
-        fluid.FluidEngine("python", incremental=False).assign(
-            full, caps.__getitem__)
-        # disjoint single-link components: per-component == global here
-        for a, b in zip(inc, full):
-            assert a.rate_gbps == pytest.approx(b.rate_gbps, abs=1e-9)
+        inc = fluid.FluidEngine("python", incremental=True).solve_batch(
+            self._problems(self.CAPS))
+        paths = [p for _, ps, _ in self._problems(self.CAPS) for p in ps]
+        (full,) = fluid.FluidEngine("python", incremental=False).solve_batch(
+            [(np.array([10.0, 6.0, 8.0, 5.0]), paths, dict(self.CAPS))])
+        # disjoint components: per-component == global here
+        np.testing.assert_allclose(np.concatenate(inc), full, rtol=0,
+                                   atol=1e-9)
 
     def test_backend_defaults(self):
         assert fluid.FluidEngine("python").incremental is False

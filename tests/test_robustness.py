@@ -7,8 +7,9 @@ Four subsystems under test:
     contract (pure function of (link, sample-slot), never query order),
     and the oracle-identity guarantee: a transparent channel is
     bit-for-bit the no-channel path;
-  * fault injection — link/host failure+recovery events in BOTH event
-    loops (bit-for-bit parity), including same-timestamp stacks,
+  * fault injection — link/host failure+recovery events, each run pinned
+    bit-for-bit to ``tests/goldens/sim_goldens.json`` (see
+    ``tests/goldens/record.py``), including same-timestamp stacks,
     zero-capacity links, and flapping trains;
   * graceful-degradation control — the controller's hysteresis gate and
     measured-vs-declared demand reconciliation;
@@ -19,7 +20,6 @@ Four subsystems under test:
 import dataclasses
 import math
 
-import numpy as np
 import pytest
 
 from repro.configs.metronome_testbed import (FAULT_SNAPSHOTS,
@@ -35,11 +35,10 @@ from repro.core.events import (BackgroundFlowChange, EventValidationError,
 from repro.core.experiment import Policy, Scenario, run
 from repro.core.framework import SchedulingFramework
 from repro.core.scheduler import MetronomePlugin
-from repro.core.simulator import (COMM, DONE, STALLED, ClusterSimulator,
-                                  SimConfig)
+from repro.core.simulator import COMM, STALLED, ClusterSimulator, SimConfig
 from repro.core.telemetry import TelemetryChannel, TelemetryView
 from repro.core.workload import Workload, make_job
-from test_event_loop import sim_equal
+from test_event_loop import pinned, sim_equal
 
 CFG = SimConfig(duration_ms=20_000.0, seed=3, jitter_std=0.01)
 
@@ -71,17 +70,80 @@ def _scheduled(jobs, controller=None):
     return cl, fw.registry
 
 
-def _both_loops(jobs_factory, cfg, **sim_kwargs):
-    out = []
-    for loop in ("array", "legacy"):
-        jobs = jobs_factory()
-        cl, registry = _scheduled(jobs)
-        sim = ClusterSimulator(
-            cl, jobs, dataclasses.replace(cfg, event_loop=loop),
-            registry=registry,
-            **{k: (v() if callable(v) else v) for k, v in sim_kwargs.items()})
-        out.append((sim, sim.run()))
-    return out
+def _one_run(jobs, cfg, **sim_kwargs):
+    cl, registry = _scheduled(jobs)
+    sim = ClusterSimulator(cl, jobs, cfg, registry=registry, **sim_kwargs)
+    return sim, sim.run()
+
+
+# ---------------------------------------------------------------------------
+# the runs pinned by value (tests/goldens/sim_goldens.json)
+# ---------------------------------------------------------------------------
+
+FAULT_CFG = SimConfig(duration_ms=10_000.0, seed=0, jitter_std=0.0)
+
+
+def _faulted(*evs, jobs=lambda: [_job()]):
+    """A run function: ``jobs()`` scheduled on the 2-node cluster and run
+    under ``FAULT_CFG`` with the events ``evs``."""
+    return lambda: _one_run(jobs(), FAULT_CFG, events=list(evs))
+
+
+def _one_job_per_node():
+    # each job demands a node's full GPU capacity, pinning one per node
+    return [_job(name, n_tasks=1, bw_gbps=5.0, n_iterations=500,
+                 resources=Resources(cpu=4, mem=16, gpu=4))
+            for name in ("a", "b")]
+
+
+def _noisy_channel():
+    chan = TelemetryChannel(sample_period_ms=500.0, noise_std=0.15,
+                            staleness_ms=250.0, dropout=0.1)
+    return run(dynamic_scenario("D1", n_iterations=30), Policy("metronome"),
+               dataclasses.replace(CFG, telemetry=chan))
+
+
+def _fault_snapshot(sid):
+    return lambda: run(
+        fault_scenario(sid, n_iterations=30, start_ms=3_000.0,
+                       period_ms=6_000.0, down_ms=1_000.0, n_cycles=2),
+        Policy("metronome"), CFG)
+
+
+def _silent_drift():
+    return _one_run(
+        [_job()], SimConfig(duration_ms=10_000.0, seed=0, jitter_std=0.0),
+        events=[TrafficChange(1_000.0, job="j", duty_mult=1.5,
+                              declared=False)])
+
+
+GOLDEN_RUNS = {
+    "telemetry.noisy_channel": _noisy_channel,
+    "fault.clean": _faulted(),
+    "link.failure_recovery": _faulted(LinkFailure(2_000.0, link="n0"),
+                                      LinkRecovery(4_000.0, link="n0")),
+    "link.degraded_recovery": _faulted(
+        LinkFailure(1_000.0, link="n0"),
+        LinkRecovery(2_000.0, link="n0", capacity_gbps=10.0)),
+    "link.zero_capacity": _faulted(LinkFailure(500.0, link="n0")),
+    "link.same_timestamp_stack": _faulted(LinkFailure(2_000.0, link="n0"),
+                                          LinkRecovery(2_000.0, link="n0")),
+    "link.double_failure": _faulted(
+        LinkFailure(1_000.0, link="n0"), LinkFailure(1_500.0, link="n0"),
+        LinkRecovery(2_000.0, link="n0"),
+        LinkRecovery(2_500.0, link="n0")),  # not failed: no-op
+    "link.unknown": _faulted(LinkFailure(100.0, link="ghost")),
+    "host.stall_recovery": _faulted(HostFailure(2_000.0, host="n0"),
+                                    HostRecovery(5_000.0, host="n0")),
+    "host.unrecovered": _faulted(HostFailure(2_000.0, host="n0"),
+                                 jobs=lambda: [_job(n_iterations=500)]),
+    "host.same_timestamp_flap": _faulted(HostFailure(2_000.0, host="n0"),
+                                         HostRecovery(2_000.0, host="n0")),
+    "host.other_host": _faulted(HostFailure(2_000.0, host="n1"),
+                                jobs=_one_job_per_node),
+    **{f"fault.{sid}": _fault_snapshot(sid) for sid in FAULT_SNAPSHOTS},
+    "reconcile.silent_drift": _silent_drift,
+}
 
 
 # ---------------------------------------------------------------------------
@@ -220,34 +282,27 @@ class TestTelemetryView:
 
 
 class TestOracleIdentity:
-    """A transparent channel must be BIT-FOR-BIT the no-channel path, and
-    a noisy channel must be loop-order independent (array == legacy)."""
+    """A transparent channel must be BIT-FOR-BIT the no-channel path,
+    under the float64 oracle and a vectorized fill alike, and a noisy
+    channel must see what the pinned run saw."""
 
-    @pytest.mark.parametrize("loop", ["array", "legacy"])
+    @pytest.mark.parametrize("backend", ["python", "jnp"])
     @pytest.mark.parametrize("channel", [
         TelemetryChannel(sample_period_ms=0.0),   # continuous, undistorted
         TelemetryChannel(sample_period_ms=1000.0),  # sampled, undistorted
     ])
-    def test_transparent_channel_is_oracle(self, loop, channel):
+    def test_transparent_channel_is_oracle(self, backend, channel):
         scen = dynamic_scenario("D1", n_iterations=30)
-        cfg = dataclasses.replace(CFG, event_loop=loop)
-        base = run(scen, Policy("metronome"), cfg)
-        tel = run(scen, Policy("metronome"),
-                  dataclasses.replace(cfg, telemetry=channel))
+        policy = Policy("metronome", sim_backend=backend)
+        base = run(scen, policy, CFG)
+        tel = run(scen, policy, dataclasses.replace(CFG, telemetry=channel))
         sim_equal(base.sim, tel.sim)
         assert base.placements == tel.placements
 
     def test_noisy_channel_loop_parity(self):
-        """The two loops interleave telemetry queries differently; the
-        per-(link, slot) RNG contract makes them see identical channels."""
-        scen = dynamic_scenario("D1", n_iterations=30)
-        chan = TelemetryChannel(sample_period_ms=500.0, noise_std=0.15,
-                                staleness_ms=250.0, dropout=0.1)
-        cfg = dataclasses.replace(CFG, telemetry=chan)
-        arr = run(scen, Policy("metronome"), cfg)
-        leg = run(scen, Policy("metronome"),
-                  dataclasses.replace(cfg, event_loop="legacy"))
-        sim_equal(arr.sim, leg.sim)
+        """Telemetry is a pure function of (link, sample slot), never of
+        query order, so the run sees the channel the pinned run saw."""
+        pinned(GOLDEN_RUNS, "telemetry.noisy_channel")
 
     def test_noisy_run_is_seed_deterministic(self):
         scen = dynamic_scenario("D1", n_iterations=30)
@@ -260,159 +315,90 @@ class TestOracleIdentity:
 
 
 # ---------------------------------------------------------------------------
-# fault injection: link/host failure + recovery, both loops bit-for-bit
+# fault injection: link/host failure + recovery, pinned bit-for-bit
 # ---------------------------------------------------------------------------
 
 class TestLinkFailure:
-    CFG = SimConfig(duration_ms=10_000.0, seed=0, jitter_std=0.0)
-
     def test_failure_zeroes_recovery_restores(self):
-        evs = [LinkFailure(2_000.0, link="n0"),
-               LinkRecovery(4_000.0, link="n0")]
-        (sa, ra), (sl, rl) = _both_loops(
-            lambda: [_job()], self.CFG, events=lambda: list(evs))
-        sim_equal(ra, rl)
-        for sim, res in ((sa, ra), (sl, rl)):
-            n0 = sim.cluster.node("n0")
-            assert n0.bw_gbps == 25.0 and n0.allocatable_gbps is None
-            assert res.iterations_done["j"] > 0
-            # ~2s of the 10s window was dead: the job finishes later
-        clean = _both_loops(lambda: [_job()], self.CFG)[0][1]
-        assert ra.finish_times_ms["j"] > clean.finish_times_ms["j"] + 1_000.0
+        sim, res = pinned(GOLDEN_RUNS, "link.failure_recovery")
+        n0 = sim.cluster.node("n0")
+        assert n0.bw_gbps == 25.0 and n0.allocatable_gbps is None
+        assert res.iterations_done["j"] > 0
+        # ~2s of the 10s window was dead: the job finishes later
+        _, clean = pinned(GOLDEN_RUNS, "fault.clean")
+        assert res.finish_times_ms["j"] > clean.finish_times_ms["j"] + 1_000.0
 
     def test_degraded_recovery(self):
-        evs = [LinkFailure(1_000.0, link="n0"),
-               LinkRecovery(2_000.0, link="n0", capacity_gbps=10.0)]
-        (sa, ra), (sl, rl) = _both_loops(
-            lambda: [_job()], self.CFG, events=lambda: list(evs))
-        sim_equal(ra, rl)
-        for sim in (sa, sl):
-            assert sim.cluster.node("n0").bw_gbps == 10.0
+        sim, _ = pinned(GOLDEN_RUNS, "link.degraded_recovery")
+        assert sim.cluster.node("n0").bw_gbps == 10.0
 
     def test_zero_capacity_link_stalls_flows(self):
         """While a traversed link is failed, comm flows have rate 0: the
         job sits mid-comm with no finish event until recovery."""
-        evs = [LinkFailure(500.0, link="n0")]
-        (sa, ra), (sl, rl) = _both_loops(
-            lambda: [_job()], self.CFG, events=lambda: list(evs))
-        sim_equal(ra, rl)
-        for sim, res in ((sa, ra), (sl, rl)):
-            st = sim.jobs["j"]
-            assert st.phase == COMM  # stuck mid-comm at the duration cap
-            assert math.isnan(res.finish_times_ms["j"])
+        sim, res = pinned(GOLDEN_RUNS, "link.zero_capacity")
+        assert sim.jobs["j"].phase == COMM  # stuck mid-comm at the cap
+        assert math.isnan(res.finish_times_ms["j"])
 
     def test_same_timestamp_failure_recovery_stack(self):
         """A failure and its recovery at ONE timestamp cancel exactly:
-        the run is bit-for-bit an event-free run, in both loops."""
-        evs = [LinkFailure(2_000.0, link="n0"),
-               LinkRecovery(2_000.0, link="n0")]
-        (sa, ra), (sl, rl) = _both_loops(
-            lambda: [_job()], self.CFG, events=lambda: list(evs))
-        sim_equal(ra, rl)
-        (ca, rca), (clg, rcl) = _both_loops(lambda: [_job()], self.CFG)
-        sim_equal(ra, rca)
+        the run is bit-for-bit an event-free run."""
+        _, res = pinned(GOLDEN_RUNS, "link.same_timestamp_stack")
+        _, clean = pinned(GOLDEN_RUNS, "fault.clean")
+        sim_equal(res, clean)
 
     def test_double_failure_single_recovery(self):
         """Failing a failed link is a no-op; the first recovery restores
         the ORIGINAL pre-failure capacity (flap-overlap semantics)."""
-        evs = [LinkFailure(1_000.0, link="n0"),
-               LinkFailure(1_500.0, link="n0"),
-               LinkRecovery(2_000.0, link="n0"),
-               LinkRecovery(2_500.0, link="n0")]  # not failed: no-op
-        (sa, ra), (sl, rl) = _both_loops(
-            lambda: [_job()], self.CFG, events=lambda: list(evs))
-        sim_equal(ra, rl)
-        for sim in (sa, sl):
-            n0 = sim.cluster.node("n0")
-            assert n0.bw_gbps == 25.0 and n0.allocatable_gbps is None
+        sim, _ = pinned(GOLDEN_RUNS, "link.double_failure")
+        n0 = sim.cluster.node("n0")
+        assert n0.bw_gbps == 25.0 and n0.allocatable_gbps is None
 
     def test_unknown_link_warns(self):
-        evs = [LinkFailure(100.0, link="ghost")]
         with pytest.warns(UnknownEventTargetWarning):
-            _both_loops(lambda: [_job()], self.CFG,
-                        events=lambda: list(evs))
+            pinned(GOLDEN_RUNS, "link.unknown")
 
 
 class TestHostFailure:
-    CFG = SimConfig(duration_ms=10_000.0, seed=0, jitter_std=0.0)
-
     def test_stall_and_recovery(self):
-        evs = [HostFailure(2_000.0, host="n0"),
-               HostRecovery(5_000.0, host="n0")]
-        (sa, ra), (sl, rl) = _both_loops(
-            lambda: [_job()], self.CFG, events=lambda: list(evs))
-        sim_equal(ra, rl)
-        clean = _both_loops(lambda: [_job()], self.CFG)[0][1]
-        for sim, res in ((sa, ra), (sl, rl)):
-            st = sim.jobs["j"]
-            assert st.phase != STALLED  # recovered
-            assert not st.stall_hosts
-            assert res.iterations_done["j"] > 0
-            # the ~3s stall pushes the finish well past the clean run's
-            assert (res.finish_times_ms["j"]
-                    > clean.finish_times_ms["j"] + 2_000.0)
+        sim, res = pinned(GOLDEN_RUNS, "host.stall_recovery")
+        _, clean = pinned(GOLDEN_RUNS, "fault.clean")
+        st = sim.jobs["j"]
+        assert st.phase != STALLED  # recovered
+        assert not st.stall_hosts
+        assert res.iterations_done["j"] > 0
+        # the ~3s stall pushes the finish well past the clean run's
+        assert res.finish_times_ms["j"] > clean.finish_times_ms["j"] + 2_000.0
 
     def test_unrecovered_host_stalls_to_cap(self):
-        evs = [HostFailure(2_000.0, host="n0")]
-        (sa, ra), (sl, rl) = _both_loops(
-            lambda: [_job(n_iterations=500)], self.CFG,
-            events=lambda: list(evs))
-        sim_equal(ra, rl)
-        for sim, res in ((sa, ra), (sl, rl)):
-            st = sim.jobs["j"]
-            assert st.phase == STALLED
-            assert math.isnan(res.finish_times_ms["j"])
-            # iterations froze at the failure: ~2s worth of 100ms periods
-            assert res.iterations_done["j"] <= 21
+        sim, res = pinned(GOLDEN_RUNS, "host.unrecovered")
+        assert sim.jobs["j"].phase == STALLED
+        assert math.isnan(res.finish_times_ms["j"])
+        # iterations froze at the failure: ~2s worth of 100ms periods
+        assert res.iterations_done["j"] <= 21
 
     def test_same_timestamp_host_flap_costs_one_iteration(self):
         """Failure and recovery at ONE timestamp: the host is back
         instantly, but the in-flight iteration was abandoned by the
         failure and restarts from its top — host flaps are destructive
         by design (unlike link flaps, which only gate rates)."""
-        evs = [HostFailure(2_000.0, host="n0"),
-               HostRecovery(2_000.0, host="n0")]
-        (sa, ra), (sl, rl) = _both_loops(
-            lambda: [_job()], self.CFG, events=lambda: list(evs))
-        sim_equal(ra, rl)
-        clean = _both_loops(lambda: [_job()], self.CFG)[0][1]
-        assert ra.iterations_done["j"] == clean.iterations_done["j"]
-        assert ra.finish_times_ms["j"] == pytest.approx(
+        sim, res = pinned(GOLDEN_RUNS, "host.same_timestamp_flap")
+        _, clean = pinned(GOLDEN_RUNS, "fault.clean")
+        assert res.iterations_done["j"] == clean.iterations_done["j"]
+        assert res.finish_times_ms["j"] == pytest.approx(
             clean.finish_times_ms["j"] + 100.0)  # one redone period
-        for sim in (sa, sl):
-            assert sim.jobs["j"].phase != STALLED
-            assert not sim._failed_hosts and not sim._failed_links
+        assert sim.jobs["j"].phase != STALLED
+        assert not sim._failed_hosts and not sim._failed_links
 
     def test_job_on_other_host_unaffected(self):
-        """A job with no task on the failed host keeps running.  Each job
-        demands the node's full GPU capacity, pinning one per node."""
-        from repro.core.cluster import Resources as Res
-
-        def jobs():
-            return [_job("a", n_tasks=1, bw_gbps=5.0, n_iterations=500,
-                         resources=Res(cpu=4, mem=16, gpu=4)),
-                    _job("b", n_tasks=1, bw_gbps=5.0, n_iterations=500,
-                         resources=Res(cpu=4, mem=16, gpu=4))]
-
-        evs = [HostFailure(2_000.0, host="n1")]
-        (sa, ra), (sl, rl) = _both_loops(
-            jobs, self.CFG, events=lambda: list(evs))
-        sim_equal(ra, rl)
-        for sim in (sa, sl):
-            stalled = [n for n, st in sim.jobs.items()
-                       if st.phase == STALLED]
-            running = [n for n, st in sim.jobs.items()
-                       if st.phase != STALLED]
-            assert len(stalled) == 1 and len(running) == 1
+        """A job with no task on the failed host keeps running."""
+        sim, _ = pinned(GOLDEN_RUNS, "host.other_host")
+        stalled = [n for n, st in sim.jobs.items() if st.phase == STALLED]
+        running = [n for n, st in sim.jobs.items() if st.phase != STALLED]
+        assert len(stalled) == 1 and len(running) == 1
 
     @pytest.mark.parametrize("sid", FAULT_SNAPSHOTS)
     def test_fault_snapshots_loop_parity(self, sid):
-        scen = fault_scenario(sid, n_iterations=30, start_ms=3_000.0,
-                              period_ms=6_000.0, down_ms=1_000.0, n_cycles=2)
-        arr = run(scen, Policy("metronome"), CFG)
-        leg = run(scen, Policy("metronome"),
-                  dataclasses.replace(CFG, event_loop="legacy"))
-        sim_equal(arr.sim, leg.sim)
+        pinned(GOLDEN_RUNS, f"fault.{sid}")
 
 
 class TestFlappingSchedule:
@@ -639,10 +625,4 @@ class TestReconciliation:
         assert rec_sim.jobs["j"].drift_mult == pytest.approx(1.0, rel=0.1)
 
     def test_silent_drift_loop_parity(self):
-        evs = [TrafficChange(1_000.0, job="j", duty_mult=1.5,
-                             declared=False)]
-        (sa, ra), (sl, rl) = _both_loops(
-            lambda: [_job()], SimConfig(duration_ms=10_000.0, seed=0,
-                                        jitter_std=0.0),
-            events=lambda: list(evs))
-        sim_equal(ra, rl)
+        pinned(GOLDEN_RUNS, "reconcile.silent_drift")
