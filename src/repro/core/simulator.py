@@ -46,6 +46,15 @@ from .workload import HIGH, Job
 
 EPS = 1e-9
 
+# active sets of at most this many flows take next-event and advance as one
+# scalar pass over lists cached in the membership cache's entry; above it
+# the per-flow loop costs more than numpy's vector calls (the crossover
+# that benchmarks/loop_crossover.py measures: DESIGN.md section 17)
+_SCALAR_MAX_FLOWS = 32
+# the active flows' rates and remaining volumes, gathered as lists by the
+# scalar pass's next-event for its advance (None on numpy's path)
+_Gathered = Optional[Tuple[List[float], List[float]]]
+
 COMPUTE, COMM, PAUSED, WAITING, DONE = "compute", "comm", "paused", "waiting", "done"
 # a job with a task on a failed host: inert until every failed host returns
 STALLED = "stalled"
@@ -121,7 +130,9 @@ class SimProfile:
     retries after a job completes).  ``active_hits`` and ``active_misses``
     split the ticks on which the set of active flow slots changed between
     the membership cache's answers and rebuilds of the ordered active set
-    (``_active_slots``)."""
+    (``_active_slots``).  ``flow_ticks`` counts the ticks with active
+    flows and ``scalar_ticks`` those of them whose next-event and advance
+    took the scalar pass (``_SCALAR_MAX_FLOWS``)."""
 
     ticks: int = 0
     assign_s: float = 0.0
@@ -142,6 +153,8 @@ class SimProfile:
     rate_memo_misses: int = 0
     active_hits: int = 0
     active_misses: int = 0
+    flow_ticks: int = 0
+    scalar_ticks: int = 0
 
     def as_dict(self) -> Dict[str, object]:
         return dataclasses.asdict(self)
@@ -444,9 +457,10 @@ class ClusterSimulator:
         self._last_fill_mode: Optional[str] = None
         # membership key: bit s is set while slot s is alive with volume
         # left.  The (job, pos)-ordered active set, its flattened path
-        # incidence, fill mode and content-id bytes are cached per key
-        # (cleared whenever a slot's content is written); _act_bits is the
-        # key of the set in force, -1 once its content may be stale
+        # incidence, fill mode, content-id bytes and the scalar pass's
+        # lists are cached per key (cleared whenever a slot's content is
+        # written); _act_bits is the key of the set in force, -1 once its
+        # content may be stale
         self._active_bits = 0
         self._act_bits = 0
         self._active_cache: Dict[int, tuple] = {}
@@ -455,6 +469,9 @@ class ClusterSimulator:
         self._flat_rows = np.empty(0, dtype=np.int64)
         self._act_single = True
         self._act_cids = b""
+        self._act_list: Optional[List[int]] = []
+        self._act_jobs: Optional[List[int]] = []
+        self._act_pairs: Optional[List[Tuple[int, int]]] = []
         self._warned: Set[Tuple[str, str]] = set()
         # (arrival_ms, workload) queue for online scheduling
         self._arrivals = collections.deque(sorted(
@@ -692,7 +709,9 @@ class ClusterSimulator:
         exactly.  Looked up by the membership key when it changed, and
         rebuilt only when the key is not cached; alongside it the
         flattened (slot row, path link) incidence used by the delivered-GB
-        scatter-add, the fill mode and the content-id bytes."""
+        scatter-add, the fill mode, the content-id bytes, and for the
+        scalar pass the slots, their jobs and the (link, row) pairs of
+        that incidence as lists."""
         bits = self._active_bits
         if bits != self._act_bits:
             cache = self._active_cache
@@ -708,12 +727,15 @@ class ClusterSimulator:
             elif prof is not None:
                 prof.active_hits += 1
             (self._act, self._flat_links, self._flat_rows, self._act_single,
-             self._act_cids) = entry
+             self._act_cids, self._act_list, self._act_jobs,
+             self._act_pairs) = entry
             self._act_bits = bits
         return self._act
 
     def _build_active(self) -> tuple:
-        """The cache entry of the active set, rebuilt from the table."""
+        """The cache entry of the active set, rebuilt from the table.
+        ``reserve`` is the only writer of a slot's job, links and path, and
+        clears the cache, so no list here outlives what it was built from."""
         tbl = self._flows
         alive = np.nonzero(tbl.alive)[0]
         act = alive[tbl.remaining[alive] > EPS]
@@ -727,7 +749,84 @@ class ClusterSimulator:
         else:
             rows = flat_links = np.empty(0, dtype=np.int64)
             single = True
-        return act, flat_links, rows, single, tbl.cid[act].tobytes()
+        lists = (None, None, None)
+        if act.size <= _SCALAR_MAX_FLOWS:  # only the scalar pass reads them
+            lists = (act.tolist(), tbl.job[act].tolist(),
+                     list(zip(flat_links.tolist(), rows.tolist())))
+        return (act, flat_links, rows, single, tbl.cid[act].tobytes(), *lists)
+
+    def _next_flow_finish(self, act: np.ndarray,
+                          nxt: float) -> Tuple[float, _Gathered]:
+        """The earlier of ``nxt`` and the first finish among the active
+        flows ``act``, and what the advance reuses: their rates and
+        remaining volumes as lists where the set is small enough for the
+        scalar pass (``_SCALAR_MAX_FLOWS``), else None.  Both paths run
+        the seed's float ops in its order, so event times are
+        bit-identical."""
+        tbl = self._flows
+        if act.size <= _SCALAR_MAX_FLOWS:
+            rate = tbl.rate[act].tolist()
+            rem = tbl.remaining[act].tolist()
+            now = self.now
+            for r, x in zip(rate, rem):
+                if r > EPS:
+                    t = now + x / r * 1e3
+                    if t < nxt:
+                        nxt = t
+            return nxt, (rate, rem)
+        r = tbl.rate[act]
+        mask = r > EPS
+        if mask.any():
+            m = (self.now + tbl.remaining[act[mask]] / r[mask] * 1e3).min()
+            if m < nxt:
+                nxt = float(m)
+        return nxt, None
+
+    def _advance_flows(self, act: np.ndarray, dt: float,
+                       gathered: _Gathered) -> None:
+        """Move the active flows ``dt`` ms on: volumes, delivered GB, and
+        for each flow that finishes its links' dirty marks, its membership
+        bit and its job's unfinished count.  The delivered-GB scatter
+        replays the seed's (job, flow, path-link) accumulation order, one
+        add at a time.  ``gathered`` is what ``_next_flow_finish``
+        returned: the lists of the scalar pass, or None for numpy's."""
+        tbl = self._flows
+        dv = self._delivered_vec
+        if gathered is None:
+            rem = tbl.remaining[act]
+            moved = np.minimum(rem, tbl.rate[act] * dt / 1e3)
+            new_rem = rem - moved
+            tbl.remaining[act] = new_rem
+            np.add.at(dv, self._flat_links, moved[self._flat_rows])
+            fin = new_rem <= EPS
+            if fin.any():
+                done_slots = act[fin]
+                gone = 0
+                for s in done_slots.tolist():
+                    self._dirty_links.update(tbl.paths[s])
+                    gone |= 1 << s
+                self._active_bits &= ~gone
+                np.subtract.at(self._junfin, tbl.job[done_slots], 1)
+            return
+        moved = []
+        new_rem = []
+        for r, x in zip(*gathered):
+            m = r * dt / 1e3
+            if x < m:
+                m = x
+            moved.append(m)
+            new_rem.append(x - m)
+        tbl.remaining[act] = new_rem
+        for l, row in self._act_pairs:
+            dv[l] = dv.item(l) + moved[row]
+        gone = 0
+        for s, j, x in zip(self._act_list, self._act_jobs, new_rem):
+            if x <= EPS:
+                self._dirty_links.update(tbl.paths[s])
+                gone |= 1 << s
+                self._junfin[j] -= 1
+        if gone:
+            self._active_bits &= ~gone
 
     # ------------------------------------------------------------- main loop
     def run(self) -> SimResult:
@@ -792,7 +891,6 @@ class ClusterSimulator:
         perf = time.perf_counter
         ann = self._annotate
         span = None  # the open phase's span, while ann records
-        tbl = self._flows
         dv = self._delivered_vec
         link_index = self._link_index
         while self.now < duration:
@@ -814,13 +912,9 @@ class ClusterSimulator:
                 if m < nxt:
                     nxt = float(m)
             act = self._active_slots()
+            gathered = None
             if act.size:
-                r = tbl.rate[act]
-                mask = r > EPS
-                if mask.any():
-                    m = (self.now + tbl.remaining[act[mask]] / r[mask] * 1e3).min()
-                    if m < nxt:
-                        nxt = float(m)
+                nxt, gathered = self._next_flow_finish(act, nxt)
             if self._events:
                 nxt = min(nxt, self._events[0].time_ms)
             if self._arrivals:
@@ -833,24 +927,10 @@ class ClusterSimulator:
                 if ann is not None:
                     span = _next_span(span, ann, "sim.advance")
 
-            # advance flows; delivered-GB scatter replays the seed's
-            # (job, flow, path-link) accumulation order, then background
+            # advance flows, then background
             if dt > 0:
                 if act.size:
-                    rem = tbl.remaining[act]
-                    moved = np.minimum(rem, tbl.rate[act] * dt / 1e3)
-                    new_rem = rem - moved
-                    tbl.remaining[act] = new_rem
-                    np.add.at(dv, self._flat_links, moved[self._flat_rows])
-                    fin = new_rem <= EPS
-                    if fin.any():
-                        done_slots = act[fin]
-                        gone = 0
-                        for s in done_slots.tolist():
-                            self._dirty_links.update(tbl.paths[s])
-                            gone |= 1 << s
-                        self._active_bits &= ~gone
-                        np.subtract.at(self._junfin, tbl.job[done_slots], 1)
+                    self._advance_flows(act, dt, gathered)
                 for bg in self.background:
                     dv[link_index[bg.link_id]] += bg.rate_gbps * dt / 1e3
             self.now = nxt
@@ -860,6 +940,10 @@ class ClusterSimulator:
                 t3 = perf()
                 prof.advance_s += t3 - t2
                 prof.ticks += 1
+                if act.size:
+                    prof.flow_ticks += 1
+                    if gathered is not None:
+                        prof.scalar_ticks += 1
             if self.now >= duration:
                 break
             if ann is not None:
