@@ -27,16 +27,12 @@ one view:
     single-node jobs synchronize over localhost and place no link traffic,
     and demand aggregates per source host link.
 
-The controller's offline recalculation keeps its legacy whole-job host-link
-demand (:meth:`recalc_traffic` / :meth:`recalc_demands`).  Since the
-fabric-wide rotation planner became the single producer of schemes
-(``core/rotation.py``), this divergence is an explicit, named *demand
-convention* of the planner (``demand='planning'`` vs ``demand='recalc'``)
-rather than two code paths: the Score phase plans with the planning view,
-the offline 3rd stage re-solves with the recalc view, and both read the
-same grouped tasks from this one class (DESIGN.md section 13).  Folding the
-host rule into the planning view would re-scale Eq. 18's excess on every
-star recalculation and is pinned out by the seed goldens.
+The controller's legacy (``joint=False``) offline recalculation keeps a
+whole-job host-link demand (:meth:`recalc_traffic` / :meth:`recalc_demands`):
+an explicit, named *demand convention* of the planner (``demand='planning'``
+vs ``demand='recalc'``) rather than a second code path (DESIGN.md section
+13).  The joint controller's circles plan with the planning view in both
+stages (DESIGN.md section 20).
 """
 from __future__ import annotations
 

@@ -1,18 +1,18 @@
 """The Metronome stop-and-wait controller — paper section III-C.
 
 Three duties:
-  1. **Global offset**: per-link rotation schemes arrive from the scheduler;
-     jobs spanning several links need consistent time-shifts. Offset
-     resolution is delegated to the fabric-wide rotation planner
-     (:func:`repro.core.rotation.resolve`): consistent per-link solutions
-     keep the Cassini-style affinity-graph BFS anchored at the *highest
-     priority* job (the paper's difference vs Cassini's random reference);
-     conflicting per-link solutions are re-solved jointly over every link
-     the component touches.  ``joint=False`` restores the legacy
-     "uplinks take precedence" tie-break as an ablation.
+  1. **Global offset**: jobs spanning several links need consistent
+     time-shifts.  The controller re-derives its circles from the live
+     placement after every change (``rotation.solve_circles``): contended
+     links sharing a job share one circle, unified over all their jobs and
+     solved jointly at its Eq. 18 optimum, the reference (Eq. 16: the
+     *highest priority* job, the paper's difference vs Cassini's random
+     reference) pinned (DESIGN.md section 20).  ``joint=False`` keeps the
+     Reserve message's per-link schemes and the legacy "uplinks take
+     precedence" tie-break as an ablation.
   2. **Offline recalculation**: when SkipPhaseThree == 0, re-run the
      exhaustive 3rd-stage search (maximize Psi among perfect-score interval
-     midpoints) and update the scheme.
+     midpoints) over the link's circle and update the answers.
   3. **Continuous regulation**: monitor per-job iteration times; within a
      window of 10 iterations, if a job exceeds ``A_T`` x baseline more than
      ``O_T`` times, pause LOW priority jobs to realign their communication
@@ -22,13 +22,14 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from . import geometry, rotation
 from .cluster import Cluster
 from .contention import LinkView
+from .fluid import interval
 from .framework import TaskRegistry
 from .geometry import DI_PRE
 from .rotation import LinkScheme
@@ -104,12 +105,29 @@ class StopAndWaitController:
         self.reconcile_count = 0
         self._measured_comm: Dict[str, collections.deque] = {}
         self.joint = joint
-        self.joint_resolve_count = 0  # components re-solved jointly
+        # components re-solved jointly: under the legacy resolution those
+        # whose per-link solutions conflict, under the circles those whose
+        # links do not all carry the same jobs
+        self.joint_resolve_count = 0
         # epoch-scoped memo for the joint re-solves of offset resolution
         # (on_schedule replans after EVERY reserve; within one epoch the
         # conflicted components repeat — see DESIGN.md section 15)
         self.plan_cache = rotation.PlanCache()
         self.links: Dict[str, LinkState] = {}  # link id -> state (see LinkState)
+        # the circles in force under the joint planner, by content key,
+        # each with whether the third stage solved it (rotation.
+        # solve_circles); links, offsets and injected idle are published
+        # from them.  A placement change marks them stale with the live
+        # (registry, cluster) and the first read re-derives them, so the
+        # pods of one admission, or of one rollback, cost one solve; a
+        # pod being evicted (still in the registry) is left out
+        self._circles: Dict[Tuple, Tuple[rotation.JointResult, bool]] = {}
+        self._stale: Optional[Tuple[TaskRegistry, Cluster]] = None
+        self._evicting: set = set()
+        # SimProfile and span factory, handed in by the simulator while it
+        # admits (the planner's time books into ``plan_s``)
+        self.profile = None
+        self.annotate = None
         self.global_offsets_ms: Dict[str, float] = {}
         self.injected_ms: Dict[str, float] = {}  # per-job E_T idle injection
         self._history: Dict[str, collections.deque] = {}
@@ -126,9 +144,19 @@ class StopAndWaitController:
     # ------------------------------------------------------------- scheduling
     def on_schedule(self, cluster: Cluster, registry: TaskRegistry,
                     msg: ReserveMessage) -> None:
-        """Receive SEND(Shifts, SkipPhaseThree, P_l(n*)) from the scheduler."""
+        """Receive SEND(Shifts, SkipPhaseThree, P_l(n*)) from the scheduler.
+
+        Under the joint planner the circles go stale, to be re-derived
+        from the live placement on the next read (:meth:`_rebuild_circles`),
+        and the message supplies which links want the offline third stage;
+        ``joint=False`` adopts the message's per-link schemes (the legacy
+        ablation)."""
         for link_id, scheme in msg.schemes.items():
             skip = msg.skips.get(link_id, msg.skip_phase_three)
+            if self.joint:
+                if not skip and link_id not in self.pending_recalc:
+                    self.pending_recalc.append(link_id)
+                continue
             self.links[link_id] = LinkState(scheme=scheme, optimal=skip)
             for j, inj in scheme.injected_ms.items():
                 if inj > 0:
@@ -137,7 +165,12 @@ class StopAndWaitController:
                 self.pending_recalc.append(link_id)
         for jname, job in registry.jobs.items():
             self._priorities[jname] = job.priority
-        self._replan_offsets(registry, cluster)
+        if self.joint:
+            # every eviction has completed: its pods are out of the registry
+            self._evicting.clear()
+            self._stale = (registry, cluster)
+        else:
+            self._replan_offsets(registry, cluster)
         # offline recalculation is delegated (the paper decouples it from the
         # scheduling fast path); callers may run run_offline_recalculation()
         # asynchronously or via the hook.
@@ -159,9 +192,16 @@ class StopAndWaitController:
     def on_evict(self, node: str, pod: Task,
                  registry: Optional[TaskRegistry] = None,
                  cluster: Optional[Cluster] = None) -> None:
-        """Pod eviction: retire the job from the node's host-link scheme and
+        """Pod eviction (the pod is still in the registry): the circles go
+        stale, to be re-derived without it, so a circle and its injected
+        idle end with the contention that made them.  ``joint=False`` (or no live
+        registry) retires the job from the node's host-link scheme and
         from every uplink scheme it appears in (evictions are all-or-nothing
         at the job level, so the job's cross-leaf flows disappear too)."""
+        if self.joint and registry is not None and cluster is not None:
+            self._evicting.add(pod.uid)
+            self._stale = (registry, cluster)
+            return
         dead: List[str] = []
         state = self.links.get(node)
         if state is not None and self._drop_job(state, pod.job):
@@ -175,12 +215,87 @@ class StopAndWaitController:
         self._replan_offsets(registry, cluster)
 
     # ---------------------------------------------------------- global offset
+    # the answers in force: re-derived on the first read after a change
+    @property
+    def links(self) -> Dict[str, LinkState]:
+        self._refresh()
+        return self._links
+
+    @links.setter
+    def links(self, value: Dict[str, LinkState]) -> None:
+        self._links = value
+
+    @property
+    def global_offsets_ms(self) -> Dict[str, float]:
+        self._refresh()
+        return self._offsets
+
+    @global_offsets_ms.setter
+    def global_offsets_ms(self, value: Dict[str, float]) -> None:
+        self._offsets = value
+
+    @property
+    def injected_ms(self) -> Dict[str, float]:
+        """Per-job idle injected by the E_T rule."""
+        self._refresh()
+        return self._injected
+
+    @injected_ms.setter
+    def injected_ms(self, value: Dict[str, float]) -> None:
+        self._injected = value
+
+    def _refresh(self) -> None:
+        if self._stale is not None:
+            registry, cluster = self._stale
+            self._rebuild_circles(registry, cluster)
+
+    def _rebuild_circles(self, registry: TaskRegistry, cluster: Cluster, *,
+                         optimal: Sequence[str] = ()) -> None:
+        """Re-derive the circles from the live placement (pods being
+        evicted left out) and publish them: every contended link's
+        scheme, each aligned job's global offset, and the idle injected
+        into each job a circle stretches.  A job on no circle, or
+        incompatible with its circle, has no alignment and no idle."""
+        self._stale = None
+        tasks = [t for t in registry.tasks.values()
+                 if t.uid not in self._evicting]
+        self._evicting.clear()
+        view = LinkView(cluster, tasks, epoch=rotation.CONTENT_EPOCH)
+        with interval(self.profile, "plan_s", self.annotate,
+                      "sim.admit.plan"):
+            circles = rotation.solve_circles(
+                view, registry, self._circles, optimal=optimal,
+                di_pre=self.di_pre, cache=self.plan_cache)
+        for key, (res, _) in circles.items():
+            # a circle whose links do not all carry the same jobs has no
+            # per-link answer: it is one joint solve over its links
+            if (self._circles.get(key) is None
+                    and len({tuple(sch.jobs)
+                             for sch in res.schemes.values()}) > 1):
+                self.joint_resolve_count += 1
+        self._circles = circles
+        links: Dict[str, LinkState] = {}
+        offsets: Dict[str, float] = {}
+        injected: Dict[str, float] = {}
+        for res, third in self._circles.values():
+            offsets.update(res.offsets_ms)
+            for lid, sch in res.schemes.items():
+                if sch.jobs:
+                    links[lid] = LinkState(scheme=sch, optimal=third)
+                for j, inj in sch.injected_ms.items():
+                    if inj > 0:
+                        injected[j] = inj
+        self.links = links
+        self.global_offsets_ms = offsets
+        self.injected_ms = injected
+
     def _replan_offsets(self, registry: Optional[TaskRegistry] = None,
                         cluster: Optional[Cluster] = None, *,
                         mode: str = "fast", demand: str = "planning") -> None:
         """Resolve the stored per-link schemes into global offsets via the
-        rotation planner.  With a live (registry, cluster) the planner can
-        re-solve conflicting components jointly; without one — or with
+        rotation planner (the ``joint=False`` path, and schemes set by
+        hand).  With a live (registry, cluster) the planner can re-solve
+        conflicting components jointly; without one — or with
         ``joint=False`` — the legacy last-link-wins reconciliation applies
         (canonical order: host links sorted, uplinks LAST)."""
         schemes = {lid: st.scheme for lid, st in self.links.items()}
@@ -247,7 +362,20 @@ class StopAndWaitController:
     def run_offline_recalculation(
         self, registry: TaskRegistry, cluster: Cluster
     ) -> int:
-        """Process pending SkipPhaseThree==0 links: exhaustive 3rd stage."""
+        """Process pending SkipPhaseThree==0 links: exhaustive 3rd stage.
+
+        Under the joint planner each pending link's circle is solved again
+        in the third stage over the demand Score plans with (the pods'
+        traffic on each link), so its answer stays at the circle's Eq. 18
+        optimum; ``joint=False`` re-solves each stored link scheme over the
+        legacy whole-job demand."""
+        if self.joint:
+            pending = list(dict.fromkeys(self.pending_recalc))
+            self.pending_recalc.clear()
+            self._rebuild_circles(registry, cluster, optimal=pending)
+            done = sum(1 for l in pending if l in self._links)
+            self.recalc_count += done
+            return done
         done = 0
         view = LinkView.from_registry(cluster, registry)
         while self.pending_recalc:
@@ -481,21 +609,25 @@ class StopAndWaitController:
             t.traffic = dataclasses.replace(new_spec)
         registry.bump()  # stored tasks mutated in place -> new epoch
         for node, state in self.links.items():
-            if job in state.scheme.jobs:
-                # re-unify periods for this link and recalc
-                jobs = state.scheme.jobs
-                periods, prios = [], []
-                for j in jobs:
-                    tasks = view.job_tasks(j)
-                    periods.append(tasks[0].traffic.period_ms if tasks else 100.0)
-                    prios.append(self._priorities.get(j, 0))
-                unified = geometry.unify_periods(periods, prios)
-                state.scheme.base_ms = unified.base_ms
-                state.scheme.muls = unified.muls
-                state.scheme.injected_ms = {
-                    j: float(unified.injected_ms[i]) for i, j in enumerate(jobs)
-                }
-                self.pending_recalc.append(node)
+            if job not in state.scheme.jobs:
+                continue
+            self.pending_recalc.append(node)
+            if self.joint:
+                continue  # the recalculation re-derives the circles
+            # re-unify periods for this link and recalc
+            jobs = state.scheme.jobs
+            periods, prios = [], []
+            for j in jobs:
+                tasks = view.job_tasks(j)
+                periods.append(tasks[0].traffic.period_ms if tasks else 100.0)
+                prios.append(self._priorities.get(j, 0))
+            unified = geometry.unify_periods(periods, prios,
+                                             n_slots=self.di_pre)
+            state.scheme.base_ms = unified.base_ms
+            state.scheme.muls = unified.muls
+            state.scheme.injected_ms = {
+                j: float(unified.injected_ms[i]) for i, j in enumerate(jobs)
+            }
         self.run_offline_recalculation(registry, cluster)
         if job in self._history:
             self._history[job].clear()

@@ -71,14 +71,17 @@ _UNTIMED = contextlib.nullcontext()
 
 
 class _Interval:
-    __slots__ = ("stats", "field", "span", "t0")
+    __slots__ = ("stats", "field", "span", "less", "t0", "inner0")
 
-    def __init__(self, stats, field: str, span) -> None:
+    def __init__(self, stats, field: str, span, less) -> None:
         self.stats, self.field, self.span = stats, field, span
+        self.less = less
 
     # the span's own cost falls inside the interval, so that a parent's
     # time outside its timed parts holds no tracing cost
     def __enter__(self) -> None:
+        if self.less is not None:
+            self.inner0 = getattr(self.stats, self.less)
         self.t0 = time.perf_counter()
         if self.span is not None:
             self.span.__enter__()
@@ -87,18 +90,23 @@ class _Interval:
         if self.span is not None:
             self.span.__exit__(*exc)
         dt = time.perf_counter() - self.t0
+        if self.less is not None:
+            dt -= getattr(self.stats, self.less) - self.inner0
         setattr(self.stats, self.field, getattr(self.stats, self.field) + dt)
 
 
-def interval(stats, field: str, annotate, name: str):
+def interval(stats, field: str, annotate, name: str,
+             less: Optional[str] = None):
     """Context manager that adds the seconds of its body to
-    ``stats.<field>`` and, where ``annotate`` (see :func:`trace_annotation`)
-    is given, spans the body as ``name`` on the profiler's clock.  With
-    ``stats=None`` it does nothing and allocates nothing."""
+    ``stats.<field>`` (less what ``stats.<less>`` gains inside it, where
+    ``less`` names a field that nested intervals time) and, where
+    ``annotate`` (see :func:`trace_annotation`) is given, spans the body as
+    ``name`` on the profiler's clock.  With ``stats=None`` it does nothing
+    and allocates nothing."""
     if stats is None:
         return _UNTIMED
     return _Interval(stats, field,
-                     annotate(name) if annotate is not None else None)
+                     annotate(name) if annotate is not None else None, less)
 
 
 # ---------------------------------------------------------------------------

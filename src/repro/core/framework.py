@@ -8,18 +8,23 @@ Coscheduling (all-or-nothing, Eqs. 11-12) gate at the job level.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 from .cluster import Cluster
+from .fluid import interval
 from .workload import Job, Task, Workload
 
 
 @dataclasses.dataclass
 class ScheduleContext:
     """Per-cycle scratch space shared across extension points (the paper's
-    PreFilter 'CacheResource' lives here)."""
+    PreFilter 'CacheResource' lives here), and the framework's timing
+    (``profile``, ``annotate``: see :class:`SchedulingFramework`), which a
+    plugin hands its planner's time to."""
 
     cache: Dict = dataclasses.field(default_factory=dict)
+    profile: Optional[object] = None
+    annotate: Optional[Callable] = None
 
 
 class SchedulerPlugin:
@@ -122,24 +127,35 @@ class SchedulingFramework:
         self.cluster = cluster
         self.plugin = plugin
         self.registry = TaskRegistry()
+        # a SimProfile to book Filter (``filter_s``) and Score less the
+        # planner (``score_s``; the plugin books ``plan_s``) into, and the
+        # span factory while a profiler records; the simulator hands both
+        # in, as it hands the fluid engine its ``annotate``
+        self.profile = None
+        self.annotate = None
 
     # -- single pod cycle ---------------------------------------------------
     def schedule_pod(self, pod: Task) -> ScheduleOutcome:
-        ctx = ScheduleContext()
+        prof, ann = self.profile, self.annotate
+        ctx = ScheduleContext(profile=prof, annotate=ann)
         self.plugin.pre_filter(ctx, self.cluster, pod, self.registry)
 
-        feasible = [
-            n for n in self.cluster.node_names
-            if self._spread_ok(pod, n)
-            and self.plugin.filter(ctx, self.cluster, pod, n, self.registry)
-        ]
+        with interval(prof, "filter_s", ann, "sim.admit.filter"):
+            feasible = [
+                n for n in self.cluster.node_names
+                if self._spread_ok(pod, n)
+                and self.plugin.filter(ctx, self.cluster, pod, n,
+                                       self.registry)
+            ]
         if not feasible:
             return ScheduleOutcome(pod, None)
 
-        scores = self.plugin.score_nodes(ctx, self.cluster, pod, feasible,
-                                         self.registry)
-        scores = self.plugin.normalize_scores(ctx, self.cluster, pod, scores,
-                                              self.registry)
+        with interval(prof, "score_s", ann, "sim.admit.score",
+                      less="plan_s"):
+            scores = self.plugin.score_nodes(ctx, self.cluster, pod,
+                                             feasible, self.registry)
+            scores = self.plugin.normalize_scores(ctx, self.cluster, pod,
+                                                  scores, self.registry)
         # deterministic tie-break on node order
         best = max(scores.items(), key=lambda kv: (kv[1], -self.cluster.index(kv[0])))
         node_name = best[0]

@@ -18,6 +18,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from .workload import HIGH
+
 DI_PRE = 72  # angular discretization precision (paper section IV-A, after Cassini)
 
 
@@ -57,24 +59,32 @@ def unify_periods(
     g_t_ms: float = 5.0,
     e_t_frac: float = 0.10,
     max_mul: int = 16,
+    n_slots: int = DI_PRE,
 ) -> UnifiedPeriods:
     """Find a common base period T_l for a set of task periods.
 
     Implements the paper's two thresholds (section III-B):
       - if the mismatch between a task's period and the nearest integer
         divisor of the base is <= ``G_T`` -> merge by averaging;
-      - if the mismatch is in (G_T, E_T * period] -> inject idle time into
-        the task's computation phase (only meaningful for low priority
-        tasks; the caller enforces priority semantics);
-      - otherwise the task is flagged not-ok (incompatible).
+      - if the task's implied period is LONGER than its own by more than
+        G_T and by at most E_T * period -> inject the difference as idle
+        time into its computation phase, where the task is of low priority
+        (``priority < HIGH``) and not the reference; a high-priority task is
+        never slowed;
+      - otherwise the task is flagged not-ok (incompatible): it is not on
+        the circle, keeps its own period, and a rotation problem holding it
+        scores 0 (see ``rotation``).
 
     The base period is anchored on the highest-priority task (its period is
     never altered — Eq. 16's "reference" semantics), scanning multipliers up
-    to ``max_mul``.
+    to ``max_mul`` while no task repeats more than ``n_slots`` times on the
+    circle (each of its periods then spans a slot of the Di-Pre circle);
+    the first base that fits every task wins, else the first that flags
+    fewest.
     """
     key = (tuple(float(p) for p in periods_ms),
            None if priorities is None else tuple(int(p) for p in priorities),
-           g_t_ms, e_t_frac, max_mul)
+           g_t_ms, e_t_frac, max_mul, n_slots)
     hit = _UNIFY_CACHE.get(key)
     if hit is not None:
         # arrays copied out: LinkScheme consumers rebind/slice them freely
@@ -90,6 +100,10 @@ def unify_periods(
     # reference: highest priority, ties -> earliest (lowest index)
     ref = int(np.lexsort((np.arange(n), -prios))[0])
     t_ref = periods[ref]
+    # idle only ever goes into a LOW priority task other than the reference
+    # (Sec. III-B: the paper never slows a high priority job), whatever the
+    # priorities of the others
+    low = (prios < HIGH) & (np.arange(n) != ref)
 
     best: Optional[UnifiedPeriods] = None
     best_bad = n + 1
@@ -99,23 +113,19 @@ def unify_periods(
     for m_ref in range(1, max_mul + 1):
         base = t_ref * m_ref
         muls = np.maximum(1, np.round(base / periods)).astype(np.int64)
-        if np.any(muls > max_mul * 4):
-            continue
+        if np.any(muls > n_slots):
+            break  # muls only grow with the base
         eff = base / muls  # implied per-task period
         delta = eff - periods  # >0 -> task must slow down (idle injection)
         ok = np.abs(delta) <= g_t_ms
         inject = np.zeros(n)
-        # E_T rule: inject idle when the implied period is LONGER by more
-        # than G_T but within E_T fraction of the task's own period. Idle is
-        # only ever injected into LOW priority pods (the paper never slows a
-        # high priority job).
-        low = prios < prios[ref] if np.any(prios != prios[ref]) else prios == prios
-        low = np.asarray(low) & (np.arange(n) != ref)
         need_inject = (~ok) & (delta > 0) & (delta <= e_t_frac * periods) & low
-        # Also compensate sub-G_T positive mismatches of low-priority tasks:
-        # without it the task's comm phase drifts by |delta| every iteration
-        # and the monitor must re-align continuously (defeats the cushion).
-        need_inject |= ok & (delta > g_t_ms * 0.0) & (delta > 0) & low
+        # Departure from Sec. III-B, which merges a sub-G_T mismatch and
+        # injects nothing: a low-priority task's positive sub-G_T mismatch
+        # is compensated with idle too (its period is the circle's either
+        # way); without it the task's comm phase drifts by |delta| every
+        # iteration and the monitor must re-align continuously.
+        need_inject |= ok & (delta > 0) & low
         inject[need_inject] = delta[need_inject]
         ok = ok | need_inject
         n_bad = int(np.sum(~ok))

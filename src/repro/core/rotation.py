@@ -179,6 +179,13 @@ def _copy_scheme(sch: "LinkScheme") -> "LinkScheme":
     )
 
 
+def _incompatible(p: int) -> RotationResult:
+    """Sec. III-B: a job whose period fits no circle is incompatible, not
+    put on it.  A rotation problem holding one scores 0, its shifts left
+    at 0 (as the admission reference reads Algorithm 1's Score)."""
+    return RotationResult(0.0, np.zeros(p, dtype=np.int64), False, 0.0, 0)
+
+
 def priority_order(registry, jobs: Sequence[str]) -> List[str]:
     """Jobs by (priority desc, deployment order asc) — Eq. 16's reference
     semantics; index 0 is the pinned reference."""
@@ -590,7 +597,7 @@ def solve_link(
             score, scheme = hit
             return score, _copy_scheme(scheme)
     unified = geometry.unify_periods(
-        periods, prios, g_t_ms=g_t_ms, e_t_frac=e_t_frac
+        periods, prios, g_t_ms=g_t_ms, e_t_frac=e_t_frac, n_slots=di_pre
     )
     duties = []
     for idx, j in enumerate(jobs):
@@ -598,7 +605,9 @@ def solve_link(
         # m_p is unchanged); this is the E_T mechanism's second insight.
         duties.append(min(1.0, comms[idx] / unified.periods_ms[idx]))
     patterns = geometry.pattern_matrix(unified.muls, duties, di_pre)
-    if mode == "optimal":
+    if not unified.ok.all():
+        result = _incompatible(len(jobs))
+    elif mode == "optimal":
         result = find_optimal_rotation(patterns, bws, cap, unified.muls,
                                        ref_index, di_pre)
     else:
@@ -723,7 +732,7 @@ def solve_link_batch(
             continue  # duplicate: filled from the first solve below
         seen_keys[key] = i
         unified = geometry.unify_periods(periods, prios, g_t_ms=g_t_ms,
-                                         e_t_frac=e_t_frac)
+                                         e_t_frac=e_t_frac, n_slots=di_pre)
         duties = [min(1.0, comms[idx] / unified.periods_ms[idx])
                   for idx in range(len(jobs))]
         patterns = geometry.pattern_matrix(unified.muls, duties, di_pre)
@@ -731,6 +740,9 @@ def solve_link_batch(
         probs[i] = _LinkProblem(jobs=jobs, bws=np.asarray(bws, np.float64),
                                 cap=float(cap), unified=unified, comms=comms,
                                 patterns=patterns, ranges=ranges)
+        if not unified.ok.all():
+            results[i] = _link_scheme_of(probs[i], _incompatible(len(jobs)))
+            continue
         n_total = scoring.total_combos(ranges)
         fam = (patterns.tobytes(), ranges, n_total)
         todo.setdefault(fam, []).append(i)
@@ -882,6 +894,10 @@ class JointProblem:
     bw_lp: np.ndarray  # (L, P)
     on_link: Dict[str, List[int]]  # link -> job indices present there
     key: Tuple  # content key (includes every solver knob)
+    # the jobs the shifts place: all of them for the Score phase (where an
+    # incompatible job makes the problem score 0), the compatible ones for
+    # a controller's circle (an incompatible job keeps its own period)
+    placed: np.ndarray  # (P,) bool
 
 
 def _build_joint_problem(
@@ -898,9 +914,16 @@ def _build_joint_problem(
     e_t_frac: float,
     backend: str,
     max_exhaustive: int,
+    circle: bool = False,
 ) -> Optional[JointProblem]:
     """The joint_solve prologue: groups, job order, unified periods, demand
-    banks.  None when a job has no tasks in the view (stale scheme).
+    banks.  None when a job has no tasks in the view (stale scheme).  A
+    job's traffic is that of its first task, the view's candidate pod
+    where the job has no task placed yet.
+
+    ``circle`` builds a controller's circle: a job incompatible with the
+    unified circle is left off it (its demand out of every link, its
+    shift pinned at 0), so the others are aligned where it cannot be.
 
     The content key captures EVERY input that can change the solve's
     output, including the solver-selection knobs (``max_exhaustive`` picks
@@ -918,6 +941,8 @@ def _build_joint_problem(
     specs = []
     for j in jobs:
         ts = view.job_tasks(j)
+        if not ts and view.extra is not None and view.extra.job == j:
+            ts = [view.extra]
         if not ts:
             return None
         specs.append(ts[0].traffic)
@@ -926,29 +951,41 @@ def _build_joint_problem(
         job = registry.jobs.get(j)
         prios.append(job.priority if job else 0)
     unified = geometry.unify_periods([s.period_ms for s in specs], prios,
-                                     g_t_ms=g_t_ms, e_t_frac=e_t_frac)
+                                     g_t_ms=g_t_ms, e_t_frac=e_t_frac,
+                                     n_slots=di_pre)
     duties = [min(1.0, specs[i].comm_ms / unified.periods_ms[i])
               for i in range(len(jobs))]
     patterns = geometry.pattern_matrix(unified.muls, duties, di_pre)
     ranges = scoring.shift_ranges(unified.muls, 0, di_pre)
+    placed = (unified.ok.copy() if circle
+              else np.ones(len(jobs), dtype=bool))
+    for pi in np.flatnonzero(~placed):
+        ranges[pi] = 1
     caps = np.array([view.cluster.link_alloc(l) for l in links])
     bw_lp = np.zeros((len(links), len(jobs)))
     on_link: Dict[str, List[int]] = {}
     for li, l in enumerate(links):
         dmds = _link_demands(view, l, jobs, demand)
         present = groups_by_link[l]
-        on_link[l] = [pi for pi, j in enumerate(jobs) if j in present]
-        for pi, j in enumerate(jobs):
-            bw_lp[li, pi] = dmds[pi] if j in present else 0.0
+        on_link[l] = [pi for pi, j in enumerate(jobs)
+                      if j in present and placed[pi]]
+        for pi in on_link[l]:
+            bw_lp[li, pi] = dmds[pi]
     key = ("joint", tuple(links), tuple(jobs), bw_lp.tobytes(),
            caps.tobytes(),
            tuple((s.period_ms, s.comm_ms) for s in specs), tuple(prios),
            tuple(tuple(on_link[l]) for l in links),
            mode, demand, rotation_mode, di_pre, g_t_ms, e_t_frac, backend,
-           max_exhaustive)
+           max_exhaustive, circle)
     return JointProblem(links=list(links), jobs=list(jobs), unified=unified,
                         patterns=patterns, ranges=ranges, caps=caps,
-                        bw_lp=bw_lp, on_link=on_link, key=key)
+                        bw_lp=bw_lp, on_link=on_link, key=key,
+                        placed=placed)
+
+
+def _compatible(prob: JointProblem) -> bool:
+    """Every job the problem places fits its circle."""
+    return bool(prob.unified.ok[prob.placed].all())
 
 
 def _joint_psi_of(prob: JointProblem, di_pre: int):
@@ -970,14 +1007,16 @@ def _finish_joint(prob: JointProblem, result: RotationResult,
     unified = prob.unified
     jobs = prob.jobs
     delays = geometry.shifts_to_delay_ms(shifts, unified.base_ms, di_pre)
-    offsets = {j: float(d) for j, d in zip(jobs, delays)}
+    offsets = {j: float(d) for j, d, on in zip(jobs, delays, prob.placed)
+               if on}
+    compatible = _compatible(prob)
     schemes: Dict[str, LinkScheme] = {}
     link_scores: List[float] = []
     for li, l in enumerate(prob.links):
         on_link = prob.on_link[l]
         sc = float(scoring.score_combos(
             prob.patterns, prob.bw_lp[li], float(prob.caps[li]),
-            shifts[None, :])[0])
+            shifts[None, :])[0]) if compatible else 0.0
         link_scores.append(sc)
         link_jobs = [jobs[pi] for pi in on_link]
         ref = link_jobs[0] if link_jobs else ""
@@ -1016,7 +1055,9 @@ def _solve_joint_problem(prob: JointProblem, *, mode: str,
                          max_exhaustive: int, chunk: int) -> JointResult:
     psi_of = _joint_psi_of(prob, di_pre)
     n_total = scoring.total_combos(prob.ranges)
-    if n_total > max_exhaustive:
+    if not _compatible(prob):
+        result = _incompatible(len(prob.jobs))
+    elif n_total > max_exhaustive:
         result = _joint_coordinate_descent(
             prob.patterns, prob.bw_lp, prob.caps, prob.unified.muls,
             prob.ranges, psi_of, optimize_psi=(mode == "optimal"))
@@ -1133,13 +1174,15 @@ def joint_solve_batch(
             continue  # duplicate problem: filled from the first solve below
         seen_keys[prob.key] = i
         n_total = scoring.total_combos(prob.ranges)
+        if not _compatible(prob):
+            # scores 0 without a search
+            n_total = max_exhaustive + 1
         fam = (prob.patterns.tobytes(), tuple(prob.ranges), n_total)
         todo.setdefault(fam, []).append(i)
 
     for fam, members in todo.items():
         group = [probs[i] for i in members]
-        if len(group) == 1 or scoring.total_combos(
-                group[0].ranges) > max_exhaustive:
+        if len(group) == 1 or fam[2] > max_exhaustive:
             for i in members:
                 results[i] = _solve_joint_problem(
                     probs[i], mode=mode, rotation_mode=rotation_mode,
@@ -1309,6 +1352,97 @@ def _joint_coordinate_descent(patterns, bw_lp, caps, muls, ranges, psi_of, *,
     final = _min_link_scores(patterns, bw_lp, caps, shifts[None, :], None)[0]
     return RotationResult(float(final), shifts, final >= PERFECT - _EPS,
                           psi_of(shifts), n_eval)
+
+
+# ---------------------------------------------------------------------------
+# A controller's circles: contended links sharing a job share one circle
+# ---------------------------------------------------------------------------
+
+def contended_components(view: LinkView) -> List[List[str]]:
+    """The links that need a rotation — two jobs or more whose demands
+    together exceed the link's allocatable bandwidth — grouped so that
+    contended links sharing a job share one circle (a job runs one
+    period): each group's links in planning order."""
+    busy: List[str] = []
+    jobs_on: Dict[str, set] = {}
+    for l in view.planning_links():
+        d = view.demands(l)
+        if len(d) > 1 and sum(d.values()) > view.cluster.link_alloc(l):
+            busy.append(l)
+            jobs_on[l] = set(d)
+    comps: List[List[str]] = []
+    done: set = set()
+    for l0 in busy:
+        if l0 in done:
+            continue
+        comp, todo = {l0}, [l0]
+        while todo:
+            a = todo.pop()
+            for b in busy:
+                if b not in comp and jobs_on[a] & jobs_on[b]:
+                    comp.add(b)
+                    todo.append(b)
+        done |= comp
+        comps.append([l for l in busy if l in comp])
+    return comps
+
+
+# the circles' memo key: their problems' content alone (never an epoch),
+# which holds every input of the solve, so a circle whose links, jobs,
+# demands and capacities have not changed is answered again unchanged
+CONTENT_EPOCH = ("content",)
+
+
+def solve_circles(
+    view: LinkView,
+    registry,
+    previous: Dict[Tuple, Tuple[JointResult, bool]],
+    *,
+    optimal: Sequence[str] = (),
+    di_pre: int = DI_PRE,
+    g_t_ms: float = 5.0,
+    e_t_frac: float = 0.10,
+    rotation_mode: str = "intermediate",
+    cache: Optional[PlanCache] = None,
+) -> Dict[Tuple, Tuple[JointResult, bool]]:
+    """A controller's circles over ``view``: one per group of contended
+    links sharing a job (:func:`contended_components`), its periods
+    unified over all the group's jobs (Eq. 16 order), a job incompatible
+    with it left off, and its shifts at the Eq. 18 optimum of the group's
+    joint problem (the worst link scored).
+
+    Returned by content key, each with whether it was solved in the third
+    stage (``mode='optimal'``: Psi maximized among the perfect-run
+    midpoints) or the fast one.  A group unchanged since ``previous`` keeps
+    its circle; one with a link in ``optimal`` is solved in the third stage
+    unless it already was."""
+    knobs = dict(demand="planning", rotation_mode=rotation_mode,
+                 di_pre=di_pre, g_t_ms=g_t_ms, e_t_frac=e_t_frac,
+                 backend="numpy", max_exhaustive=1 << 22, circle=True)
+    out: Dict[Tuple, Tuple[JointResult, bool]] = {}
+    for links in contended_components(view):
+        prob = _build_joint_problem(view, registry, links, None,
+                                    mode="fast", **knobs)
+        if prob is None:
+            continue
+        third = any(l in optimal for l in links)
+        old = previous.get(prob.key)
+        if old is not None and (old[1] or not third):
+            out[prob.key] = old
+            continue
+        mode = "optimal" if third else "fast"
+        prob_m = (_build_joint_problem(view, registry, links, None,
+                                       mode=mode, **knobs) if third else prob)
+        hit = cache.get(view.epoch, prob_m.key) if cache is not None else None
+        if hit is None:
+            hit = _solve_joint_problem(
+                prob_m, mode=mode, rotation_mode=rotation_mode,
+                di_pre=di_pre, backend="numpy", max_exhaustive=1 << 22,
+                chunk=8192)
+            if cache is not None:
+                cache.put(view.epoch, prob_m.key, _copy_joint(hit))
+        out[prob.key] = (_copy_joint(hit), third)
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -21,6 +21,7 @@ import numpy as np
 from . import geometry, rotation
 from .cluster import Cluster
 from .contention import LinkView
+from .fluid import interval
 from .framework import ScheduleContext, SchedulerPlugin, TaskRegistry
 from .geometry import DI_PRE
 from .rotation import LinkScheme
@@ -62,6 +63,24 @@ class ReserveMessage:
     shifts_ms: Dict[str, float]
     skip_phase_three: bool
     skips: Dict[str, bool] = dataclasses.field(default_factory=dict)
+
+
+def _closes_loop(g: nx.Graph, job: str) -> bool:
+    """A simple cycle of three jobs or more through ``job`` whose edges
+    have no link in common.  Every simple cycle is walked, not only a
+    cycle basis: a loop may be a sum of basis cycles that are each
+    harmless (their edges sharing a link) while it is not."""
+    def walk(path: List[str], common: Optional[set]) -> bool:
+        for b in g[path[-1]]:
+            links = g[path[-1]][b]["links"]
+            both = set(links) if common is None else common & links
+            if b == job and len(path) >= 3:
+                if not both:
+                    return True
+            elif b not in path and walk(path + [b], both):
+                return True
+        return False
+    return walk([job], None)
 
 
 class MetronomePlugin(SchedulerPlugin):
@@ -192,21 +211,7 @@ class MetronomePlugin(SchedulerPlugin):
         closure = {l for u, v, d in g.subgraph(comp).edges(data=True)
                    for l in d["links"]}
         closure_links = [l for l in view.planning_links() if l in closure]
-        loop = False
-        try:
-            for cyc in nx.cycle_basis(g, pod.job):
-                if len(cyc) < 3 or pod.job not in cyc:
-                    continue
-                common = None
-                for a, b in zip(cyc, cyc[1:] + cyc[:1]):
-                    links = g[a][b]["links"]
-                    common = set(links) if common is None else common & links
-                if not common:
-                    loop = True
-                    break
-        except nx.NetworkXError:
-            pass
-        return loop, closure_links
+        return _closes_loop(g, pod.job), closure_links
 
     # ------------------------------------------------------------------ Score
     def _candidate_links(self, cluster: Cluster, view: LinkView, pod: Task,
@@ -252,12 +257,13 @@ class MetronomePlugin(SchedulerPlugin):
         # bandwidth score is the worst link score
         view = self._candidate_view(cluster, pod, node_name, registry)
         links = self._candidate_links(cluster, view, pod, node_name)
-        plan = rotation.plan(
-            view, registry, links=links, self_job=pod.job, mode="fast",
-            demand="planning", di_pre=self.di_pre, g_t_ms=self.g_t_ms,
-            e_t_frac=self.e_t_frac, rotation_mode=self.rotation_mode,
-            joint=self.joint, cache=self.plan_cache,
-        )
+        with interval(ctx.profile, "plan_s", ctx.annotate, "sim.admit.plan"):
+            plan = rotation.plan(
+                view, registry, links=links, self_job=pod.job, mode="fast",
+                demand="planning", di_pre=self.di_pre, g_t_ms=self.g_t_ms,
+                e_t_frac=self.e_t_frac, rotation_mode=self.rotation_mode,
+                joint=self.joint, cache=self.plan_cache,
+            )
         link_schemes = plan.schemes
         worst = plan.score
 
@@ -283,13 +289,16 @@ class MetronomePlugin(SchedulerPlugin):
             if self.joint:
                 wanted = set(closure) | set(links)
                 plan_links = [l for l in view.planning_links() if l in wanted]
-                jplan = rotation.plan(
-                    view, registry, links=plan_links,
-                    self_job=pod.job, mode="fast", demand="planning",
-                    di_pre=self.di_pre, g_t_ms=self.g_t_ms,
-                    e_t_frac=self.e_t_frac, rotation_mode=self.rotation_mode,
-                    joint=True, cache=self.plan_cache,
-                )
+                with interval(ctx.profile, "plan_s", ctx.annotate,
+                              "sim.admit.plan"):
+                    jplan = rotation.plan(
+                        view, registry, links=plan_links,
+                        self_job=pod.job, mode="fast", demand="planning",
+                        di_pre=self.di_pre, g_t_ms=self.g_t_ms,
+                        e_t_frac=self.e_t_frac,
+                        rotation_mode=self.rotation_mode, joint=True,
+                        cache=self.plan_cache,
+                    )
                 if jplan.schemes:
                     link_schemes = jplan.schemes
                     worst = jplan.score
@@ -352,6 +361,13 @@ class MetronomePlugin(SchedulerPlugin):
             link_specs.extend((view, lid) for lid in plan_links)
         if not cand:
             return
+        with interval(ctx.profile, "plan_s", ctx.annotate, "sim.admit.plan"):
+            self._solve_candidates(cand, link_specs, pod, registry)
+
+    def _solve_candidates(self, cand, link_specs, pod: Task,
+                          registry: TaskRegistry) -> None:
+        """The planner's part of :meth:`_warm_candidates`: the per-link
+        solves, then the joint solves of their conflicted components."""
         solved = rotation.solve_link_batch(
             link_specs, registry, self_job=pod.job, mode="fast",
             demand="planning", di_pre=self.di_pre, g_t_ms=self.g_t_ms,

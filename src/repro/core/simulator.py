@@ -127,7 +127,17 @@ class SimProfile:
     ``admit_s`` is ``_try_schedule``'s body (framework, offline
     recalculation, admission, realign); it is booked also inside the phase
     that called it (``events`` for arrivals, ``step`` for pending-queue
-    retries after a job completes).  ``active_hits`` and ``active_misses``
+    retries after a job completes).  Inside it: ``filter_s``, the Filter
+    loop of ``SchedulingFramework.schedule_pod``; ``score_s``, Score and
+    NormalizeScore less the planner; ``plan_s``, the rotation planner's
+    solves, the Score phase's (``rotation.plan``, ``solve_link_batch``,
+    ``joint_solve_batch`` and the searches under them) and the
+    controller's circles at Reserve, eviction and recalculation;
+    ``recalc_s``, ``run_offline_recalculation`` less the planner; and
+    ``realign_s``, the realign loop that ends ``_schedule``.
+    ``plan_cache_hits`` and ``plan_cache_misses`` count the lookups of the
+    plugin's and the controller's ``rotation.PlanCache`` during
+    admission.  ``active_hits`` and ``active_misses``
     split the ticks on which the set of active flow slots changed between
     the membership cache's answers and rebuilds of the ordered active set
     (``_active_slots``).  ``flow_ticks`` counts the ticks with active
@@ -155,6 +165,13 @@ class SimProfile:
     active_misses: int = 0
     flow_ticks: int = 0
     scalar_ticks: int = 0
+    filter_s: float = 0.0
+    score_s: float = 0.0
+    plan_s: float = 0.0
+    recalc_s: float = 0.0
+    realign_s: float = 0.0
+    plan_cache_hits: int = 0
+    plan_cache_misses: int = 0
 
     def as_dict(self) -> Dict[str, object]:
         return dataclasses.asdict(self)
@@ -512,28 +529,49 @@ class ClusterSimulator:
 
     # ------------------------------------------------------- online arrivals
     def _try_schedule(self, wl) -> bool:
-        if self.profile is None:
+        prof = self.profile
+        if prof is None:
             return self._schedule(wl)
-        with interval(self.profile, "admit_s", self._annotate, "sim.admit"):
-            return self._schedule(wl)
+        ctl = self.controller
+        caches = [c for c in (getattr(self.framework.plugin, "plan_cache",
+                                      None),
+                              ctl.plan_cache if ctl is not None else None)
+                  if c is not None]
+        hits = sum(c.hits for c in caches)
+        misses = sum(c.misses for c in caches)
+        # the controller's planner time is booked only while it admits
+        if ctl is not None:
+            ctl.profile, ctl.annotate = prof, self._annotate
+        try:
+            with interval(prof, "admit_s", self._annotate, "sim.admit"):
+                return self._schedule(wl)
+        finally:
+            if ctl is not None:
+                ctl.profile = ctl.annotate = None
+            prof.plan_cache_hits += sum(c.hits for c in caches) - hits
+            prof.plan_cache_misses += sum(c.misses for c in caches) - misses
 
     def _schedule(self, wl) -> bool:
         assert self.framework is not None
-        if self.framework.schedule_workload(wl):
-            if self.controller is not None and self.offline_recalc:
+        if not self.framework.schedule_workload(wl):
+            return False
+        prof, ann = self.profile, self._annotate
+        if self.controller is not None and self.offline_recalc:
+            with interval(prof, "recalc_s", ann, "sim.admit.recalc",
+                          less="plan_s"):
                 self.controller.run_offline_recalculation(
                     self.framework.registry, self._ctl_cluster)
-            for job in wl.jobs:
-                self._admit_job(job)
-            # a new scheme may shift existing low-priority jobs
-            if self.controller is not None:
+        for job in wl.jobs:
+            self._admit_job(job)
+        # a new scheme may shift existing low-priority jobs
+        if self.controller is not None:
+            with interval(prof, "realign_s", ann, "sim.admit.realign"):
                 for name, st in self.jobs.items():
                     job = st.job
                     if (st.phase not in (DONE,) and job.priority != HIGH
                             and name not in {j.name for j in wl.jobs}):
                         self._apply_realign(name)
-            return True
-        return False
+        return True
 
     def _process_arrivals(self) -> None:
         while self._arrivals and self._arrivals[0][0] <= self.now + EPS:
@@ -836,6 +874,9 @@ class ClusterSimulator:
         self._annotate = (trace_annotation() if self.profile is not None
                           else None)
         self.fluid.annotate = self._annotate
+        if self.framework is not None:
+            self.framework.profile = self.profile
+            self.framework.annotate = self._annotate
         return self._run_array()
 
     def _validate_events(self) -> None:
@@ -893,6 +934,13 @@ class ClusterSimulator:
         span = None  # the open phase's span, while ann records
         dv = self._delivered_vec
         link_index = self._link_index
+        # a run that resumes where the last one ended on a job's last flow
+        # ending: that job's step was cut off with the last run, so the
+        # first tick steps it where it is, advancing nothing
+        n = len(self._jobs_list)
+        due_now = bool(n) and bool(np.any(
+            (self._jp[:n] == _COMM_CODE) & self._jhasflows[:n]
+            & (self._junfin[:n] == 0)))
         while self.now < duration:
             t0 = perf() if prof is not None else 0.0
             if ann is not None:
@@ -919,6 +967,9 @@ class ClusterSimulator:
                 nxt = min(nxt, self._events[0].time_ms)
             if self._arrivals:
                 nxt = min(nxt, self._arrivals[0][0])
+            if due_now:
+                nxt = self.now
+                due_now = False
             nxt = max(nxt, self.now)  # no time travel
             dt = nxt - self.now
             if prof is not None:
